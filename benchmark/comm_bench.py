@@ -4,8 +4,8 @@ ISSUE 2 acceptance lane: at a BERT-base-sized parameter list (~200 dense
 tensors), `pushpull_list` with gradient fusion (MXNET_KVSTORE_BUCKET_MB
 buckets, kvstore/fusion.py) must issue >= 5x fewer kvstore dispatches per
 step than the per-key push+pull loop, and spend less host wall time — the
-per-key path is pure host-bound dispatch overhead that PROFILE.md's
-device-time decomposition cannot see.
+per-key path is pure host-bound dispatch overhead that a device-time
+decomposition cannot see.
 
 Dispatches are measured from the telemetry registry, not guessed:
 per-key = mxnet_kvstore_push_seconds.count + mxnet_kvstore_pull_seconds.count

@@ -22,7 +22,7 @@ class and the ISSUE 7 acceptance bar — a 4-worker pool must at least
 double single-core decode).  Hosts with fewer cores cannot physically
 double (workers + the assembler + the consumer share the cores), so the
 gate relaxes to 0.6 x usable cores; the measured ratio is always
-printed for the PROFILE.md record.
+printed.
 
 Usage:
     python benchmark/data_bench.py [--images 768] [--size 256]

@@ -1,4 +1,4 @@
-"""Input-pipeline throughput benchmark (VERDICT r3 item 5).
+"""Input-pipeline throughput benchmark.
 
 Measures images/sec through ``ImageRecordIter`` on REAL JPEG bytes — the
 reference measures its decode thread pool the same way

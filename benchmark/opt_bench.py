@@ -4,7 +4,8 @@ ISSUE 5 acceptance lane: at BERT-base adam shapes (~199 dense tensors,
 110M params), the flat-buffer fused optimizer (`optimizer_fusion`) must
 dispatch >= 4x fewer times per step than the per-param update loop and
 spend less host wall time — on the chip the same collapse converts
-adam's 8.9 ms/step (~2.8x its HBM bound, PROFILE.md) toward the ~3.2 ms
+adam's 8.9 ms/step (~2.8x its HBM bound; builder-measured through the
+earlier chip access, see ROADMAP A4) toward the ~3.2 ms
 bound, which is most of what the seq-512 lane needs for MFU >= 0.45.
 
 Dispatches are measured from the telemetry registry, not guessed:

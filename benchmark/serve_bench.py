@@ -42,7 +42,7 @@ Usage:
         [--requests 48] [--max-batch 8] [--block-tokens 16] [--seed 0]
 
 Prints one JSON line per lane plus a summary; exits non-zero when a gate
-fails.  On-chip recipe: PROFILE.md ("Serving" addendum).
+fails.
 """
 
 from __future__ import annotations

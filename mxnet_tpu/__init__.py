@@ -42,8 +42,25 @@ def _apply_x64():
         jax.config.update("jax_enable_x64", True)
 
 
+def _apply_compile_cache():
+    # One persistent compile cache for every entry point (chip_smoke.py,
+    # bench.py children, the serving replica): a chip call starts with no
+    # compiled code, and BERT-base alone compiles for most of a minute.
+    # Where JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and
+    # nothing is set here.  Otherwise the cache lives at ONE fixed path in
+    # the checkout (git-ignored): the path is part of the cache key, so a
+    # directory named by pid, time or tempfile would never hit.
+    import os
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache"))
+
+
 _apply_matmul_precision()
 _apply_x64()
+_apply_compile_cache()
 
 from .base import MXNetError  # noqa: F401
 from .context import (  # noqa: F401

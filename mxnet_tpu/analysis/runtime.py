@@ -47,7 +47,7 @@ _installed = False
 _compiles = 0
 
 # every jit/pjit cache miss records exactly one backend compile under
-# this key (jax 0.4.x); trace-only events are not counted because a
+# this key; trace-only events are not counted because a
 # pure re-trace that hits the executable cache is not a perf cliff
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

@@ -9,7 +9,7 @@ budget, the planner enumerates candidate layouts —
 budget?) and an analytic roofline step-time model (which fitter is
 FASTEST?), and emits a :class:`Plan` that ``parallel.TrainStep`` consumes
 directly.  This closes ROADMAP 3's loop: the fits-per-shape crossover
-table PROFILE.md r9 asked a human to read is now a function call.
+table a human once had to read is now a function call.
 
 Everything here is hardware-free and DETERMINISTIC: the same inputs
 always produce the same plan (and byte-identical ``plan.json`` — the CI

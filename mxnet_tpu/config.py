@@ -122,7 +122,7 @@ KNOWN_VARS = {
     "MXNET_PERFGATE_MFU_BAND": (
         "0.25", float,
         "Relative band for the on-chip sweep's measured-vs-analytic MFU "
-        "assertion (tools/onchip_sweep.py, PROFILE.md r10 protocol: "
+        "assertion (tools/onchip_sweep.py: "
         "analytic MFU counts ALL XLA-emitted flops, so it sits a few "
         "percent above the hand-derived number)."),
     "MXNET_STEPCLOCK_WINDOW": (
@@ -205,7 +205,7 @@ KNOWN_VARS = {
         "If 1, gelu (the op, LeakyReLU act_type='gelu', and "
         "gluon.nn.GELU) defaults to the tanh approximation "
         "0.5x(1+tanh(sqrt(2/pi)(x+0.044715x^3))) instead of the exact erf "
-        "form — the cheaper PROFILE.md lever for the seq-512 MFU target. "
+        "form — a cheaper, untried lever for the seq-512 MFU target. "
         "An explicit approximate= attr always wins; read when an op/block "
         "first resolves, so set it before building the model."),
     "MXNET_PARAMS_FORMAT": (
@@ -491,8 +491,9 @@ KNOWN_VARS = {
         "outright (pure-python fallbacks everywhere)."),
     "MXNET_NATIVE_CACHE": (
         None, str,
-        "Directory for on-demand-compiled native libraries when the "
-        "package dir is read-only (default ~/.cache/mxnet_tpu)."),
+        "Directory the on-demand-compiled native libraries are built "
+        "into and loaded from (default mxnet_tpu/src/build/ inside the "
+        "checkout; set it when the package dir is read-only)."),
     # flash-attention kernel tuning (single-tile kernels only)
     "MXNET_FLASH_BLOCK_H_FWD": (
         None, int,

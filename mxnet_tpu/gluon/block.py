@@ -343,7 +343,8 @@ class CachedOp:
             self._cache.clear()
             self._cache_epoch = _reg.dispatch_epoch()
         key = tuple((tuple(a.shape), str(a.dtype)) for a in in_arrays) \
-            + (train_mode, tuple(sorted(kwargs.items())))
+            + (train_mode, tuple(sorted(kwargs.items())),
+               _reg.step_layout_key())
         entry = self._cache.get(key)
         if entry is None:
             entry = self._trace(param_list, in_arrays, train_mode, kwargs)

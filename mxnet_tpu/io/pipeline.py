@@ -1,8 +1,8 @@
 """Multi-core record→decode→batch pipeline (ISSUE 7 tentpole).
 
 The single-process ``ImageRecordIter`` tops out at one core's native JPEG
-decode rate (~650 img/s measured vs the 1500 img/s multi-core target —
-PROFILE.md); the reference keeps this path fed with a C++ decode THREAD
+decode rate (~650 img/s builder-measured vs the 1500 img/s multi-core
+target); the reference keeps this path fed with a C++ decode THREAD
 pool (src/io/iter_image_recordio_2.cc), and DALI/tf.data reach the same
 end with process/stream parallelism.  This module is that stage for the
 TPU rebuild, built from three pieces:

@@ -9,22 +9,6 @@ fallback and an interpret-mode path used by the CPU test suite as the
 numerics oracle.
 """
 
-import functools as _functools
-
-
-def shard_map_compat():
-    """The shard_map version shim, defined ONCE: new jax spells the
-    replication check ``check_vma``, the experimental fallback spells it
-    ``check_rep`` — callers get a shard_map with the check disabled
-    either way (used by pipeline.py and the SP kernels)."""
-    try:
-        from jax import shard_map as _sm
-        return _functools.partial(_sm, check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _functools.partial(_sm, check_rep=False)
-
-
-from .flash_attention import flash_attention  # noqa: F401,E402
-from .ring_attention import (ring_attention,  # noqa: F401,E402
+from .flash_attention import flash_attention  # noqa: F401
+from .ring_attention import (ring_attention,  # noqa: F401
                              sequence_parallel_attention)

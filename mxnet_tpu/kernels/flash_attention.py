@@ -9,8 +9,9 @@ upstream kernel's index arithmetic miscompiles under x64 — everything
 here pins explicit int32/float32 types, including BlockSpec index-map
 literals (see ``_zi``).  This kernel is the TPU branch of
 ``contrib.masked_selfatt`` / ``contrib.masked_att_qkv``
-(``ops/contrib.py::_attend``), gated by a one-time compile probe that
-falls back to the dense fp32 path on toolchains that reject the IR.
+(``ops/contrib.py::_attend``), chosen there from shapes and platform
+alone: a kernel the compiler refuses raises, nothing falls back to the
+dense path.
 
 Layout: q, k, v are (batch, heads, seq, head_dim); segment ids are
 (batch, seq) int32 — attention only flows between positions with EQUAL
@@ -85,9 +86,7 @@ def _pick_block_h(H, bq, bk, single_tile=False):
         if forced and H % int(forced) == 0:
             # non-divisor head counts FALL THROUGH to the auto pick (not an
             # error): the knob targets one model's shape, but the same
-            # process also compiles other head counts — notably the
-            # eligibility probe's small-H configs, which must keep passing
-            # or the whole flash path silently degrades to dense
+            # process also compiles other head counts
             return int(forced)
     if single_tile == "bwd":
         budget = 3 * 1024 * 1024
@@ -117,12 +116,12 @@ def _mask_block(sq_ref, skv_ref, causal, iq, ik, bq, bk):
     the compiled kernel).  int32 iota only (x64-safe).
 
     sq_ref block is (1, bq, LANES) (q ids broadcast over lanes), skv_ref is
-    (1, SUBLANES, bk) (kv ids broadcast over sublanes) — the tile-legal
+    (1, 1, SUBLANES, bk) (kv ids broadcast over sublanes) — the tile-legal
     layout trick for 1-per-row scalars."""
     mask = None
     if sq_ref is not None:
         sq = sq_ref[0][:, :1]      # (bq, 1)
-        skv = skv_ref[0][:1, :]    # (1, bk)
+        skv = skv_ref[0, 0][:1, :]  # (1, bk)
         mask = sq == skv
     if causal:
         qi = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
@@ -135,12 +134,12 @@ def _mask_block(sq_ref, skv_ref, causal, iq, ik, bq, bk):
 def _mask_block_T(sqT_ref, skvT_ref, causal, iq, ik, bq, bk):
     """(bk, bq) mask (or None) — the TRANSPOSED tile for the dk/dv
     kernel, built directly from transposed segment layouts (sqT
-    (1, SUBLANES, bq) q ids over lanes, skvT (1, bk, LANES) kv ids over
+    (1, 1, SUBLANES, bq) q ids over lanes, skvT (1, bk, LANES) kv ids over
     sublanes) because Mosaic cannot legalize a bool vector transpose
     (`tpu.transpose` on i1)."""
     mask = None
     if sqT_ref is not None:
-        sq = sqT_ref[0][:1, :]     # (1, bq)
+        sq = sqT_ref[0, 0][:1, :]  # (1, bq)
         skv = skvT_ref[0][:, :1]   # (bk, 1)
         mask = skv == sq           # (bk, bq)
     if causal:
@@ -158,10 +157,23 @@ def _seg_row_layout(seg, L):
     return jnp.broadcast_to(seg[:, :, None], (seg.shape[0], L, _LANES))
 
 
-def _seg_lane_layout(seg, L):
-    """Segment ids per LANE — (B, _SUBLANES, L), for kv-side ids in
-    (bq, bk) masks and q-side ids in transposed (bk, bq) masks."""
-    return jnp.broadcast_to(seg[:, None, :], (seg.shape[0], _SUBLANES, L))
+def _seg_lane_layout(seg, L, blk):
+    """Segment ids per LANE, one row group per seq block —
+    (B, L // blk, _SUBLANES, blk), for kv-side ids in (bq, bk) masks and
+    q-side ids in transposed (bk, bq) masks.  The block index is its own
+    array dim so a kernel's (1, 1, _SUBLANES, blk) block always spans the
+    whole of the last two dims: the TPU lowering only takes a lane dim
+    that is a multiple of 128 or the whole dim, and blk may be 64."""
+    B = seg.shape[0]
+    return jnp.broadcast_to(seg.reshape(B, L // blk, 1, blk),
+                            (B, L // blk, _SUBLANES, blk))
+
+
+def _seg_lane_spec(blk, index_map):
+    """BlockSpec for one seq block of a ``_seg_lane_layout`` array;
+    ``index_map`` gives (batch, block) from the grid indices."""
+    return pl.BlockSpec((1, 1, _SUBLANES, blk),
+                        lambda *g: (*index_map(*g), _zi(), _zi()))
 
 
 def _apply_mask(s, mask):
@@ -284,12 +296,11 @@ def _fwd_single(q, k, v, seg_q, seg_kv, causal, scale, hb, interpret):
     if has_seg:
         in_specs += [
             pl.BlockSpec((1, Lq, _LANES), lambda b, h: (b, _zi(), _zi())),
-            pl.BlockSpec((1, _SUBLANES, Lk),
-                         lambda b, h: (b, _zi(), _zi())),
+            _seg_lane_spec(Lk, lambda b, h: (b, _zi())),
         ]
         inputs += [
             _seg_row_layout(seg_q, Lq),
-            _seg_lane_layout(seg_kv, Lk),
+            _seg_lane_layout(seg_kv, Lk, Lk),
         ]
     out, lse = pl.pallas_call(
         functools.partial(_fwd_single_kernel, causal=causal, scale=scale,
@@ -335,10 +346,10 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_k, block_h,
     inputs = [q, k, v]
     if has_seg:
         seg_q = _seg_row_layout(seg_q, Lq)
-        seg_kv = _seg_lane_layout(seg_kv, Lk)
+        seg_kv = _seg_lane_layout(seg_kv, Lk, bk)
         in_specs += [
             pl.BlockSpec((1, bq, _LANES), lambda b, h, i, j: (b, i, _zi())),
-            pl.BlockSpec((1, _SUBLANES, bk), lambda b, h, i, j: (b, _zi(), j)),
+            _seg_lane_spec(bk, lambda b, h, i, j: (b, j)),
         ]
         inputs += [seg_q, seg_kv]
 
@@ -513,12 +524,11 @@ def _bwd_fused(q, k, v, seg_q, seg_kv, lse_b, delta_b, do, causal, scale,
     if has_seg:
         in_specs += [
             pl.BlockSpec((1, Lq, _LANES), lambda b, h: (b, _zi(), _zi())),
-            pl.BlockSpec((1, _SUBLANES, Lk),
-                         lambda b, h: (b, _zi(), _zi())),
+            _seg_lane_spec(Lk, lambda b, h: (b, _zi())),
         ]
         inputs += [
             _seg_row_layout(seg_q, Lq),
-            _seg_lane_layout(seg_kv, Lk),
+            _seg_lane_layout(seg_kv, Lk, Lk),
         ]
     return pl.pallas_call(
         functools.partial(_bwd_fused_kernel, causal=causal, scale=scale,
@@ -586,18 +596,16 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
         # two layouts of each segment-id vector: per-sublane-row for the
         # dq kernel's (bq, bk) mask, per-lane for the dkv (bk, bq) mask
         seg_qr = _seg_row_layout(seg_q, Lq)
-        seg_kvl = _seg_lane_layout(seg_kv, Lk)
-        seg_qT = _seg_lane_layout(seg_q, Lq)
+        seg_kvl = _seg_lane_layout(seg_kv, Lk, bk)
+        seg_qT = _seg_lane_layout(seg_q, Lq, bq)
         seg_kvT = _seg_row_layout(seg_kv, Lk)
         dq_specs += [
             pl.BlockSpec((1, bq, _LANES), lambda b, h, i, j: (b, i, _zi())),
-            pl.BlockSpec((1, _SUBLANES, bk),
-                         lambda b, h, i, j: (b, _zi(), j)),
+            _seg_lane_spec(bk, lambda b, h, i, j: (b, j)),
         ]
         dq_inputs += [seg_qr, seg_kvl]
         dkv_specs += [
-            pl.BlockSpec((1, _SUBLANES, bq),
-                         lambda b, h, j, i: (b, _zi(), i)),
+            _seg_lane_spec(bq, lambda b, h, j, i: (b, i)),
             pl.BlockSpec((1, bk, _LANES), lambda b, h, j, i: (b, j, _zi())),
         ]
         dkv_inputs += [seg_qT, seg_kvT]

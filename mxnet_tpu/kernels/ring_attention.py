@@ -121,8 +121,6 @@ def sequence_parallel_attention(q, k, v, mesh, axis="sp", seg_q=None,
     nesting shard_maps or pjit shardings outside).
     """
     from jax.sharding import PartitionSpec as P
-    from . import shard_map_compat
-    shard_map = shard_map_compat()
 
     if hasattr(mesh, "mesh"):            # accept DeviceMesh too
         mesh = mesh.mesh
@@ -153,7 +151,8 @@ def sequence_parallel_attention(q, k, v, mesh, axis="sp", seg_q=None,
 
     in_specs = (spec_x, spec_x, spec_x) + ((spec_s, spec_s) if has_seg
                                            else ())
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=spec_x)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=spec_x, check_vma=False)
     # reshard inputs onto the mesh first: when this runs EAGERLY (e.g. a
     # TrainStep tape-capture pass) the operands arrive committed to a
     # single device and shard_map would reject them; under a jit trace

@@ -44,10 +44,7 @@ def ulysses_attention(q, k, v, axis_name, causal=False, scale=1.0):
     (unscaled — the caller applies 1/√d).  The head dim H must divide by
     the axis size n (standard Ulysses requirement — heads are what gets
     scattered)."""
-    # jax.lax.axis_size doesn't exist on this toolchain (jax 0.4.x);
-    # psum over the literal 1 folds to the static axis size — the same
-    # idiom ring_attention.py uses
-    n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     B, H, Lb, D = q.shape
     if H % n:
         raise ValueError(f"ulysses: heads {H} not divisible by axis {n}")
@@ -91,7 +88,6 @@ def ulysses_sequence_parallel_attention(q, k, v, mesh, axis="sp",
     ``sequence_parallel_attention`` — SAME signature and defaults
     (``sm_scale=1.0`` i.e. unscaled, like the ring kernel: the caller
     applies 1/√d).  Segment masking is a ring-only feature for now."""
-    from . import shard_map_compat
     if seg_q is not None or seg_kv is not None:
         raise NotImplementedError(
             "ulysses: segment masking not implemented — use the ring "
@@ -112,9 +108,9 @@ def ulysses_sequence_parallel_attention(q, k, v, mesh, axis="sp",
             return ulysses_attention(qq, kk, vv, axis, causal=causal,
                                      scale=sm_scale)
 
-        f = jax.jit(shard_map_compat()(
+        f = jax.jit(jax.shard_map(
             body, mesh=raw_mesh, in_specs=(spec, spec, spec),
-            out_specs=spec))
+            out_specs=spec, check_vma=False))
         _jit_cache[key] = f
     # reshard first: eager callers (TrainStep tape capture) hand over
     # single-device-committed arrays the shard_map would reject; under a

@@ -172,11 +172,7 @@ class KVStoreDistTPUSync(KVStoreLocal):
                 # not hang the bring-up forever
                 kwargs["initialization_timeout"] = max(1, int(t))
             try:
-                try:
-                    jax.distributed.initialize(**kwargs)
-                except TypeError:  # older jax without initialization_timeout
-                    kwargs.pop("initialization_timeout", None)
-                    jax.distributed.initialize(**kwargs)
+                jax.distributed.initialize(**kwargs)
             except RuntimeError as e:
                 msg = str(e).lower()
                 if "already" in msg or "only be called once" in msg \
@@ -261,15 +257,14 @@ class KVStoreDistTPUSync(KVStoreLocal):
         if fn is None:
             import jax
             from jax.sharding import PartitionSpec as P
-            from ..kernels import shard_map_compat
-            shard_map = shard_map_compat()
             mesh = self._proc_mesh()
 
             def reduce_(x):  # x block: (1, *shape) per device
                 return jax.lax.psum(x[0], "proc")
 
-            fn = jax.jit(shard_map(reduce_, mesh=mesh, in_specs=P("proc"),
-                                   out_specs=P()))
+            fn = jax.jit(jax.shard_map(
+                reduce_, mesh=mesh, in_specs=P("proc"), out_specs=P(),
+                check_vma=False))
             self._psum_cache[key] = fn
         return fn
 
@@ -350,15 +345,14 @@ class KVStoreDistTPUSync(KVStoreLocal):
         if fn is None:
             import jax
             from jax.sharding import PartitionSpec as P
-            from ..kernels import shard_map_compat
-            shard_map = shard_map_compat()
             mesh = self._proc_mesh()
 
             def gather(x):  # block (1, *shape) → (P, *shape) replicated
                 return jax.lax.all_gather(x[0], "proc")
 
-            fn = jax.jit(shard_map(gather, mesh=mesh, in_specs=P("proc"),
-                                   out_specs=P()))
+            fn = jax.jit(jax.shard_map(
+                gather, mesh=mesh, in_specs=P("proc"), out_specs=P(),
+                check_vma=False))
             self._psum_cache[key] = fn
         return fn
 

@@ -6,19 +6,23 @@ seam: a small C ABI (mxnet_tpu/src/*.cc) compiled on demand with g++ and
 loaded with ctypes — no pybind11 dependency, and the C boundary stays as
 language-portable as the reference's C API.
 
-Build-on-first-use: the shared library lands next to the sources
-(mxnet_tpu/src/librecordio.so) or, if the package dir is read-only, under
-``$MXNET_NATIVE_CACHE`` (default ~/.cache/mxnet_tpu).  Every entry point
-has a pure-python fallback — the native path is a fast lane, never a
-requirement (``MXNET_USE_NATIVE=0`` disables it outright).
+Build-on-first-use into ONE directory: ``$MXNET_NATIVE_CACHE`` if set,
+else ``mxnet_tpu/src/build/`` inside the checkout (git-ignored).  A built
+library is reused only while the sha256 of its source and compile flags,
+stored beside it, still matches — never by mtime, which a copy of the
+tree changes.  Every entry point has a pure-python fallback: a host
+without the toolchain gets a RuntimeWarning and the slow lane
+(``MXNET_USE_NATIVE=0`` disables the native lane outright).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 
 import numpy as _np
 
@@ -44,42 +48,64 @@ _ERRORS = {
 }
 
 
-def _cache_dir():
+def _build_dir():
     return config.get("MXNET_NATIVE_CACHE") \
-        or os.path.join(os.path.expanduser("~"), ".cache", "mxnet_tpu")
+        or os.path.join(os.path.dirname(_SRC), "build")
 
 
-def _so_candidates():
-    yield os.path.join(os.path.dirname(_SRC), "librecordio.so")
-    yield os.path.join(_cache_dir(), "librecordio.so")
-
-
-def _compile(out_path, src=_SRC, extra_link=()):
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    # compile to a unique temp name, then atomically rename: concurrent
-    # workers (tools/launch.py spawns N processes) must never CDLL a
-    # half-written ELF
-    tmp = f"{out_path}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, src,
-           *extra_link]
+def _replace_atomically(path, write):
+    """Write ``path`` through a unique temp name and an atomic rename:
+    concurrent workers (tools/launch.py, pytest-xdist) must never read a
+    half-written file."""
+    tmp = f"{path}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, out_path)
+        write(tmp)
+        os.replace(tmp, path)
     finally:
         if os.path.exists(tmp):
-            try:
-                os.unlink(tmp)   # failed/timed-out compile must not litter
-            except OSError:
-                pass
+            os.unlink(tmp)   # a failed/timed-out build must not litter
 
 
-def _fresh(so_path, src=_SRC):
-    """A prebuilt .so is reusable only if at least as new as the source —
-    a stale binary would silently keep old scanner behavior after a fix."""
+def _build(name, src, extra_link=()):
+    """Path of the up-to-date shared library for ``src``, compiling it
+    unless the stored digest of (source, command) still matches."""
+    out = os.path.join(_build_dir(), name)
+    stamp = out + ".sha256"
+    flags = ["-O3", "-shared", "-fPIC", "-std=c++17"]
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read() + bytes(
+            " ".join([*flags, *extra_link]), "utf-8")).hexdigest()
     try:
-        return os.path.getmtime(so_path) >= os.path.getmtime(src)
+        with open(stamp) as f:
+            if f.read().strip() == digest and os.path.exists(out):
+                return out
     except OSError:
-        return False
+        pass
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    _replace_atomically(out, lambda tmp: subprocess.run(
+        ["g++", *flags, "-o", tmp, src, *extra_link],
+        check=True, capture_output=True, timeout=120))
+
+    def _write_stamp(tmp):
+        with open(tmp, "w") as f:
+            f.write(digest + "\n")
+    _replace_atomically(stamp, _write_stamp)
+    return out
+
+
+def _load(name, src, bind, extra_link=()):
+    """Build and bind one library; None (with a warning naming the cause)
+    when this host cannot build or load it."""
+    try:
+        return bind(_build(name, src, extra_link))
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        warnings.warn(
+            f"mxnet_tpu.native: {name} unavailable, using the pure-python "
+            f"path ({type(e).__name__}: {e} "
+            f"{detail.decode(errors='replace')[-300:]})",
+            RuntimeWarning, stacklevel=3)
+        return None
 
 
 def _bind(path):
@@ -111,31 +137,8 @@ def recordio_lib():
         _tried = True
         if not config.get_int("MXNET_USE_NATIVE", 1):
             return None
-        for cand in _so_candidates():
-            try:
-                if not (os.path.exists(cand) and _fresh(cand)):
-                    _compile(cand)
-                _lib = _bind(cand)
-                return _lib
-            except Exception:  # noqa: BLE001
-                # rebuild failed (no toolchain?) — a stale-by-mtime but
-                # loadable prebuilt binary beats losing the native lane,
-                # but say so: silently-old scanner behavior must be
-                # diagnosable
-                if os.path.exists(cand):
-                    try:
-                        _lib = _bind(cand)
-                        import warnings
-                        warnings.warn(
-                            f"mxnet_tpu.native: using prebuilt {cand} older "
-                            "than src/recordio.cc (recompile failed); "
-                            "native scanner behavior may predate source "
-                            "fixes", RuntimeWarning, stacklevel=2)
-                        return _lib
-                    except Exception:  # noqa: BLE001
-                        pass
-                continue
-        return None
+        _lib = _load("librecordio.so", _SRC, _bind)
+        return _lib
 
 
 def native_available():
@@ -213,11 +216,6 @@ _jpeg_lib = None
 _jpeg_tried = False
 
 
-def _jpeg_so_candidates():
-    yield os.path.join(os.path.dirname(_JPEG_SRC), "libjpegdec.so")
-    yield os.path.join(_cache_dir(), "libjpegdec.so")
-
-
 def _bind_jpeg(path):
     lib = ctypes.CDLL(path)
     u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -244,15 +242,9 @@ def jpeg_lib():
         _jpeg_tried = True
         if not config.get_int("MXNET_USE_NATIVE", 1):
             return None
-        for cand in _jpeg_so_candidates():
-            try:
-                if not (os.path.exists(cand) and _fresh(cand, _JPEG_SRC)):
-                    _compile(cand, src=_JPEG_SRC, extra_link=("-ljpeg",))
-                _jpeg_lib = _bind_jpeg(cand)
-                return _jpeg_lib
-            except Exception:  # noqa: BLE001
-                continue
-        return None
+        _jpeg_lib = _load("libjpegdec.so", _JPEG_SRC, _bind_jpeg,
+                          extra_link=("-ljpeg",))
+        return _jpeg_lib
 
 
 def jpeg_decode_available():

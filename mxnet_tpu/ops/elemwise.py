@@ -64,7 +64,7 @@ _unary("ceil", lambda jnp, x: jnp.ceil(x), differentiable=False)
 _unary("round", lambda jnp, x: jnp.round(x), differentiable=False)
 _unary("rint", lambda jnp, x: jnp.rint(x), differentiable=False)
 _unary("trunc", lambda jnp, x: jnp.trunc(x), differentiable=False)
-_unary("fix", lambda jnp, x: jnp.fix(x), differentiable=False)
+_unary("fix", lambda jnp, x: jnp.trunc(x), differentiable=False)
 _unary("gamma", lambda jnp, x: _gamma_impl(jnp, x))
 _unary("gammaln", lambda jnp, x: _gammaln_impl(jnp, x))
 _unary("erf", lambda jnp, x: _erf_impl(jnp, x))
@@ -137,7 +137,7 @@ def _softrelu(x):
 
 def _gelu_tanh_default():
     """Knob-resolved default for gelu's ``approximate`` attr (ISSUE 7
-    satellite: the tanh form is the untried PROFILE.md MFU lever).
+    satellite: the tanh form is an untried MFU lever, ROADMAP A3).
     Resolved when an executable is first built for the attr set — same
     trace-time-knob contract as MXNET_FUSED_ATTENTION; pass an explicit
     ``approximate=`` (it is part of the jit cache key) to flip per call."""

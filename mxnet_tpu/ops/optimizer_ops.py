@@ -220,8 +220,7 @@ def _adadelta_update(weight, grad, acc_g, acc_delta, rho=0.9, epsilon=1e-5,
 
 # ---------------------------------------------------------------------------
 # multi-tensor fused updates (reference src/operator/optimizer_op.cc
-# multi_sgd_update / multi_sgd_mom_update / multi_mp_sgd_* — VERDICT r3
-# item 8).  One registry dispatch updates N params: the per-param host
+# multi_sgd_update / multi_sgd_mom_update / multi_mp_sgd_*).  One registry dispatch updates N params: the per-param host
 # dispatch loop becomes a single jitted XLA program.  Per-param lr/wd ride
 # as INPUT vectors (traced, so schedules never recompile); the weight/grad
 # (/mom/w32) tensors arrive interleaved like the reference kernels.

@@ -21,6 +21,7 @@ When autograd is recording, we capture ``jax.vjp`` residuals at dispatch time
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time as _time
@@ -157,6 +158,39 @@ def _costmodel_rearm():
 
 _costmodel.add_rearm_hook(_costmodel_rearm)
 
+# The layout of the TrainStep program this thread is tracing: set by
+# parallel.TrainStep around its trace, None otherwise.  An op that GSPMD
+# cannot partition — the Pallas flash kernel, ops.contrib._flash — reads it
+# to place its own shard_map.  Its key is part of every trace-cache key of
+# this module and of gluon's CachedOp: a nested jit caches its trace by
+# avals alone, and would replay a kernel laid out for another mesh.
+_step = threading.local()
+
+
+def step_layout():
+    """``(DeviceMesh, batch_axes)`` of the TrainStep being traced on this
+    thread, else None; ``batch_axes`` are the mesh axes dim 0 of the batch
+    is sharded over."""
+    return getattr(_step, "layout", None)
+
+
+def step_layout_key():
+    """Hashable identity of :func:`step_layout` (None outside a trace)."""
+    return getattr(_step, "key", None)
+
+
+@contextlib.contextmanager
+def step_layout_scope(mesh, batch_axes):
+    saved = step_layout(), step_layout_key()
+    _step.layout = (mesh, batch_axes)
+    _step.key = (tuple(d.id for d in mesh.devices), mesh.axis_names,
+                 mesh.shape, batch_axes)
+    try:
+        yield
+    finally:
+        _step.layout, _step.key = saved
+
+
 # Pre-dispatch array-cast hook (mxnet_tpu.amp): fn(op_name, arrays) -> arrays,
 # jax-traceable so it folds into jit traces.  _dispatch_epoch bumps whenever
 # the hook changes so shape/dtype-keyed caches (CachedOp) retrace.
@@ -230,7 +264,8 @@ def _callable_for(op, attrs):
     if _REGISTRY.get(op.name) is op:  # interned op: stable identity
         try:
             mkey = (op.name, jit_on,
-                    tuple(attrs.items()) if attrs else None)
+                    tuple(attrs.items()) if attrs else None,
+                    getattr(_step, "key", None))
             f = _callable_memo.get(mkey)
             if f is not None:
                 return f
@@ -252,7 +287,7 @@ def _build_callable(op, attrs, jit_on):
     if not jit_on:
         return functools.partial(op.fn, **attrs) if attrs else op.fn
     dyn_keys = tuple(sorted(dyn))
-    key = (op.name, _freeze(static), dyn_keys)
+    key = (op.name, _freeze(static), dyn_keys, step_layout_key())
     try:
         jf = _jit_cache.get(key)
     except TypeError:  # unhashable attr (e.g. a traced array kwarg) — no cache

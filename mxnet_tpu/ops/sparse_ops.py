@@ -1,6 +1,6 @@
 """Sparse-storage kernel ops (reference src/operator/tensor/dot.cc
 FComputeEx sparse paths, square_sum.cc, sparse_retain.cc — SURVEY §2.2
-tensor/ + VERDICT r3 item 7).
+tensor/).
 
 TPU-native storage dispatch: the reference routes (stype...) tuples to
 FComputeEx kernels at graph-build time; here the sparse containers

@@ -1,10 +1,10 @@
 """Flat-buffer fused optimizer updates — multi-tensor apply with donation.
 
-PROFILE.md's step decomposition names the optimizer as the gap between the
-seq-512 lane's 0.43 MFU and the 0.45 BASELINE target: the 110M-param
-multi-precision adam costs 8.9 ms/step against a ~3.2 ms HBM bound because
-the update runs as one small dispatch per parameter, each re-reading
-weights and states from HBM.  Every serious trainer fuses here — PyTorch
+The builders' step decomposition of the BERT seq-512 lane (through the
+earlier chip access; not re-measured on today's code) named the
+optimizer as a gap: the 110M-param multi-precision adam cost 8.9 ms/step
+against a ~3.2 ms HBM bound because the update ran as one small dispatch
+per parameter, each re-reading weights and states from HBM.  Every serious trainer fuses here — PyTorch
 DDP buckets gradients, NVIDIA Apex runs multi-tensor `FusedAdam` — and
 this module is that layer for the TPU rebuild, shaped like PR 2's kvstore
 gradient fusion (same `GradBucketer` bucket layout, same bit-identity
@@ -47,7 +47,10 @@ kernels — a 1-ulp split that survives ``lax.optimization_barrier``
 (fusion inlines straight through barriers).  Per-param output fusions
 have the same structure as the reference kernels and round identically;
 per-param lr/wd must ride as individual scalar args for the same reason
-(an indexed vector load inside the kernel changes codegen).
+(an indexed vector load inside the kernel changes codegen).  The flat
+gradient entry slices its segments behind an ``optimization_barrier``
+for the same reason: a slice fused into the update loop rounds
+differently from the per-param kernels under jaxlib 0.9.
 
 Donation invariant: callers must NOT alias donated buffers — after a
 fused update, previously captured raw ``jax.Array`` references to
@@ -144,8 +147,8 @@ _planner = None
 def planner():
     """Module-wide GradBucketer planning optimizer buckets (the kvstore's
     layout machinery, reused with n_rep=1).  Rebuilt whenever
-    MXNET_OPTIMIZER_BUCKET_MB changes, so a runtime knob flip (e.g. the
-    PROFILE.md bucket-size sweep) replans instead of half-applying."""
+    MXNET_OPTIMIZER_BUCKET_MB changes, so a runtime knob flip (e.g. a
+    bucket-size sweep) replans instead of half-applying."""
     global _planner
     nbytes = bucket_bytes_from_env()
     with _lock:
@@ -301,11 +304,18 @@ def _build_exec(kind, mp, has_mom, shapes, sizes, cfg, flat_grad):
         mom = args[s0 + 2 * n + 1] if has_mom else None
         new_ws = []
         new_states = [[] for _ in range(n_roles)]
+        if flat_grad:
+            # the barrier keeps each segment a buffer of its own: with
+            # the slice fused into the update loop, XLA:CPU (jaxlib
+            # 0.9) contracts the mul+adds differently from the
+            # per-param kernels and ~1 element in 256 lands 1 ulp off
+            gs = jax.lax.optimization_barrier(tuple(
+                args[n][offs[i]:offs[i] + sizes[i]].reshape(shapes[i])
+                for i in range(n)))
+        else:
+            gs = args[n:n + n]
         for i in range(n):
-            if flat_grad:
-                g = args[n][offs[i]:offs[i] + sizes[i]].reshape(shapes[i])
-            else:
-                g = args[n + i]
+            g = gs[i]
             sts = [flats[r * n + i] for r in range(n_roles)]
             new_w, outs = _param_update(kind, mp, has_mom, cfg, ws[i], g,
                                         sts, lrs[i], wds[i], rescale, mom)

@@ -207,8 +207,6 @@ def _collective_fn(kind, mesh, shape, dtype, variant):
     if fn is not None:
         return fn
     jax = _jax()
-    from .kernels import shard_map_compat
-    shard_map = shard_map_compat()
     axis = mesh.axis_names[0]
     n = mesh.size
     if kind == "allreduce":
@@ -223,8 +221,8 @@ def _collective_fn(kind, mesh, shape, dtype, variant):
         def f(xs):
             return jax.lax.all_gather(xs[0], axis)[None]
 
-    fn = jax.jit(shard_map(f, mesh=mesh.mesh, in_specs=mesh.spec(axis),
-                           out_specs=mesh.spec(axis)))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh.mesh, in_specs=mesh.spec(axis),
+                               out_specs=mesh.spec(axis), check_vma=False))
     _collective_cache[key] = fn
     return fn
 
@@ -471,10 +469,22 @@ class TrainStep:
             out.append(st)
 
     def _resolve(self, data_nd):
+        """Fix the param/state order; ``data_nd=None`` (lowering from
+        shapes alone) skips the forward that finishes deferred init, so
+        every param must already know its shape."""
         from . import autograd
-        with autograd.pause():
-            self.net(data_nd)  # finish deferred init
+        if data_nd is not None:
+            with autograd.pause():
+                self.net(data_nd)  # finish deferred init
         self._params = list(self.net.collect_params().values())
+        if data_nd is None:
+            deferred = [p.name for p in self._params
+                        if p.shape is None or 0 in p.shape]
+            if deferred:
+                raise MXNetError(
+                    "TrainStep.lowered from shapes needs an initialized "
+                    "net: run one forward first (deferred-init params: "
+                    f"{deferred[:4]})")
         self._trainable = [p for p in self._params if p.grad_req != "null"]
         if self._rules is not None:
             # declarative layout: resolve the rule set against the named
@@ -582,6 +592,7 @@ class TrainStep:
         activations inside the net are recomputed during backward instead
         of saved (single-output nets only — remat_call's contract)."""
         from . import autograd, random as _rnd
+        from .ops import registry as _reg
 
         params, trainable = self._params, self._trainable
         state_nds = self._state_nds
@@ -594,6 +605,14 @@ class TrainStep:
         from . import optimizer_fusion as _fus
 
         from .ndarray.ndarray import swap_slot_values
+
+        # the mesh axes dim 0 of the batch is sharded over (ops that place
+        # their own shard_map read them through registry.step_layout)
+        batch_axes = self._data_spec[0] if self._data_spec \
+            else self.mesh.axis_names[0]
+        if not isinstance(batch_axes, (tuple, list)):
+            batch_axes = (batch_axes,)
+        batch_axes = tuple(a for a in batch_axes if a is not None)
 
         def forward_loss(key, d, l):
             """(remat'd) forward + loss under record scope; grads land in
@@ -630,6 +649,7 @@ class TrainStep:
             import jax.numpy as jnp
             saved_opt = (optzr._update_count, optzr._index_update_count,
                          optzr._get_lr, optzr.rescale_grad)
+            layout = _reg.step_layout_scope(self.mesh, batch_axes)
             # one swap covers params + optimizer state + grad buffers
             # (grads enter zeroed in-trace: params the loss does not reach
             # keep a zero gradient — the reference tolerates stale grads)
@@ -639,7 +659,7 @@ class TrainStep:
                          jnp.zeros(p.shape, p._data._grad.dtype))
                         for p in trainable])
             try:
-                with swap_slot_values(pairs):
+                with layout, swap_slot_values(pairs):
                     optzr._update_count = lambda idx: None
                     optzr._index_update_count = _TracedCount(t)
                     optzr._get_lr = lambda idx: lr_vec[idx]
@@ -755,6 +775,64 @@ class TrainStep:
             jax.jit(raw_multi, in_shardings=in_sh, out_shardings=out_sh,
                     donate_argnums=donate), "parallel.TrainStep")
 
+    def _program(self, data, label, stacked=None, steps=None):
+        """The jitted program for these (shape, dtype)s, built on first
+        use: ``__call__``'s single step when ``steps`` is None, else
+        ``run``'s scan of ``steps`` steps."""
+        self._evict_stale_traces()
+        key_sig = ((tuple(data.shape), str(data.dtype)),
+                   (tuple(label.shape), str(label.dtype)))
+        if steps is not None:
+            key_sig = ("multi", stacked, steps) + key_sig
+        fn = self._cache.get(key_sig)
+        if fn is None:
+            fn = self._build(data, label) if steps is None else \
+                self._build_multi(stacked, len(data.shape),
+                                  len(label.shape))
+            self._cache[key_sig] = fn
+        return fn
+
+    def lowered(self, data, label, steps=None, scan=True):
+        """The ``jax.stages.Lowered`` of the program ``run(data, label,
+        steps)`` — or, with ``scan=False``, ``__call__(data, label)`` —
+        dispatches, from shapes alone: nothing runs and no step is
+        counted.  ``.compile()`` it to read ``as_text()`` (is the Pallas
+        kernel in the program?) and ``memory_analysis()``, also for a
+        described topology the process has no device of (mesh built over
+        ``topo.devices``).  ``data``/``label`` may be arrays or
+        ``jax.ShapeDtypeStruct``s; from structs alone the net must
+        already be initialized."""
+        import jax
+        if self._params is None:
+            if isinstance(data, jax.ShapeDtypeStruct):
+                self._resolve(None)
+            else:
+                data = data if isinstance(data, NDArray) else nd.array(data)
+                self._resolve(NDArray._from_data(data._data[0])
+                              if scan and steps is None else data)
+        f32 = _np.float32
+        n_tr = len(self._trainable)
+
+        def struct(x, lead=()):
+            return jax.ShapeDtypeStruct(lead + tuple(x.shape), x.dtype)
+
+        p_vals = tuple(struct(p._data._data) for p in self._params)
+        s_vals = tuple(struct(s._data) for s in self._state_nds)
+        if not scan:
+            fn = self._program(data, label)
+            lead = ()
+        else:
+            stacked = steps is None
+            if stacked:
+                steps = data.shape[0]
+            fn = self._program(data, label, stacked, steps)
+            lead = (steps,)
+        key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+        return fn.lower(struct(key, lead), jax.ShapeDtypeStruct(lead, f32),
+                        jax.ShapeDtypeStruct(lead + (n_tr,), f32),
+                        jax.ShapeDtypeStruct((), f32), p_vals, s_vals,
+                        struct(data), struct(label))
+
     def run(self, data, label, steps=None):
         """Run many fused training steps in ONE jitted dispatch.
 
@@ -781,15 +859,7 @@ class TrainStep:
             probe = NDArray._from_data(data._data[0]) if stacked else data
             self._resolve(probe)
 
-        self._evict_stale_traces()
-        key_sig = ("multi", stacked, steps,
-                   (tuple(data.shape), str(data.dtype)),
-                   (tuple(label.shape), str(label.dtype)))
-        fn = self._cache.get(key_sig)
-        if fn is None:
-            fn = self._build_multi(stacked, len(data.shape),
-                                   len(label.shape))
-            self._cache[key_sig] = fn
+        fn = self._program(data, label, stacked, steps)
 
         # host-side bookkeeping for every step up front; per-step scalars
         # ship as stacked traced arrays
@@ -857,13 +927,7 @@ class TrainStep:
         if self._params is None:
             self._resolve(data)
 
-        self._evict_stale_traces()
-        key_sig = ((tuple(data.shape), str(data.dtype)),
-                   (tuple(label.shape), str(label.dtype)))
-        fn = self._cache.get(key_sig)
-        if fn is None:
-            fn = self._build(data, label)
-            self._cache[key_sig] = fn
+        fn = self._program(data, label)
 
         # host-side step bookkeeping: advance the real counters, compute
         # per-param lr (schedules, multipliers); ship as traced scalars

@@ -35,14 +35,6 @@ from .base import MXNetError
 __all__ = ["gpipe", "pipeline_apply", "stack_blocks", "PipelinedBlock"]
 
 
-def _shard_map():
-    """(jax, shard_map) with the replication check normalized — single
-    definition lives in kernels.shard_map_compat."""
-    import jax
-    from .kernels import shard_map_compat
-    return jax, shard_map_compat()
-
-
 def gpipe(stage_fn, n_stages, n_microbatches, mesh, axis="pp",
           data_axis=None):
     """Build the SPMD GPipe schedule for a homogeneous stage function.
@@ -66,7 +58,7 @@ def gpipe(stage_fn, n_stages, n_microbatches, mesh, axis="pp",
     pytree whose leaves have leading dim ``n_stages`` (sharded over
     ``axis``), ``x`` the trunk input ``(batch, ...)``.  Differentiable.
     """
-    jax, shard_map = _shard_map()
+    import jax
     import jax.numpy as jnp
 
     if mesh.axis_size(axis) != n_stages:
@@ -125,8 +117,8 @@ def gpipe(stage_fn, n_stages, n_microbatches, mesh, axis="pp",
                 f"must be divisible by n_microbatches={M}")
         in_specs = (jax.tree_util.tree_map(lambda _: stage_spec,
                                            params_stacked), act_spec)
-        f = shard_map(schedule, mesh=mesh.mesh, in_specs=in_specs,
-                      out_specs=act_spec)
+        f = jax.shard_map(schedule, mesh=mesh.mesh, in_specs=in_specs,
+                          out_specs=act_spec, check_vma=False)
         return f(params_stacked, x)
 
     return jax.jit(wrapped)

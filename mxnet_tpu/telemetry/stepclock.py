@@ -1,6 +1,6 @@
 """Step-time attribution — where did this training step's wall time go?
 
-The profiling recipes in PROFILE.md all end with the same question: is the
+Every profiling recipe ends with the same question: is the
 run input-bound, comms-bound, or compute-bound?  ``StepClock`` answers it
 continuously: instrumented chokepoints split every optimizer step into
 

@@ -19,7 +19,7 @@ def amp_off_after():
 
 
 def test_lazy_names_resolve():
-    # VERDICT r2 missing #1: every advertised lazy must import
+    # every advertised lazy must import
     for name in ("amp", "monitor", "contrib", "gluon", "optimizer", "metric",
                  "initializer", "lr_scheduler", "io", "image", "kvstore",
                  "profiler", "runtime", "symbol", "parallel", "test_utils",

@@ -1,0 +1,317 @@
+"""What can be known about the chip without the chip.
+
+1. Compiles for a DESCRIBED TPU v5e (the chip's compiler is installed here
+   and needs no device): the flash kernel at the shapes of the real lanes,
+   one whole TrainStep program at BERT-base width, the same step on a
+   4-device mesh, and the serving executables at the chip_smoke.py width.
+   They raise what the chip's compiler would raise — interpret-mode tests
+   cannot see an illegal block, a kernel GSPMD cannot partition or a program
+   that does not fit.  A compile that passes is not a chip run.
+2. chip_smoke.py's phase functions at a tiny size on the CPU, and the
+   refusals: chip_smoke.py and bench.py never fall back to the CPU.
+
+The topology is described inside a module-scoped fixture (never at import,
+in a skipif or in parametrize): only the worker that runs this file loads
+the TPU library, and it compiles in its own process.
+"""
+
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu import nd, parallel
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+V5E_HBM_BYTES = 16e9
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(ROOT, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    return _load("chip_smoke")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described-topology executable is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache off here
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# -- 1a. the flash kernel, forward and backward ------------------------------
+
+FLASH_SHAPES = [
+    # (B, H, L, D), dtype, causal, segment ids, forced block
+    pytest.param((32, 12, 512, 64), "bfloat16", False, True, None,
+                 id="bert_seq512_b32_segids"),
+    pytest.param((4, 16, 2048, 128), "bfloat16", True, False, None,
+                 id="llama_seq2048_d128_causal"),
+    pytest.param((8, 16, 2048, 64), "bfloat16", True, False, None,
+                 id="llama_seq2048_d64_causal"),
+    pytest.param((2, 16, 4096, 128), "bfloat16", True, False, None,
+                 id="seq4096_streaming"),
+    pytest.param((2, 2, 128, 64), "float32", True, True, 64,
+                 id="block64_segids_f32"),
+    pytest.param((2, 2, 128, 64), "bfloat16", False, True, 64,
+                 id="block64_segids_bf16"),
+    pytest.param((2, 2, 128, 64), "bfloat16", True, True, 8,
+                 id="block8_segids_bf16"),
+]
+
+
+@pytest.mark.parametrize("shape,dtype,causal,seg,block", FLASH_SHAPES)
+def test_flash_kernel_compiles_for_v5e(one_chip, shape, dtype, causal, seg,
+                                       block):
+    from mxnet_tpu.kernels.flash_attention import flash_attention
+    x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    s = jax.ShapeDtypeStruct((shape[0], shape[2]), jnp.int32,
+                             sharding=one_chip)
+    blocks = {} if block is None else {"block_q": block, "block_k": block}
+
+    def loss(q, k, v, *segs):
+        sq = segs[0] if segs else None
+        out = flash_attention(q, k, v, sq, sq, causal, 0.125, **blocks)
+        return out.astype(jnp.float32).sum()
+
+    args = (x, x, x) + ((s,) if seg else ())
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+        .lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_illegal_block_raises_not_falls_back(one_chip):
+    """A block the TPU lowering cannot take (4 rows: not a multiple of 8)
+    raises at compile time — nothing switches to the dense path."""
+    from mxnet_tpu.kernels.flash_attention import flash_attention
+    x = jax.ShapeDtypeStruct((2, 2, 128, 64), jnp.float32,
+                             sharding=one_chip)
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        jax.jit(lambda q: flash_attention(q, q, q, None, None, False, 0.125,
+                                          block_q=4, block_k=4)) \
+            .lower(x).compile()
+
+
+# -- 1b. whole programs ------------------------------------------------------
+
+@pytest.fixture
+def bert_2layer(smoke, monkeypatch):
+    """chip_smoke.py's train config at BERT-base WIDTH (768 units, 12
+    heads of 64, seq 512, batch 32, bf16), depth cut to 2 layers so the
+    compile stays in seconds."""
+    from mxnet_tpu.gluon.model_zoo import bert
+    monkeypatch.setitem(bert._BERT_CONFIGS, "bert_2_768_12",
+                        (2, 768, 3072, 12))
+    return dict(smoke.TRAIN_FULL, name="bert_2_768_12")
+
+
+def _compile_step(smoke, cfg, mesh, tp_axis=None):
+    with smoke.bf16_matmuls(cfg["dtype"]):
+        model, step = smoke.build_bert_step(cfg, mesh, tp_axis)
+        model(nd.array(np.zeros((1, 8), np.int32)))   # deferred init
+        batch = jax.ShapeDtypeStruct(
+            (cfg["scan_steps"], cfg["batch"], cfg["seq"]), np.int32)
+        return step.lowered(batch, batch).compile()
+
+
+def test_bert_width_trainstep_compiles_with_the_kernel(topo, smoke,
+                                                       bert_2layer):
+    mesh = parallel.make_mesh(shape=(1,), devices=list(topo.devices[:1]))
+    compiled = _compile_step(smoke, bert_2layer, mesh)
+    # forward + fused backward kernel per layer: attention is the Pallas
+    # kernel, not the dense path
+    assert compiled.as_text().count("tpu_custom_call") == 4
+    assert mx.telemetry.costmodel.peak_bytes(
+        compiled.memory_analysis()) < V5E_HBM_BYTES
+
+
+def test_trainstep_compiles_for_a_four_chip_mesh(topo, smoke, bert_2layer):
+    """GSPMD cannot partition a Mosaic kernel: under a mesh the kernel
+    runs inside ops.contrib._flash's shard_map (batch over dp, heads over
+    tp), and the step compiles — also right after a one-device step of
+    the same shapes, whose cached op traces must not be replayed."""
+    mesh = parallel.make_mesh(shape=(2, 2), axis_names=("dp", "tp"),
+                              devices=list(topo.devices))
+    text = _compile_step(smoke, bert_2layer, mesh, "tp").as_text()
+    assert text.count("tpu_custom_call") == 4
+    assert " all-reduce(" in text or " all-reduce-start(" in text
+
+
+def test_serving_executables_compile_for_v5e(one_chip, smoke):
+    """Prefill (1, P) and decode (B, 1) at the chip_smoke.py serve width,
+    depth cut to 2 layers; weights as shapes only."""
+    from mxnet_tpu.serving import models as sm
+    c = dict(smoke.SERVE_FULL, layers=2)
+    hd = c["units"] // c["heads"]
+
+    def arr(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    U, Hd, KV = c["units"], c["hidden"], c["kv_heads"] * hd
+    block = sm.LlamaBlockW(
+        attn_norm=arr((U,)), q=arr((U, U)), k=arr((KV, U)), v=arr((KV, U)),
+        o=arr((U, U)), mlp_norm=arr((U,)), gate=arr((Hd, U)),
+        up=arr((Hd, U)), down=arr((U, Hd)))
+    weights = sm.LlamaW(embed=arr((c["vocab"], U)),
+                        blocks=(block,) * c["layers"], norm=arr((U,)),
+                        lm_head=arr((c["vocab"], U)))
+    cfg = sm.LlamaCfg(layers=c["layers"], units=U, heads=c["heads"],
+                      kv_heads=c["kv_heads"], head_dim=hd, eps=1e-5,
+                      rope_base=500000.0)
+    table = -(-c["max_seq"] // c["block_tokens"])
+    pool = arr((c["max_batch"] * table + 1, c["block_tokens"],
+                c["kv_heads"], hd), jnp.float32)
+    kv = ((pool, pool),) * c["layers"]
+    B = c["max_batch"]
+
+    def i32(*shape):
+        return arr(shape, jnp.int32)
+
+    jits = sm._jitted()
+    jits["llama_prefill"].lower(
+        cfg, weights, kv, i32(1, c["prefill_tokens"]), i32(1),
+        i32(table)).compile()
+    jits["llama_decode"].lower(
+        cfg, weights, kv, i32(B), i32(B, table), i32(B),
+        arr((B,), jnp.bool_)).compile()
+
+
+# -- 2. chip_smoke.py on the CPU at a tiny size, and the refusals ------------
+
+TINY_TRAIN = dict(name="bert_3_128_2", vocab=200, seq=32, batch=8,
+                  scan_steps=2, dispatches=2, dtype="bfloat16")
+TINY_SERVE = dict(layers=2, units=64, hidden=172, heads=4, kv_heads=2,
+                  vocab=101, dtype="bfloat16", max_batch=4, block_tokens=4,
+                  max_seq=64, prefill_tokens=16,
+                  prompt_lens=(3, 9, 5, 12, 7), max_new_tokens=6)
+
+
+@pytest.fixture(scope="module")
+def clock(smoke):
+    return smoke.CompileClock()
+
+
+def test_smoke_small_phases_on_cpu(smoke, clock):
+    assert set(smoke.run_phase("native", clock,
+                               smoke.phase_native)["available"]) \
+        == {"recordio", "jpeg"}
+    row = smoke.run_phase("imperative", clock, smoke.phase_imperative,
+                          mx.cpu(), "cpu")
+    assert row["losses"][2] < row["losses"][0]
+    assert row["compile_seconds"] > 0      # the clock saw the compiles
+    row = smoke.run_phase("gluon", clock, smoke.phase_gluon, mx.cpu(), "cpu")
+    assert row["param_platforms"] == ["cpu"]
+
+
+def test_smoke_serve_phase_on_cpu(smoke, clock):
+    row = smoke.run_phase("serve", clock, smoke.phase_serve, TINY_SERVE,
+                          mx.cpu(), "cpu")
+    assert row["exact_matches"] == row["requests"] == 5
+
+
+def test_smoke_train_phase_on_cpu(smoke, clock):
+    row = smoke.run_phase("train", clock, smoke.phase_train, TINY_TRAIN,
+                          "cpu", expect_kernel=False)
+    assert row["pallas_calls"] == 0 and len(row["losses"]) == 4
+
+
+def test_smoke_train_phase_fails_without_the_kernel(smoke, clock, capsys,
+                                                    monkeypatch):
+    """Told to expect the kernel, the phase fails the run at once when the
+    compiled program holds the dense path."""
+    monkeypatch.setattr(smoke, "train_and_check",
+                        lambda *a, **k: ({"pallas_calls": 0}, [], None))
+    with pytest.raises(SystemExit):
+        smoke.run_phase("train", clock, smoke.phase_train, TINY_TRAIN,
+                        "cpu", expect_kernel=True)
+    assert "no tpu_custom_call" in capsys.readouterr().out
+
+
+def test_smoke_multichip_phase_on_four_virtual_devices(smoke, clock):
+    row = smoke.run_phase("multichip", clock, smoke.phase_multichip,
+                          TINY_TRAIN, "cpu", jax.devices()[:4])
+    assert row["dp4"]["param_device_ids"] == [0, 1, 2, 3]
+    assert row["dp2xtp2"]["params_split"] > 0
+
+
+def test_smoke_phase_wrong_platform_fails(smoke, clock, capsys):
+    """A phase whose arrays are not where it was told fails the run."""
+    with pytest.raises(SystemExit):
+        smoke.run_phase("imperative", clock, smoke.phase_imperative,
+                        mx.cpu(), "tpu")
+    assert "arrays not on tpu" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("argv", [[], ["--chips", "4"]])
+def test_smoke_main_refuses_the_cpu(smoke, capsys, argv):
+    assert smoke.main(argv) != 0
+    out = capsys.readouterr()
+    assert out.out == "" and "needs a TPU" in out.err
+
+
+def _run(code, **env):
+    base = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    return subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                          env={**base, **env}, capture_output=True,
+                          text=True, timeout=120)
+
+
+def test_bench_parent_is_jax_free_and_child_refuses_the_cpu():
+    p = _run("import sys, bench; "
+             "print('jax' in sys.modules or 'mxnet_tpu' in sys.modules)")
+    assert p.stdout.strip() == "False", p.stderr
+    p = _run("import bench, sys; sys.exit(bench.main())",
+             MXNET_BENCH_CHILD="1", MXNET_BENCH_MODEL="bert_3_128_2")
+    row = json.loads(p.stdout.strip().splitlines()[-1])
+    assert p.returncode == 1 and row["value"] == 0.0
+    assert "measures the TPU" in row["extra"]["error"]
+
+
+@pytest.mark.parametrize("module", ["mxnet_tpu.resilience.controller",
+                                    "mxnet_tpu.serving.router"])
+def test_launcher_parents_leave_the_chip_alone(module):
+    """The multi-process launchers' parent side initializes no JAX
+    backend: a parent that did would hold the chip its children need."""
+    p = _run(f"import {module}; from jax._src import xla_bridge; "
+             "print(len(xla_bridge._backends))")
+    assert p.stdout.strip() == "0", p.stderr
+
+
+def test_compile_cache_dir_is_env_or_fixed_checkout_path(tmp_path):
+    code = ("import mxnet_tpu, jax; "
+            "print(jax.config.jax_compilation_cache_dir)")
+    assert _run(code).stdout.strip() == os.path.join(ROOT, ".jax_cache")
+    given = str(tmp_path / "cc")
+    assert _run(code, JAX_COMPILATION_CACHE_DIR=given).stdout.strip() \
+        == given

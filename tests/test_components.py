@@ -1,5 +1,5 @@
 """Estimator, CustomOp, optimize_for, opperf, im2rec, parse_log tests
-(VERDICT r2 remaining component gaps)."""
+(remaining component gaps)."""
 
 import io
 import os

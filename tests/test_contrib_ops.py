@@ -366,8 +366,8 @@ def test_masked_encdec_att_grads_flow():
 
 
 # ---------------------------------------------------------------------------
-# multihead_attention_* named wrappers (ISSUE 14 satellite; VERDICT
-# missing #2): parity against ops.contrib._dense_sdpa, the tree's ONE
+# multihead_attention_* named wrappers (ISSUE 14 satellite):
+# parity against ops.contrib._dense_sdpa, the tree's ONE
 # attention-numerics oracle.
 # ---------------------------------------------------------------------------
 

@@ -175,6 +175,52 @@ def test_telemetry_clear_clears_ledger(armed):
 
 
 # ---------------------------------------------------------------------------
+# the peak table: keyed by device_kind, no default for an unknown device
+# ---------------------------------------------------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.mark.parametrize("kind,bf16,bw", [
+    ("TPU v5 lite", 197e12, 819e9),
+    ("TPU v4", 275e12, 1228e9),
+    ("TPU v5", 459e12, 2765e9),
+])
+def test_peaks_come_from_the_device_kind_table(monkeypatch, kind, bf16, bw):
+    import jax
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", kind)])
+    assert costmodel.peak_flops("bfloat16") == bf16
+    assert costmodel.peak_flops("float32") == bf16 / 4
+    assert costmodel.peak_hbm_bytes_per_s() == bw
+
+
+def test_unknown_tpu_kind_raises_instead_of_defaulting(monkeypatch):
+    import jax
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice("tpu", "TPU v9 imaginary")])
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        costmodel.peak_flops()
+    with pytest.raises(ValueError, match="no peak"):
+        costmodel.peak_hbm_bytes_per_s()
+
+
+def test_unreachable_backend_raises_instead_of_reading_as_cpu(monkeypatch):
+    import jax
+
+    def no_backend(*a):
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        costmodel.peak_flops()
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        costmodel.roofline(1e9, 1e6)
+
+
+# ---------------------------------------------------------------------------
 # fits-per-shape estimator vs memory_analysis (the auto-sharder contract)
 # ---------------------------------------------------------------------------
 

@@ -1,4 +1,4 @@
-"""Engine-contract tests (VERDICT r2 next-round item 9).
+"""Engine-contract tests.
 
 Reference models: tests/python/unittest/test_engine.py +
 test_exc_handling.py (async error surfacing) and the NaiveEngine

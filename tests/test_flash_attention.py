@@ -1,4 +1,4 @@
-"""In-house Pallas flash-attention kernel tests (VERDICT r3 item 1).
+"""In-house Pallas flash-attention kernel tests.
 
 Interpreter-mode parity on the CPU platform: ``kernels/flash_attention.py``
 forward + custom backward against the dense fp32 oracle

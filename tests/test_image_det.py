@@ -1,4 +1,4 @@
-"""Detection data-pipeline tests (VERDICT r3 item 6; reference
+"""Detection data-pipeline tests (reference
 python/mxnet/image/detection.py + src/io ImageDetRecordIter + im2rec
 --pack-label)."""
 
@@ -165,7 +165,7 @@ def test_im2rec_pack_label_roundtrip(tmp_path):
 
 def test_ssd_example_trains_from_records(tmp_path):
     """The SSD lane fed by PACKED RECORDS instead of synthetic arrays
-    (VERDICT r3 item 6 'feed the SSD example from packed records')."""
+    ('feed the SSD example from packed records')."""
     import importlib.util
     spec = importlib.util.spec_from_file_location(
         "train_ssd", os.path.join(_ROOT, "examples", "ssd", "train_ssd.py"))

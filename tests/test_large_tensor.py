@@ -1,5 +1,5 @@
-"""Large-tensor / int64-index coverage (VERDICT r3 missing item 6;
-reference tests/nightly/test_large_array.py, SURVEY §4.1).
+"""Large-tensor / int64-index coverage (reference
+tests/nightly/test_large_array.py, SURVEY §4.1).
 
 Two tiers, mirroring the reference's nightly split:
 

@@ -1,4 +1,4 @@
-"""Multi-tensor fused optimizer tests (VERDICT r3 item 8; reference
+"""Multi-tensor fused optimizer tests (reference
 src/operator/optimizer_op.cc multi_sgd_update / multi_mp_sgd_* kernels +
 the optimizer aggregation the reference drives through
 MXNET_OPTIMIZER_AGGREGATION_SIZE)."""

@@ -33,6 +33,36 @@ def test_native_lib_builds():
         "g++ is in the image; the native recordio lane must build"
 
 
+def test_native_reuse_is_keyed_on_source_hash_not_mtime(tmp_path,
+                                                       monkeypatch):
+    """A built library is reused while the stored digest of its source
+    matches — whatever a copy did to mtimes — and rebuilt when it does
+    not; everything lands in the one build directory."""
+    monkeypatch.setenv("MXNET_NATIVE_CACHE", str(tmp_path))
+    so = native._build("librecordio.so", native._SRC)
+    assert os.path.dirname(so) == str(tmp_path)
+    assert sorted(os.listdir(tmp_path)) == ["librecordio.so",
+                                            "librecordio.so.sha256"]
+    built = os.stat(so).st_mtime_ns
+    os.utime(so, ns=(1, 1))                  # "older than the source"
+    assert native._build("librecordio.so", native._SRC) == so
+    assert os.stat(so).st_mtime_ns == 1      # reused, not rebuilt
+    with open(so + ".sha256", "w") as f:
+        f.write("digest of some other source\n")
+    native._build("librecordio.so", native._SRC)
+    assert os.stat(so).st_mtime_ns >= built  # rebuilt
+
+
+def test_native_build_failure_warns_and_falls_back(tmp_path, monkeypatch):
+    """No toolchain: a RuntimeWarning names the cause and the loader
+    returns None (callers then take the pure-python path)."""
+    monkeypatch.setenv("MXNET_NATIVE_CACHE", str(tmp_path))
+    monkeypatch.setenv("PATH", str(tmp_path))          # no g++ here
+    with pytest.warns(RuntimeWarning, match="librecordio.so unavailable"):
+        assert native._load("librecordio.so", native._SRC,
+                            native._bind) is None
+
+
 def test_native_index_matches_python_scan(tmp_path):
     rec_path, _, payloads = _write_rec(tmp_path, indexed=False)
     scan = native.index_recordio(rec_path)

@@ -222,7 +222,7 @@ def test_np_autograd_through_np_functions():
 
 
 # ---------------------------------------------------------------------------
-# delegated-surface parity extension (ISSUE 8 satellite, VERDICT weak #6):
+# delegated-surface parity extension (ISSUE 8 satellite):
 # a representative ~30-function slice across the three behavioral axes the
 # thin delegation could silently get wrong — dtype promotion, axis kwargs
 # (tuple / negative / keepdims), and python-scalar / 0-d operands.

@@ -1,4 +1,4 @@
-"""Registry-WIDE operator sweep (VERDICT r4 item 4; reference
+"""Registry-WIDE operator sweep (reference
 tests/python/unittest/test_operator.py breadth, SURVEY §4.1/§4.2).
 
 Three auto-discovered tiers over every registered kernel (aliases dedup

@@ -273,8 +273,7 @@ def test_trainstep_data_spec_empty_replicates_batch():
 def test_sharding_coverage_counters_count_each_param_once():
     """resolved + fallback covers EVERY param exactly once per resolve
     (replicated-by-empty-spec params land in fallback), independent of
-    step count — the layout-coverage contract the PROFILE.md r9 recipe
-    reads."""
+    step count — the layout-coverage contract."""
     from mxnet_tpu.telemetry import REGISTRY
     import mxnet_tpu.telemetry as tel
     mesh = parallel.DeviceMesh(shape=(4, 2), axis_names=("dp", "tp"))

@@ -1,4 +1,4 @@
-"""Registry-level sparse-storage op tests (VERDICT r3 item 7), mirroring
+"""Registry-level sparse-storage op tests, mirroring
 the reference's tests/python/unittest/test_sparse_operator.py patterns:
 dense-oracle forward parity + numeric gradients through the recorded
 tape.  Reference kernels: src/operator/tensor/dot.cc (FComputeEx csr

@@ -1,4 +1,4 @@
-"""Sparse parameter-server path tests (VERDICT r2 item 10; reference
+"""Sparse parameter-server path tests (reference
 tests/nightly/dist_sync_kvstore.py row_sparse cases + sparse optimizer
 lazy-update semantics)."""
 

@@ -1,5 +1,5 @@
 """Symbol API tests (reference tests/python/unittest/test_symbol.py +
-test_operator.py symbolic cases).  Covers VERDICT r1 item 4: auto-created
+test_operator.py symbolic cases).  Covers auto-created
 param vars, infer_shape through nn ops, bind/simple_bind fwd+bwd."""
 
 import numpy as np
@@ -99,7 +99,7 @@ def test_simple_bind_and_grad():
 
 def test_symbolic_batchnorm_aux_update():
     """BN moving stats must update during symbolic training forward
-    (FMutateInputs writeback, VERDICT r1)."""
+    (FMutateInputs writeback)."""
     data = mx.sym.var("data")
     bn = mx.sym.BatchNorm(data, name="bn")
     ex = bn.simple_bind(ctx=mx.cpu(), data=(16, 4))

@@ -1,4 +1,4 @@
-"""SyncBatchNorm contract tests (VERDICT r3 item 9; reference
+"""SyncBatchNorm contract tests (reference
 src/operator/contrib/sync_batch_norm.cc + gluon.contrib SyncBatchNorm).
 
 The absorption claim: under ``parallel.TrainStep`` (one SPMD program, the
