@@ -63,7 +63,7 @@ HOT_PATHS = {
     # the scrape handler runs per request on server threads — both must
     # stay host-sync-free and flag-disciplined
     "telemetry/costmodel.py": {"__call__", "_probe", "wrap_jit",
-                               "wrap_jit_if_armed", "_on_duration_event"},
+                               "wrap_jit_if_armed", "_on_time_span"},
     "telemetry/httpd.py": {"do_GET"},
     # perf-regression gate (ISSUE 16): the steady-state capture window is
     # the measured region of every snapshot lane — a host sync inside it

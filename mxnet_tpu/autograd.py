@@ -25,6 +25,7 @@ import threading
 
 import numpy as _np
 
+from . import regions as _regions
 from .base import MXNetError
 
 __all__ = ["record", "pause", "train_mode", "predict_mode", "is_recording",
@@ -119,7 +120,7 @@ def _current_epoch():
 class _Node:
     """One recorded op application."""
     __slots__ = ("op_name", "vjp_fn", "in_entries", "out_avals", "grads",
-                 "op", "attrs", "inputs")
+                 "op", "attrs", "inputs", "region")
 
     def __init__(self, op_name, vjp_fn, in_entries, out_avals,
                  op=None, attrs=None, inputs=None):
@@ -134,6 +135,10 @@ class _Node:
         self.op = op
         self.attrs = attrs
         self.inputs = inputs
+        # the region path the op ran in (mxnet_tpu.regions): backward
+        # re-enters it around vjp_fn under a trace, so the compiled
+        # backward's instructions carry the forward's names
+        self.region = _regions.current()
 
 
 def _entries_for(inputs):
@@ -270,8 +275,52 @@ def _freed(node):
     return node.vjp_fn is None and node.inputs is None
 
 
+def _backprop_node(n, create_graph):
+    """One node's step of backward: its vjp on the cotangents accumulated
+    in ``grads``, each input's share added to its producer or leaf."""
+    if create_graph:
+        in_grads = _recorded_vjp_call(n)
+    else:
+        cts = tuple(_coerce_ct(g, av) if g is not None
+                    else _zeros_like_aval(av)
+                    for g, av in zip(n.grads, n.out_avals))
+        in_grads = n.vjp_fn(cts[0] if len(cts) == 1 else cts)
+    for entry, g in zip(n.in_entries, in_grads):
+        if entry is None or g is None:
+            continue
+        gd = g._data if hasattr(g, "_data") else g
+        if getattr(gd, "dtype", None) is not None:
+            import jax
+            if gd.dtype == jax.dtypes.float0:
+                # gradient w.r.t. an integer-valued input (indices,
+                # lengths): carries no information and float0 supports
+                # no arithmetic — drop instead of accumulating
+                continue
+        kind = entry[0]
+        if kind == "leaf":
+            entry[1]._accumulate_grad(g)
+        else:  # ("node", node, idx)
+            _, pnode, pidx = entry
+            if _freed(pnode):
+                # the producer was freed by an earlier backward (it may
+                # even be off the tape): silent gradient loss otherwise
+                raise MXNetError(
+                    "cannot run backward: a shared subgraph was freed by "
+                    "a previous backward() (pass retain_graph=True, or "
+                    "call backward once on the combined heads)")
+            if pnode.grads is None:
+                pnode.grads = [None] * len(pnode.out_avals)
+            pnode.grads[pidx] = (g if pnode.grads[pidx] is None
+                                 else pnode.grads[pidx] + g)
+
+
 def _run_tape_backward(tape, create_graph=False):
     visited = set()
+    # one check per backward: an imperative one enters no named_scope
+    named = None
+    if _regions.tracing():
+        import jax
+        named = jax.named_scope
     for n in reversed(tape):
         if n.grads is None or all(g is None for g in n.grads):
             continue
@@ -280,40 +329,13 @@ def _run_tape_backward(tape, create_graph=False):
                 "cannot run backward through a subgraph already freed by a "
                 "previous backward() (pass retain_graph=True to keep it)")
         visited.add(n)
-        if create_graph:
-            in_grads = _recorded_vjp_call(n)
+        if named is not None and n.region:
+            # under a trace the vjp's ops, and the sums of cotangents it
+            # feeds, take the names of the region the op ran in
+            with named(n.region):
+                _backprop_node(n, create_graph)
         else:
-            cts = tuple(_coerce_ct(g, av) if g is not None
-                        else _zeros_like_aval(av)
-                        for g, av in zip(n.grads, n.out_avals))
-            in_grads = n.vjp_fn(cts[0] if len(cts) == 1 else cts)
-        for entry, g in zip(n.in_entries, in_grads):
-            if entry is None or g is None:
-                continue
-            gd = g._data if hasattr(g, "_data") else g
-            if getattr(gd, "dtype", None) is not None:
-                import jax
-                if gd.dtype == jax.dtypes.float0:
-                    # gradient w.r.t. an integer-valued input (indices,
-                    # lengths): carries no information and float0 supports
-                    # no arithmetic — drop instead of accumulating
-                    continue
-            kind = entry[0]
-            if kind == "leaf":
-                entry[1]._accumulate_grad(g)
-            else:  # ("node", node, idx)
-                _, pnode, pidx = entry
-                if _freed(pnode):
-                    # the producer was freed by an earlier backward (it may
-                    # even be off the tape): silent gradient loss otherwise
-                    raise MXNetError(
-                        "cannot run backward: a shared subgraph was freed by "
-                        "a previous backward() (pass retain_graph=True, or "
-                        "call backward once on the combined heads)")
-                if pnode.grads is None:
-                    pnode.grads = [None] * len(pnode.out_avals)
-                pnode.grads[pidx] = (g if pnode.grads[pidx] is None
-                                     else pnode.grads[pidx] + g)
+            _backprop_node(n, create_graph)
         n.grads = None
     return visited
 

@@ -27,6 +27,7 @@ import numpy as _np
 
 from ..base import MXNetError
 from .. import ndarray as nd
+from .. import regions as _regions
 from ..ndarray.ndarray import NDArray
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
@@ -96,6 +97,13 @@ class Block:
         self._name = self._prefix[:-1] if self._prefix.endswith("_") \
             else self._prefix
         self._scope = _BlockScope(self)
+        # region scope of this block's forward (mxnet_tpu.regions): a child
+        # takes the name it is registered under in its parent — the
+        # attribute or ``register_child`` name, which the user's code fixes
+        # (a prefix the user left out comes from a process-wide counter) —
+        # and a block with no parent keeps its own ``name``, so the zoo
+        # BERT's ops read ``bert/encoder/layer3/attn_qkv/…``
+        self._region = self._name
         self._children = {}
         self._reg_params = {}
         self._forward_hooks = []
@@ -135,6 +143,7 @@ class Block:
             existing = self.__dict__.get("_children")
             if existing is not None:
                 existing[name] = value
+                value._region = name
         elif isinstance(value, Parameter):
             reg = self.__dict__.get("_reg_params")
             if reg is not None:
@@ -145,6 +154,7 @@ class Block:
         if name is None:
             name = str(len(self._children))
         self._children[name] = block
+        block._region = name
 
     def register_forward_hook(self, hook):
         self._forward_hooks.append(hook)
@@ -212,7 +222,8 @@ class Block:
     def __call__(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args, **kwargs)
+        with _regions.scope(self._region):
+            out = self.forward(*args, **kwargs)
         for hook in self._forward_hooks:
             hook(self, args, out)
         return out

@@ -317,6 +317,7 @@ def _fwd_single(q, k, v, seg_q, seg_kv, causal, scale, hb, interpret):
             jax.ShapeDtypeStruct((B, H, Lq, _STAT), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd_single",
     )(*inputs)
     return out, lse[..., 0]
 
@@ -373,6 +374,7 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_k, block_h,
             pltpu.VMEM((hb, bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*inputs)
     return out, lse[..., 0]  # lse (B, H, Lq)
 
@@ -542,6 +544,7 @@ def _bwd_fused(q, k, v, seg_q, seg_kv, lse_b, delta_b, do, causal, scale,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_fused",
     )(*inputs)
 
 
@@ -620,6 +623,7 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_inputs)
 
     dk, dv = pl.pallas_call(
@@ -640,6 +644,7 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
             pltpu.VMEM((hb, bk, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_inputs)
     return dq, dk, dv
 

@@ -12,12 +12,28 @@ are preserved at every op boundary.
 
 from __future__ import annotations
 
+import functools
+
+from .. import regions
 from .registry import register
 
 
 def _jnp():
     import jax.numpy as jnp
     return jnp
+
+
+def _attention_region(fn):
+    """Run a fused attention op under the region ``attention``: the head
+    split, whichever of ``_flash`` and the dense softmax(QK^T)V the shapes
+    and the platform pick, and the head merge.  The compiled program and
+    the device trace then name attention whatever implements it; the scope
+    lies inside the op's own jit, whose transpose keeps it."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with regions.scope("attention"):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 @register("contrib.div_sqrt_dim")
@@ -138,6 +154,7 @@ def _dense_sdpa(q, k, v, seg, causal, scale):
 
 
 @register("contrib.masked_selfatt")
+@_attention_region
 def _masked_selfatt(qkv, valid_length=None, heads=1, causal=False):
     """Fused masked multi-head self-attention.
 
@@ -209,6 +226,7 @@ def _attend(q, k, v, valid_length, causal):
 
 
 @register("contrib.masked_att_qkv")
+@_attention_region
 def _masked_att_qkv(q, k, v, valid_length=None, num_kv_groups=1,
                     causal=False):
     """Masked attention over SEPARATE (B, H, L, D) q/k/v tensors — the
@@ -229,6 +247,7 @@ def _masked_att_qkv(q, k, v, valid_length=None, num_kv_groups=1,
 
 
 @register("contrib.sp_att_qkv", jit=False)
+@_attention_region
 def _sp_att_qkv(q, k, v, impl="ring", axis="sp", num_kv_groups=1,
                 causal=False):
     """Sequence-parallel attention over separate (B, H, L, D) q/k/v —
@@ -325,6 +344,7 @@ def _multihead_attention_valatt(att, v, heads=1):
 
 
 @register("contrib.multihead_attention")
+@_attention_region
 def _multihead_attention(q, k, v, valid_length=None, heads=1,
                          causal=False):
     """Fused masked multi-head attention over separate time-major
@@ -398,6 +418,7 @@ def _interleaved_matmul_encdec_valatt(kv, att, heads=1):
 
 
 @register("contrib.masked_encdec_att")
+@_attention_region
 def _masked_encdec_att(q, kv, valid_length=None, heads=1):
     """Fused masked encoder-decoder (cross) attention — the single-op TPU
     replacement for the reference's interleaved_matmul_encdec_qk →

@@ -27,13 +27,13 @@ performance path.
 from __future__ import annotations
 
 import functools
-import time as _time
 
 import numpy as _np
 
 from .base import MXNetError
 from .context import Context
 from . import ndarray as nd
+from . import regions as _regions
 from . import telemetry as _tel
 from .telemetry import costmodel as _costmodel
 from .telemetry import stepclock as _sclock
@@ -345,6 +345,45 @@ class _TracedCount(dict):
         pass
 
 
+# The four host phases of one TrainStep dispatch -> the StepClock phase
+# each feeds (telemetry.stepclock): only the device_put block is h2d, the
+# rest is the host's work to launch the program.  None of it is compute.
+_HOST_PHASES = {"bookkeeping": "enqueue", "h2d": "h2d",
+                "enqueue": "enqueue", "writeback": "enqueue"}
+
+
+class _HostPhase:
+    """``with _HostPhase("h2d", enabled):`` — one host phase of a dispatch,
+    under the name ``trainstep.<phase>``.  Always a
+    ``jax.profiler.TraceAnnotation``: inert (~0.4 us) unless a profiler
+    session is open, and then a span on the trace's host plane, on the
+    device trace's clock.  With telemetry enabled it is a
+    ``telemetry.Span`` (the same annotation plus the ring buffer) and the
+    span's own stamps feed the StepClock."""
+
+    __slots__ = ("_span", "_phase")
+
+    def __init__(self, name, enabled):
+        if enabled:
+            self._span = _ttrace.Span(_ttrace.get_tracer(),
+                                      "trainstep." + name, "trainstep", {})
+            self._phase = _HOST_PHASES[name]
+        else:
+            from jax.profiler import TraceAnnotation
+            self._span = TraceAnnotation("trainstep." + name)
+            self._phase = None
+
+    def __enter__(self):
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        if self._phase is not None:
+            _sclock.STEP_CLOCK.note(self._phase, self._span.duration_s)
+        return False
+
+
 class TrainStep:
     """One fully-fused, mesh-sharded training step.
 
@@ -467,6 +506,28 @@ class TrainStep:
                 TrainStep._flat_state(s, out)
         elif isinstance(st, NDArray):
             out.append(st)
+
+    def optimizer_state(self):
+        """What the optimizer holds for each trainable parameter, by
+        parameter name: ``{"weight": the values the update is applied to
+        (the float32 master under ``multi_precision``, else the parameter
+        itself), "state": the optimizer's own state for it — Adam's
+        ``(m, v)``, SGD's momentum, None for a stateless optimizer}`` as
+        NDArrays.  Handles, not copies: a later dispatch donates the
+        arrays behind them, so read ``_data``/``asnumpy()`` before it.
+        Empty before the first dispatch (or ``lowered``) has resolved the
+        parameters."""
+        out = {}
+        opt = self.optimizer
+        for i, p in enumerate(self._trainable or ()):
+            st = self._states[i]
+            # optimizer.create_state_multi_precision's own rule: (master,
+            # state) for a half-precision weight, the plain state otherwise
+            if opt.multi_precision and opt._is_half(p.data().dtype):
+                out[p.name] = {"weight": st[0], "state": st[1]}
+            else:
+                out[p.name] = {"weight": p.data(), "state": st}
+        return out
 
     def _resolve(self, data_nd):
         """Fix the param/state order; ``data_nd=None`` (lowering from
@@ -625,26 +686,39 @@ class TrainStep:
                     out = remat_call(net, d_nd)
                 else:
                     out = net(d_nd)
-                loss = loss_fn(out, l_nd)
-                if loss.shape:
-                    loss = loss.mean()
+                with _regions.scope("loss"):
+                    loss = loss_fn(out, l_nd)
+                    if loss.shape:
+                        loss = loss.mean()
+            # the tape re-enters each op's region around its vjp, so the
+            # backward's instructions carry the forward's names
             autograd.backward([loss])
             return loss
 
         def apply_update():
-            if fused is not None:
-                # fused flat update: same segment math as the
-                # imperative donated executables, inlined into
-                # this trace (bitwise identical to the loop below)
-                _fus.traced_update(optzr, fused[0], fused[1],
-                                   trainable, self._states)
-            else:
-                for i, p in enumerate(trainable):
-                    optzr.update_multi_precision(i, p._data,
-                                                 p._data._grad,
-                                                 self._states[i])
+            with _regions.scope("optimizer"):
+                if fused is not None:
+                    # fused flat update: same segment math as the
+                    # imperative donated executables, inlined into
+                    # this trace (bitwise identical to the loop below)
+                    _fus.traced_update(optzr, fused[0], fused[1],
+                                       trainable, self._states)
+                else:
+                    for i, p in enumerate(trainable):
+                        optzr.update_multi_precision(i, p._data,
+                                                     p._data._grad,
+                                                     self._states[i])
 
-        def raw(key, t, lr_vec, rescale, param_vals, state_vals, d, l):
+        # The function's name becomes the compiled module's (jit_train_step,
+        # jit_train_steps) and so part of JAX's compile-cache key — which
+        # the region scopes are not: they reach the module as op_name
+        # metadata, and the key strips metadata.  A tree that shares a cache
+        # directory with an older one would load that tree's executable and
+        # read its names (or none) back from it.  So a change to the scopes
+        # that telemetry reads renames the program with it (last: PR 27,
+        # from raw/raw_multi).
+        def train_step(key, t, lr_vec, rescale, param_vals, state_vals, d,
+                       l):
             import jax
             import jax.numpy as jnp
             saved_opt = (optzr._update_count, optzr._index_update_count,
@@ -716,7 +790,7 @@ class TrainStep:
                 (optzr._update_count, optzr._index_update_count,
                  optzr._get_lr, optzr.rescale_grad) = saved_opt
 
-        return raw
+        return train_step
 
     def _build(self, data, label):
         import jax
@@ -744,8 +818,8 @@ class TrainStep:
         import jax
         raw = self._make_raw()
 
-        def raw_multi(keys, ts, lr_vecs, rescale, param_vals, state_vals,
-                      d, l):
+        def train_steps(keys, ts, lr_vecs, rescale, param_vals, state_vals,
+                        d, l):
             def body(carry, xs):
                 p_vals, s_vals = carry
                 if stacked:
@@ -772,7 +846,7 @@ class TrainStep:
         if _ttrace._ENABLED:
             _M_RETRACES.inc()
         return _costmodel.wrap_jit(
-            jax.jit(raw_multi, in_shardings=in_sh, out_shardings=out_sh,
+            jax.jit(train_steps, in_shardings=in_sh, out_shardings=out_sh,
                     donate_argnums=donate), "parallel.TrainStep")
 
     def _program(self, data, label, stacked=None, steps=None):
@@ -861,61 +935,20 @@ class TrainStep:
 
         fn = self._program(data, label, stacked, steps)
 
-        # host-side bookkeeping for every step up front; per-step scalars
-        # ship as stacked traced arrays
-        from . import random as _rnd
-        n_tr = len(self._trainable)
-        ts, lr_vecs = [], []
-        for _ in range(steps):
-            self._step_count += 1
-            for i in range(n_tr):
-                self.optimizer._update_count(i)
-            ts.append(_np.float32(self.optimizer._index_update_count.get(
-                0, self._step_count)))
-            lr_vecs.append([self.optimizer._get_lr(i) for i in range(n_tr)])
-        ts = _np.asarray(ts, _np.float32)
-        lr_vecs = _np.asarray(lr_vecs, _np.float32)
-        rescale = _np.float32(self.optimizer.rescale_grad)
-        keys = jax.random.split(_rnd.get_key(), steps)
+        def bookkeeping():
+            # per-step scalars ship as stacked traced arrays; one fresh
+            # key per step from the seeded stateful stream
+            from . import random as _rnd
+            ts, lr_vecs, rescale = self._advance(steps)
+            return jax.random.split(_rnd.get_key(), steps), ts, lr_vecs, \
+                rescale
 
-        # one flag read per dispatch (graftcheck GC05); the StepClock
-        # treats each run() dispatch as one "step" — h2d is the measured
-        # device_put block, everything else lands in compute
-        enabled = _ttrace._ENABLED
-        if enabled:
-            _sclock.STEP_CLOCK.begin_step()
-            _t0 = _time.perf_counter()
-        lead = 1 if stacked else 0
-        d_sh, l_sh = self._data_shardings(len(data.shape) - lead,
-                                          len(label.shape) - lead,
-                                          stacked=stacked)
-        d = jax.device_put(data._data, d_sh)
-        l = jax.device_put(label._data, l_sh)
-        p_sh, s_sh = self._shardings()
-        p_vals = tuple(jax.device_put(p._data._data, sh)
-                       for p, sh in zip(self._params, p_sh))
-        s_vals = tuple(jax.device_put(s._data, sh)
-                       for s, sh in zip(self._state_nds, s_sh))
-
-        if enabled:
-            _sclock.STEP_CLOCK.note("h2d", _time.perf_counter() - _t0)
-            _M_STEP_DISPATCHES.inc()
-            if self._n_micro > 1:
-                _M_MICROBATCHES.inc(self._n_micro * steps)
-        new_p, new_s, losses = fn(keys, ts, lr_vecs, rescale, p_vals, s_vals,
-                                  d, l)
-        for p, v in zip(self._params, new_p):
-            p._data._set_data(v)
-        for s, v in zip(self._state_nds, new_s):
-            s._set_data(v)
-        if enabled:
-            _sclock.STEP_CLOCK.end_step()
-        return NDArray._from_data(losses)
+        return NDArray._from_data(self._dispatch(
+            fn, bookkeeping, data, label, stacked, steps))
 
     # -- call -----------------------------------------------------------------
     def __call__(self, data, label):
         """Run one step; returns the (replicated) scalar loss NDArray."""
-        import jax
         if not isinstance(data, NDArray):
             data = nd.array(data)
         if not isinstance(label, NDArray):
@@ -929,47 +962,75 @@ class TrainStep:
 
         fn = self._program(data, label)
 
-        # host-side step bookkeeping: advance the real counters, compute
-        # per-param lr (schedules, multipliers); ship as traced scalars
-        self._step_count += 1
-        for i in range(len(self._trainable)):
-            self.optimizer._update_count(i)
-        t = _np.float32(self.optimizer._index_update_count.get(
-            0, self._step_count))
-        lr_vec = _np.array([self.optimizer._get_lr(i)
-                            for i in range(len(self._trainable))], _np.float32)
-        rescale = _np.float32(self.optimizer.rescale_grad)
-        # per-step dropout key from the seeded stateful stream (mx.random.seed)
-        from . import random as _rnd
-        key = _rnd.get_key()
+        def bookkeeping():
+            # per-step dropout key from the seeded stateful stream
+            # (mx.random.seed)
+            from . import random as _rnd
+            ts, lr_vecs, rescale = self._advance(1)
+            return _rnd.get_key(), ts[0], lr_vecs[0], rescale
 
-        # one flag read per dispatch (graftcheck GC05); StepClock: the
-        # device_put block is h2d, the remainder of the step is compute
-        # (the fused trace folds comms+optimizer into one XLA program —
-        # phases inside the jit are not host-splittable)
+        return NDArray._from_data(self._dispatch(
+            fn, bookkeeping, data, label, False, 1))
+
+    def _advance(self, steps):
+        """Host-side bookkeeping of ``steps`` steps up front: advance the
+        real counters and compute per-param lr (schedules, multipliers);
+        ``(t (steps,), lr (steps, n_trainable), rescale)`` ship to the
+        program as traced float32 values."""
+        n_tr = len(self._trainable)
+        ts, lr_vecs = [], []
+        for _ in range(steps):
+            self._step_count += 1
+            for i in range(n_tr):
+                self.optimizer._update_count(i)
+            ts.append(self.optimizer._index_update_count.get(
+                0, self._step_count))
+            lr_vecs.append([self.optimizer._get_lr(i) for i in range(n_tr)])
+        return (_np.asarray(ts, _np.float32),
+                _np.asarray(lr_vecs, _np.float32).reshape(steps, n_tr),
+                _np.float32(self.optimizer.rescale_grad))
+
+    def _dispatch(self, fn, bookkeeping, data, label, stacked, steps):
+        """The host's part of one dispatch of ``fn``, in four phases
+        (module docstring of telemetry.stepclock): ``bookkeeping()``
+        returns the program's leading scalar arguments, ``h2d`` puts the
+        batch, parameters and optimizer state where the program wants
+        them, ``enqueue`` calls the jitted program — asynchronously: it
+        returns before the device has finished, so its time says nothing
+        about the chip — and ``writeback`` hands the new arrays to the
+        parameter and state handles.  Returns the program's losses (a jax
+        array)."""
+        import jax
+        # one flag read per dispatch (graftcheck GC05); the StepClock
+        # treats each dispatch as one "step"
         enabled = _ttrace._ENABLED
         if enabled:
             _sclock.STEP_CLOCK.begin_step()
-            _t0 = _time.perf_counter()
-        d_sh, l_sh = self._data_shardings(len(data.shape), len(label.shape))
-        d = jax.device_put(data._data, d_sh)
-        l = jax.device_put(label._data, l_sh)
-        p_sh, s_sh = self._shardings()
-        p_vals = tuple(jax.device_put(p._data._data, sh)
-                       for p, sh in zip(self._params, p_sh))
-        s_vals = tuple(jax.device_put(s._data, sh)
-                       for s, sh in zip(self._state_nds, s_sh))
-
+        with _HostPhase("bookkeeping", enabled):
+            scalars = bookkeeping()
+        with _HostPhase("h2d", enabled):
+            lead = 1 if stacked else 0
+            d_sh, l_sh = self._data_shardings(len(data.shape) - lead,
+                                              len(label.shape) - lead,
+                                              stacked=stacked)
+            d = jax.device_put(data._data, d_sh)
+            l = jax.device_put(label._data, l_sh)
+            p_sh, s_sh = self._shardings()
+            p_vals = tuple(jax.device_put(p._data._data, sh)
+                           for p, sh in zip(self._params, p_sh))
+            s_vals = tuple(jax.device_put(s._data, sh)
+                           for s, sh in zip(self._state_nds, s_sh))
         if enabled:
-            _sclock.STEP_CLOCK.note("h2d", _time.perf_counter() - _t0)
             _M_STEP_DISPATCHES.inc()
             if self._n_micro > 1:
-                _M_MICROBATCHES.inc(self._n_micro)
-        new_p, new_s, loss = fn(key, t, lr_vec, rescale, p_vals, s_vals, d, l)
-        for p, v in zip(self._params, new_p):
-            p._data._set_data(v)
-        for s, v in zip(self._state_nds, new_s):
-            s._set_data(v)
+                _M_MICROBATCHES.inc(self._n_micro * steps)
+        with _HostPhase("enqueue", enabled):
+            new_p, new_s, losses = fn(*scalars, p_vals, s_vals, d, l)
+        with _HostPhase("writeback", enabled):
+            for p, v in zip(self._params, new_p):
+                p._data._set_data(v)
+            for s, v in zip(self._state_nds, new_s):
+                s._set_data(v)
         if enabled:
             _sclock.STEP_CLOCK.end_step()
-        return NDArray._from_data(loss)
+        return losses

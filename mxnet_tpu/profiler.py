@@ -160,24 +160,16 @@ def dump(finished=True, profile_process="worker"):  # noqa: ARG001
 
 @contextlib.contextmanager
 def scope(name="<unk>"):
-    """Profiling scope — annotates the XLA trace, the span tracer, and the
-    ledger.  A cheap no-op (no jax import, no recording) when neither the
-    profiler nor telemetry is active."""
+    """Profiling scope — one ``telemetry.span`` (the span tracer and, while
+    a profiler session is open, the XLA trace's host plane, under the same
+    name) plus a ledger row.  A cheap no-op (no jax import, no recording)
+    when neither the profiler nor telemetry is active."""
     if not (_state["running"] or telemetry.enabled()):
         yield
         return
-    ann_cm = contextlib.nullcontext()
-    if _state["xla_trace"]:
-        try:
-            import jax
-            ann = getattr(jax.profiler, "TraceAnnotation", None)
-            if ann is not None:
-                ann_cm = ann(name)
-        except Exception:
-            pass
     t0 = time.perf_counter()
     try:
-        with telemetry.span(f"scope:{name}", "scope"), ann_cm:
+        with telemetry.span(f"scope:{name}", "scope"):
             yield
     finally:
         if _state["running"]:

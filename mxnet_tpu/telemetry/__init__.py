@@ -8,7 +8,9 @@ table):
   manager recording begin/end host timestamps into a ring buffer;
   ``chrome_trace()`` exports genuine Chrome-trace JSON (``traceEvents`` with
   ``ph:"X"``, ``pid``/``tid``, ``cat``, ``args``, ``process_name``/
-  ``thread_name`` metadata) for chrome://tracing / Perfetto.
+  ``thread_name`` metadata) for chrome://tracing / Perfetto.  A span is also
+  a ``jax.profiler.TraceAnnotation`` of the same name: one name on both
+  timelines, the second on the device trace's clock.
 - **metrics** (`metrics`) — process-global Counter/Gauge/Histogram registry
   (optionally labeled) with Prometheus-text and JSON exporters.
 - **ledger** (`ledger`) — the per-op aggregate table mx.profiler renders.
@@ -20,10 +22,14 @@ The distributed observability plane (ISSUE 10) sits on top:
   Chrome trace (pid=rank) + merged Prometheus snapshot on rank 0 /
   ``tools/telemetry_report.py``; decode-pool workers ship counters back
   on their task-ack channel.
-- **stepclock** — per-step data_wait/h2d/compute/comms/optimizer
+- **stepclock** — per-step data_wait/h2d/enqueue/compute/comms/optimizer
   attribution from Trainer/TrainStep, ``mxnet_step_phase_seconds{phase=}``
-  histograms, and the rolling input-/comms-/compute-bound verdict
-  rendered by ``telemetry.report()``.
+  histograms, and the rolling input-/host-/comms-/compute-bound verdict
+  rendered by ``telemetry.report()``.  A ``TrainStep`` phase is host time
+  of one dispatch: ``h2d`` (the ``device_put`` block) and ``enqueue``
+  (bookkeeping, the asynchronous call of the jitted program, writeback) —
+  never compute, which only a device trace sees.  The same four intervals
+  are ``trainstep.*`` ``TraceAnnotation``s whether telemetry is on or not.
 - **flightrec** — the always-on crash black box: bounded postmortem dumps
   on unhandled exceptions, deadline-exceeded, chaos exits, SIGTERM, and
   SIGUSR2 (``MXNET_FLIGHTREC*`` knobs).
@@ -34,7 +40,9 @@ The analytic performance observatory (ISSUE 12) completes the stack:
   every jit boundary the runtime owns (XLA's own flops/bytes/HBM numbers,
   no hardware needed), analytic MFU + roofline verdicts
   (``report(cost=True)``, BENCH rows), and the fits-per-shape
-  ``estimate_memory`` API (``MXNET_COSTMODEL`` knobs).
+  ``estimate_memory`` API (``MXNET_COSTMODEL`` knobs); and, always on,
+  ``mxnet_jit_build_seconds{site=,stage=}``: what a wrapped site's
+  dispatches spent in JAX's trace, lower and load stages.
 - **httpd** — the live scrape plane (``MXNET_TELEMETRY_PORT``):
   ``/metrics`` Prometheus exposition, ``/statusz`` run status,
   ``/ledger.json``.
@@ -48,7 +56,8 @@ fallbacks}_total`` and ``mxnet_resilience_retry_backoff_seconds``.  Everything i
 one flag: ``MXNET_TELEMETRY=1`` in the environment, ``telemetry.enable()``
 at runtime, or implicitly via ``mx.profiler.start()``.  When the flag is
 off, the dispatch hot path pays exactly one module-attribute check and the
-non-hot paths one no-op span; nothing here imports jax.
+non-hot paths one no-op span; importing this package imports no jax (the
+first recorded span and the first wrapped jit do).
 """
 
 from __future__ import annotations

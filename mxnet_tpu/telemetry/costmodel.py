@@ -19,6 +19,15 @@ into a first-class observability layer (ISSUE 12 tentpole):
   ``memory_analysis`` argument/output/temp bytes → a per-device peak-HBM
   estimate.  Disarmed, the wrapper costs one module-flag read per call
   (and the per-op dispatch path is not wrapped at all).
+- **build stages** (``mxnet_jit_build_seconds{site=,stage=}``, always on,
+  armed or not) — of a dispatch through :func:`wrap_jit` that built
+  something, the seconds JAX reports for each stage of the build:
+  ``trace`` (the Python trace to a jaxpr: for ``parallel.TrainStep`` the
+  package's own tape runs here), ``lower`` (jaxpr to MLIR module) and
+  ``load`` (``backend_compile_duration``: on this JAX the compile-cache
+  look-up, a real compile on a miss, and loading the executable).  It
+  costs nothing until something is built: the wrapper compares one
+  module-level counter before and after the call.
 - **analytic MFU / roofline** (:func:`roofline`, :func:`lane_summary`) —
   ledger flops + a measured step wall-time give *analytic MFU* (the flops
   XLA counted, not a hand-derived 6N formula), arithmetic intensity, and
@@ -45,6 +54,7 @@ Import discipline: jax is imported lazily inside the armed paths only —
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 import warnings
@@ -93,6 +103,23 @@ _TPU_PEAKS = {
     "TPU v4": (275e12, 1228e9),
     "TPU v5": (459e12, 2765e9),       # v5p
 }
+
+# Build stages ride the same jax.monitoring events as time spans, banked
+# whether armed or not.  A traced program's nested jits report their own
+# trace inside the outer one's, so a stage's seconds are the union of its
+# spans, never their sum.  The ring is bounded: spans nobody claims (the
+# per-op dispatch path is not wrapped) fall off its end.
+_BUILD_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "load",
+}
+_BUILD_SPANS: collections.deque = collections.deque(maxlen=4096)
+_BUILD_TICK = 0     # spans banked so far; wrappers compare it around a call
+_BUILD_HELP = (
+    "Seconds of a wrap_jit site's dispatches spent building, by stage: "
+    "trace (Python trace to jaxpr), lower (jaxpr to MLIR), load (compile "
+    "cache look-up, compile on a miss, executable load).")
 
 _M_EXECUTABLES = _metrics.counter(
     "mxnet_costmodel_executables_total",
@@ -155,30 +182,53 @@ def disarm():
 
 
 def _install_listener():
-    """Attribute jax's compile-phase duration events (trace / lower /
-    backend-compile) to the site currently dispatching on this thread."""
+    """Attribute jax's compile-phase events (trace / lower /
+    backend-compile) to the site currently dispatching on this thread:
+    their durations for the armed ledger's pool, their time spans for the
+    build stages."""
     global _LISTENER_INSTALLED
     with _lock:
         if _LISTENER_INSTALLED:
             return
         _LISTENER_INSTALLED = True
-    try:
-        import jax.monitoring as jm
-        jm.register_event_duration_secs_listener(_on_duration_event)
-    except Exception:  # noqa: BLE001 — no jax (offline report tooling)
-        pass
+    import jax.monitoring as jm
+    jm.register_event_time_span_listener(_on_time_span)
 
 
-def _on_duration_event(name, seconds, **kwargs):  # noqa: ARG001
-    global _COMPILE_TICK
-    if not _ARMED or "/compile/" not in name:
-        return   # disarmed-era compiles must not bank (the listener
-        #          stays registered across disarm/arm cycles)
-    if getattr(_ANALYSIS_TLS, "active", False):
-        return   # the ledger's own AOT compiles must not bank/tick
+def _on_time_span(name, start, end, **kwargs):  # noqa: ARG001
+    global _BUILD_TICK, _COMPILE_TICK
+    stage = _BUILD_STAGE_OF.get(name)
+    if stage is None or getattr(_ANALYSIS_TLS, "active", False):
+        return   # the ledger's own AOT compiles are no dispatch's build
+    #          and must not bank/tick
     with _pending_lock:
-        _PENDING_COMPILE_S.append(float(seconds))
-        _COMPILE_TICK += 1
+        _BUILD_SPANS.append((threading.get_ident(), stage, start, end))
+        _BUILD_TICK += 1
+        if _ARMED:   # disarmed-era compiles must not reach the armed pool
+            _PENDING_COMPILE_S.append(float(end - start))
+            _COMPILE_TICK += 1
+
+
+def _bank_build(site, n_spans):
+    """Credit ``site`` with what this thread built during the dispatch
+    that just returned: the last ``n_spans`` banked spans, by stage."""
+    me = threading.get_ident()
+    with _pending_lock:
+        spans = list(_BUILD_SPANS)[-n_spans:]
+    by_stage: dict = {}
+    for tid, stage, start, end in spans:
+        if tid == me:
+            by_stage.setdefault(stage, []).append((start, end))
+    for stage, intervals in by_stage.items():
+        total, reached = 0.0, float("-inf")
+        for start, end in sorted(intervals):
+            if end > reached:
+                total += end - max(start, reached)
+                reached = end
+        # a gauge by the registry's naming rule (GC09: only ``_total``
+        # names a counter); it only ever grows
+        _metrics.gauge("mxnet_jit_build_seconds", _BUILD_HELP,
+                       labels={"site": site, "stage": stage}).inc(total)
 
 
 _ANALYSIS_TLS = threading.local()
@@ -408,7 +458,9 @@ class _InstrumentedJit:
     read.  The armed steady-state cost is lock-free: a local call-count
     bump, one thread-local set/restore pair, and one C++ cache-size probe
     — analysis work happens only when the cache GREW (a compile, which
-    already cost seconds)."""
+    already cost seconds).  Armed or not, a dispatch during which this
+    thread built something banks the build's stages under the site
+    (``mxnet_jit_build_seconds``): one counter compare per call."""
 
     __slots__ = ("_jf", "site", "_nexec", "_calls", "_tick", "__weakref__")
 
@@ -421,14 +473,18 @@ class _InstrumentedJit:
         #                     arming AFTER an executable was built still
         #                     records it lazily on its next dispatch
         LEDGER._register(self)
+        _install_listener()
 
     def __getattr__(self, name):        # .lower / ._cache_size passthrough
         return getattr(self._jf, name)
 
     def __call__(self, *args, **kwargs):
-        if not _ARMED:
-            return self._jf(*args, **kwargs)
+        built = _BUILD_TICK
         out = self._jf(*args, **kwargs)
+        if built != _BUILD_TICK:            # something was built meanwhile
+            _bank_build(self.site, _BUILD_TICK - built)
+        if not _ARMED:
+            return out
         self._calls += 1
         if self._tick != _COMPILE_TICK:     # something compiled: was it us?
             self._probe(args, kwargs)

@@ -8,12 +8,20 @@ continuously: instrumented chokepoints split every optimizer step into
   noted between steps and folded into the step they fed);
 - ``h2d``        — host→device transfer of the batch and state
   (``parallel.TrainStep``'s ``device_put`` block);
-- ``compute``    — forward/backward/dispatch; also absorbs all
-  *unattributed* step time (user code between steps), so the five phases
-  always sum to the step's wall time;
+- ``enqueue``    — the host's work to launch one fused ``TrainStep``
+  program: step bookkeeping, the call of the jitted program, writing the
+  new handles back.  The call is asynchronous — it returns before the
+  device has finished — so this is host time, never compute: what the chip
+  did meanwhile only a device trace says;
+- ``compute``    — forward/backward/dispatch of the imperative
+  ``gluon.Trainer`` path; also absorbs all *unattributed* step time (user
+  code between steps; around a ``TrainStep`` that is mostly the host
+  waiting for the device's results), so the phases always sum to the
+  step's wall time;
 - ``comms``      — gradient reduction (``trainer.allreduce``, which wraps
   the kvstore pushpull / fused psum path);
-- ``optimizer``  — the weight update.
+- ``optimizer``  — the weight update (imperative path; a ``TrainStep``
+  fuses it into its program).
 
 ``gluon.Trainer.step`` and ``parallel.TrainStep`` drive the process-global
 ``STEP_CLOCK`` whenever telemetry is enabled (callers gate on the tracer
@@ -21,14 +29,21 @@ flag — this module reads no flags itself, keeping graftcheck GC05 happy).
 Every finished step observes into the ``mxnet_step_phase_seconds`` labeled
 histograms and a bounded rolling window (``MXNET_STEPCLOCK_WINDOW``) from
 which :func:`StepClock.summary` computes per-phase medians and the rolling
-**verdict**: ``input-bound`` (data_wait + h2d dominate), ``comms-bound``,
+**verdict**: ``input-bound`` (data_wait + h2d dominate), ``host-bound``
+(enqueue: the host cannot launch programs fast enough), ``comms-bound``,
 or ``compute-bound`` (compute + optimizer).  ``telemetry.report()`` renders
 the table; ``tools/telemetry_report.py`` renders it per rank from exported
 snapshots.
 
 A ``TrainStep`` "step" is one jitted dispatch — with ``run(steps=K)`` that
 is K fused steps, so phase times are per *dispatch*; the verdict is
-unaffected (it compares shares, not absolutes).
+unaffected (it compares shares, not absolutes).  Its four host phases are
+``jax.profiler.TraceAnnotation``s named ``trainstep.bookkeeping``,
+``trainstep.h2d``, ``trainstep.enqueue`` and ``trainstep.writeback``,
+always in the code: in a profiler session they lie on the trace's host
+plane, on the device trace's clock.  With telemetry enabled the same
+intervals are ``telemetry.Span``s and feed this clock: ``h2d`` to ``h2d``,
+the other three to ``enqueue``.
 
 Stdlib-only; nothing here imports jax.
 """
@@ -44,11 +59,12 @@ from . import metrics as _metrics
 
 __all__ = ["PHASES", "StepClock", "STEP_CLOCK", "report"]
 
-PHASES = ("data_wait", "h2d", "compute", "comms", "optimizer")
+PHASES = ("data_wait", "h2d", "enqueue", "compute", "comms", "optimizer")
 
 # verdict label -> the phases whose medians it aggregates
 VERDICT_GROUPS = {
     "input-bound": ("data_wait", "h2d"),
+    "host-bound": ("enqueue",),
     "comms-bound": ("comms",),
     "compute-bound": ("compute", "optimizer"),
 }
@@ -57,7 +73,7 @@ _PHASE_HIST = {
     p: _metrics.histogram(
         "mxnet_step_phase_seconds",
         "Per-step wall seconds attributed to each phase of the training "
-        "step (data_wait/h2d/compute/comms/optimizer).",
+        "step (data_wait/h2d/enqueue/compute/comms/optimizer).",
         labels={"phase": p})
     for p in PHASES
 }
@@ -190,8 +206,8 @@ class StepClock:
                 "verdict": verdict}
 
     def verdict(self):
-        """The rolling bottleneck verdict: 'input-bound' / 'comms-bound' /
-        'compute-bound' ('idle' with no recorded steps)."""
+        """The rolling bottleneck verdict: 'input-bound' / 'host-bound' /
+        'comms-bound' / 'compute-bound' ('idle' with no recorded steps)."""
         return self.summary()["verdict"]
 
     def reset(self):
@@ -228,12 +244,9 @@ def report(clock=None, registry=None):
         lines.append(
             f"  {p:<10} {ph['median'] * 1e3:>10.3f} {ph['p90'] * 1e3:>10.3f}"
             f" {ph['mean'] * 1e3:>10.3f} {share:>6.0%}")
-    shares = {k: v / total_med for k, v in s["groups"].items()}
-    lines.append(
-        f"verdict: {s['verdict']} "
-        f"(input {shares['input-bound']:.0%} / "
-        f"comms {shares['comms-bound']:.0%} / "
-        f"compute {shares['compute-bound']:.0%})")
+    shares = " / ".join(f"{label.split('-')[0]} {v / total_med:.0%}"
+                        for label, v in s["groups"].items())
+    lines.append(f"verdict: {s['verdict']} ({shares})")
     counters = []
     for name in ("mxnet_trainer_steps_total",
                  "mxnet_sharding_step_dispatches_total",

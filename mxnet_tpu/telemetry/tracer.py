@@ -6,11 +6,18 @@ host timestamps into a bounded ring buffer; ``chrome_trace()`` renders the
 buffer as genuine Chrome-trace JSON (``traceEvents`` with ``ph:"X"`` complete
 events) that chrome://tracing / Perfetto load directly.
 
+One span primitive, two timelines: a ``Span`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so while a profiler
+session is open the span lands on the host plane of the profiler's own
+trace, on the device trace's clock (``perf_counter_ns`` stamps in the ring
+buffer cannot be laid beside device events).  Outside a session the
+annotation is inert (~0.4 us).
+
 Overhead discipline: recording is gated on the module-level ``_ENABLED``
 flag.  When off, ``span()`` returns a shared stateless no-op context manager
 and hot paths (ops.registry dispatch) skip instrumentation after a single
-flag check.  Nothing here imports jax — the module is safe to import on any
-hot path.
+flag check.  Importing the module imports no jax (safe on any hot path and
+for the offline report tooling); the first recorded ``Span`` does.
 """
 
 from __future__ import annotations
@@ -53,15 +60,18 @@ NULL_SPAN = _NullSpan()
 class Span:
     """One timed region.  Context-manager; records on exit."""
 
-    __slots__ = ("_tracer", "name", "category", "attrs", "_t0", "_t1")
+    __slots__ = ("_tracer", "name", "category", "attrs", "_t0", "_t1",
+                 "_annotation")
 
     def __init__(self, tracer, name, category, attrs):
+        from jax.profiler import TraceAnnotation
         self._tracer = tracer
         self.name = name
         self.category = category
         self.attrs = attrs
         self._t0 = None
         self._t1 = None
+        self._annotation = TraceAnnotation(name)
 
     def set(self, **attrs):
         """Attach attributes mid-span (rendered under Chrome-trace args)."""
@@ -75,11 +85,13 @@ class Span:
         return (self._t1 - self._t0) / 1e9
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         self._t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         self._tracer.add_event(self.name, self.category, self._t0, self._t1,
                                self.attrs)
         return False

@@ -193,8 +193,11 @@ def test_hedge_fires_and_loser_cancelled(tmp_path):
         assert h.stats()["hedged"]
         assert wall < 1.5, f"hedge should beat the 2s straggler: {wall}"
         cancel_log = tmp_path / "cancels-0000.log"
-        _wait(cancel_log.exists, msg="loser cancel log")
-        assert h.rid in cancel_log.read_text().split()
+        # the replica opens the log, then writes the line: wait for the
+        # line, not for the file (read between the two it is empty)
+        _wait(lambda: cancel_log.exists()
+              and h.rid in cancel_log.read_text().split(),
+              msg="loser cancel log")
     finally:
         r.stop()
 

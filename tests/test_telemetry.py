@@ -263,18 +263,35 @@ def test_scope_cheap_noop_when_stopped():
     assert _events() == []
 
 
-def test_scope_records_without_trace_annotation(monkeypatch):
+def test_scope_is_one_span_on_both_timelines(monkeypatch):
+    """profiler.scope has no annotation path of its own: the telemetry span
+    it opens is the ring-buffer event and the profiler's TraceAnnotation,
+    under one name."""
     import jax
     monkeypatch.setattr(jax.profiler, "start_trace",
                         lambda d: None, raising=False)
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: None, raising=False)
-    monkeypatch.delattr(jax.profiler, "TraceAnnotation", raising=False)
+    annotated = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            annotated.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
     profiler.start()
     with profiler.scope("annotated"):
         pass
     profiler.stop()
+    assert annotated == ["scope:annotated"]
     assert "scope:annotated" in telemetry.ledger.snapshot()
+    assert [e["name"] for e in _events()] == ["scope:annotated"]
 
 
 def test_pause_then_stop_closes_xla_trace(monkeypatch):

@@ -54,8 +54,8 @@ def _rank_row(snap):
     sc = snap.get("stepclock") or {}
     phases = sc.get("phases") or {}
     meds = {p: (phases.get(p) or {}).get("median", 0.0)
-            for p in ("data_wait", "h2d", "compute", "comms", "optimizer",
-                      "total")}
+            for p in ("data_wait", "h2d", "enqueue", "compute", "comms",
+                      "optimizer", "total")}
     counters = {}
     for e in snap.get("metrics", ()):
         if e.get("kind") == "counter" and e.get("value"):
@@ -121,15 +121,16 @@ def main(argv=None):
     else:
         print(f"telemetry report — {len(rows)} rank(s) from {args.dir}")
         hdr = (f"  {'rank':>4} {'steps':>5} {'spans':>6} {'verdict':<14} "
-               f"{'data_wait':>10} {'h2d':>8} {'compute':>9} {'comms':>8} "
-               f"{'optimizer':>10}   (median ms)")
+               f"{'data_wait':>10} {'h2d':>8} {'enqueue':>9} {'compute':>9} "
+               f"{'comms':>8} {'optimizer':>10}   (median ms)")
         print(hdr)
         for r in rows:
             m = r["phase_median_ms"]
             print(f"  {r['rank']:>4} {r['steps']:>5} {r['spans']:>6} "
                   f"{r['verdict']:<14} {m['data_wait']:>10.3f} "
-                  f"{m['h2d']:>8.3f} {m['compute']:>9.3f} "
-                  f"{m['comms']:>8.3f} {m['optimizer']:>10.3f}")
+                  f"{m['h2d']:>8.3f} {m['enqueue']:>9.3f} "
+                  f"{m['compute']:>9.3f} {m['comms']:>8.3f} "
+                  f"{m['optimizer']:>10.3f}")
         tally: dict = {}
         for r in rows:
             tally[r["verdict"]] = tally.get(r["verdict"], 0) + 1
