@@ -258,12 +258,45 @@ def _softmax_activation(data, mode="instance"):
 
 @register("softmax_cross_entropy")
 def _softmax_cross_entropy(data, label):
+    """Sum over the rows of ``logsumexp(data) - data[label]``.
+
+    reference src/operator/loss_binary_op.cc.  custom_vjp, so that no array
+    of ``data``'s shape outlives the forward except ``data`` itself: the
+    residuals are ``data`` as it arrived, the per-row logsumexp and the
+    label, and the backward recomputes the softmax from them and writes
+    ``(softmax - onehot) * g`` in one elementwise pass.  The label's place
+    is an iota comparison (no scatter, no one-hot array); reductions run in
+    float32 for narrower ``data``.  ``label`` holds class indices in
+    [0, classes), integer or float, and gets no gradient.
+    """
     import jax
     jnp = _jnp()
-    logp = jax.nn.log_softmax(data, axis=-1)
-    nll = -jnp.take_along_axis(
-        logp, label.astype(jnp.int32).reshape(-1, 1), axis=-1)
-    return jnp.sum(nll)
+    lax = _lax()
+    acc = jnp.promote_types(data.dtype, jnp.float32)
+
+    def at_label(l, shape):
+        classes = lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+        return classes == l.astype(jnp.int32).reshape(-1, 1)
+
+    @jax.custom_vjp
+    def f(x, l):
+        return f_fwd(x, l)[0]
+
+    def f_fwd(x, l):
+        xf = x.astype(acc)
+        lse = jax.nn.logsumexp(xf, axis=-1, keepdims=True)
+        picked = jnp.sum(jnp.where(at_label(l, x.shape), xf, 0), axis=-1,
+                         keepdims=True)
+        return jnp.sum(lse - picked).astype(x.dtype), (x, lse, l)
+
+    def f_bwd(res, g):
+        x, lse, l = res
+        p = jnp.exp(x.astype(acc) - lse)
+        grad = jnp.where(at_label(l, x.shape), p - 1, p) * g.astype(acc)
+        return grad.astype(x.dtype), None
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(data, label)
 
 
 def _softmax_output_fwd(data, label, grad_scale, ignore_label,

@@ -772,7 +772,7 @@ def estimate_memory(model_cfg, mesh_shape, rule_pack, batch, seq=None,
     Returns a breakdown dict whose ``total_bytes`` is the estimated
     steady-state peak for a donated step: live arguments (params +
     optimizer state + batch) plus the backward working set (gradients +
-    saved activations + the fp32 logits head + the fsdp gather
+    saved activations + the logits the loss keeps + the fsdp gather
     working set).  Validated against ``memory_analysis`` on the dryrun
     llama lanes: 2.6% off on (2,2,2) dp×tp×sp, ~1% on dp×fsdp
     (gather term = half the full-along-fsdp weight bytes, measured),
@@ -851,10 +851,10 @@ def estimate_memory(model_cfg, mesh_shape, rule_pack, batch, seq=None,
                 act_elems += tokens_act * in_f
             act_elems += tokens_act * out_f
 
-    # fp32 logits head: softmax_cross_entropy upcasts and saves both the
-    # logits and their softmax for backward
+    # logits head: softmax_cross_entropy saves the logits it was given and
+    # a per-row logsumexp; its backward recomputes the softmax from them
     v = int(vocab) if vocab else inferred_vocab
-    logits_b = 2 * tokens_act * v * 4 if v else 0
+    logits_b = tokens_act * v * 4 if v else 0
     # gradients live as temps through backward + the fused update; a
     # microbatched step additionally carries the accumulator, and under
     # fsdp the in-scan per-microbatch gradients are FULL along fsdp
