@@ -171,8 +171,15 @@ def _record(op, vjp_fn, inputs, outputs, attrs=None):
 
 
 def _zeros_like_aval(aval):
+    """The cotangent of an output nothing downstream differentiated: zeros
+    of its type, or ``float0`` zeros for an integer-valued output (the
+    expert ids of ``contrib.moe_router``), which is what ``jax.vjp`` takes
+    there."""
+    import jax
     import jax.numpy as jnp
     shape, dtype = aval
+    if not jnp.issubdtype(dtype, jnp.inexact):
+        return _np.zeros(shape, jax.dtypes.float0)
     return jnp.zeros(shape, dtype)
 
 

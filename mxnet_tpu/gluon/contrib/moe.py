@@ -19,6 +19,14 @@ dispatch so XLA tiles everything onto the MXU, no dynamic gather/scatter:
    across devices and inserts the all-to-alls over ICI;
  - auxiliary load-balance loss (Switch eq. 4): E · Σ_e f_e · p_e, returned
    alongside the output so callers add ``aux_weight * aux`` to their loss.
+
+``DroplessMoE`` is the second design, the one DeepSeek-V3-shaped models
+train with: a sigmoid router with a choice-only bias over ALL experts of the
+layer, no capacity and no dropped token, bias-free SwiGLU experts run as
+grouped matrix products over the (token, expert) pairs sorted by expert
+(``ops/moe.py``), shared experts every token passes, and a notion of the
+experts THIS device holds: the layer expert parallelism needs, run without
+its exchange.
 """
 
 from __future__ import annotations
@@ -26,9 +34,10 @@ from __future__ import annotations
 import math
 
 from ..block import HybridBlock
+from ..nn import Dense
 from ...base import MXNetError
 
-__all__ = ["SparseMoE"]
+__all__ = ["SparseMoE", "DroplessMoE", "SwiGLU"]
 
 
 class SparseMoE(HybridBlock):
@@ -154,3 +163,129 @@ class SparseMoE(HybridBlock):
         # Switch load-balance loss: E * sum_e (token fraction_e * prob mass_e)
         aux = F.sum(f_frac * F.mean(probs, axis=0)) * E
         return y, aux
+
+
+class SwiGLU(HybridBlock):
+    """Bias-free gated feed-forward: ``down(silu(gate x) * up x)``."""
+
+    def __init__(self, units, hidden_size, **kwargs):
+        super().__init__(**kwargs)
+        with self.name_scope():
+            self.gate = Dense(hidden_size, flatten=False, use_bias=False,
+                              in_units=units, prefix="gate_")
+            self.up = Dense(hidden_size, flatten=False, use_bias=False,
+                            in_units=units, prefix="up_")
+            self.down = Dense(units, flatten=False, use_bias=False,
+                              in_units=hidden_size, prefix="down_")
+
+    def hybrid_forward(self, F, x):
+        return self.down(F.silu(self.gate(x)) * self.up(x))
+
+
+class _Router(HybridBlock):
+    """Sigmoid top-k router with a choice-only bias; ``(weights (N, k)
+    float32, experts (N, k) int32)`` of ``contrib.moe_router``.  The bias
+    takes no gradient (``grad_req="null"``): what moves it in the published
+    recipe is a balancing rule outside the loss."""
+
+    def __init__(self, units, num_experts, k, scale, normalize, **kwargs):
+        super().__init__(**kwargs)
+        self._attrs = dict(k=int(k), scale=float(scale),
+                           normalize=bool(normalize))
+        with self.name_scope():
+            self.weight = self.params.get(
+                "weight", shape=(num_experts, units), init=None)
+            self.bias = self.params.get(
+                "bias", shape=(num_experts,), init="zeros", grad_req="null")
+
+    def hybrid_forward(self, F, x, weight, bias):
+        return F.contrib.moe_router(x, weight, bias, **self._attrs)
+
+
+class _Experts(HybridBlock):
+    """The routed experts held here, stacked: ``gate``/``up`` (count, U, I)
+    and ``down`` (count, I, U), the layout the grouped products take,
+    sharded over ``expert_axis`` where a mesh has it."""
+
+    def __init__(self, units, hidden_size, first, count, expert_axis,
+                 **kwargs):
+        super().__init__(**kwargs)
+        self._first = int(first)
+        with self.name_scope():
+            self.gate = self.params.get(
+                "gate", shape=(count, units, hidden_size), init=None)
+            self.up = self.params.get(
+                "up", shape=(count, units, hidden_size), init=None)
+            self.down = self.params.get(
+                "down", shape=(count, hidden_size, units), init=None)
+        for p in (self.gate, self.up, self.down):
+            p.sharding = (expert_axis, None, None)
+
+    def hybrid_forward(self, F, x, weights, experts, gate, up, down):
+        return F.contrib.moe_experts(x, weights, experts, gate, up, down,
+                                     first=self._first)
+
+
+class DroplessMoE(HybridBlock):
+    """Dropless routed feed-forward with shared experts (DeepSeek-V3's).
+
+    Parameters
+    ----------
+    units : int — model width.
+    hidden_size : int — width of one routed expert (a bias-free SwiGLU).
+    num_experts : int — experts of the LAYER: the router's width.
+    num_experts_per_token : int — k.
+    experts_held : (first, count) — the experts this device holds; None =
+        all of them.  A token's pairs on other experts add nothing here:
+        their device would add them.
+    num_shared_experts : int — shared experts, run as one SwiGLU of width
+        ``num_shared_experts * hidden_size`` on every token (0 = none).
+    routed_scaling_factor, norm_topk_prob : the router's scale, and whether
+        the chosen weights are divided by their sum first.
+
+    ``__call__(x) -> y`` with x (B, L, units) or (N, units); y = routed +
+    shared.  No capacity, no dropped token, no auxiliary loss.  Children:
+    ``router``, ``experts``, ``shared``.  Under a ``parallel.TrainStep`` the
+    layer reports, per step, ``mxnet_moe_pairs_total{layer}`` (pairs that
+    fell on held experts), ``mxnet_moe_tokens_total`` (tokens routed, summed
+    over layers) and ``mxnet_moe_expert_tokens_max{layer}`` (the fullest
+    held expert of a step) through ``parallel.report_counter``.
+    """
+
+    def __init__(self, units, hidden_size, num_experts,
+                 num_experts_per_token, experts_held=None,
+                 num_shared_experts=0, routed_scaling_factor=1.0,
+                 norm_topk_prob=True, expert_axis="ep", **kwargs):
+        super().__init__(**kwargs)
+        first, count = experts_held or (0, num_experts)
+        if num_experts_per_token > num_experts:
+            raise MXNetError("num_experts_per_token > num_experts")
+        if first < 0 or count < 1 or first + count > num_experts:
+            raise MXNetError(
+                f"experts_held {(first, count)} is not a range of the "
+                f"layer's {num_experts} experts")
+        self._units = units
+        with self.name_scope():
+            self.router = _Router(units, num_experts, num_experts_per_token,
+                                  routed_scaling_factor, norm_topk_prob,
+                                  prefix="router_")
+            self.experts = _Experts(units, hidden_size, first, count,
+                                    expert_axis, prefix="experts_")
+            self.shared = SwiGLU(units, num_shared_experts * hidden_size,
+                                 prefix="shared_") \
+                if num_shared_experts else None
+
+    def hybrid_forward(self, F, x):
+        from ... import parallel, regions
+        xf = F.reshape(x, shape=(-1, self._units))              # (N, U)
+        weights, chosen = self.router(xf)
+        y, tokens = self.experts(xf, weights, chosen)
+        layer = {"layer": regions.current()}
+        parallel.report_counter("mxnet_moe_pairs_total", tokens,
+                                labels=layer)
+        parallel.report_counter("mxnet_moe_tokens_total", xf.shape[0])
+        parallel.report_counter("mxnet_moe_expert_tokens_max", tokens,
+                                labels=layer, kind="max")
+        if self.shared is not None:
+            y = y + self.shared(xf)
+        return F.reshape(y, shape=x.shape)

@@ -11,7 +11,7 @@ from . import bert  # noqa: F401
 
 def __getattr__(name):
     import importlib
-    if name in ("vision", "llama", "transformer", "yolo"):
+    if name in ("vision", "llama", "mla_moe", "transformer", "yolo"):
         mod = importlib.import_module(f".{name}", __name__)
         globals()[name] = mod
         return mod

@@ -58,8 +58,24 @@ class RMSNorm(HybridBlock):
         return (out * weight.astype("float32")).astype(x.dtype)
 
 
-def _rope(F, x, base=500000.0):
-    """Rotary embeddings over the last dim; x: (B, H, L, D)."""
+def _rope(F, x, base=500000.0, rotate=None, interleaved=False):
+    """Rotary embeddings over the last dim; x: (B, H, L, D).  ``base``: the
+    published ``rope_theta``.  ``rotate=(begin, end)``: only that slice of
+    the last dim turns, the rest passes through (latent attention keeps a
+    position-free part of each head).  ``interleaved``: the slice holds its
+    pairs as (2i, 2i+1), DeepSeek's ``rope_interleave``; it is
+    de-interleaved to [evens | odds] and leaves in that order, which is the
+    rotate-half form every other caller has."""
+    if rotate is not None:
+        begin, end = rotate
+        D = x.shape[3]
+        parts = [_rope(F, x[:, :, :, begin:end], base,
+                       interleaved=interleaved)]
+        if begin:
+            parts.insert(0, x[:, :, :, :begin])
+        if end < D:
+            parts.append(x[:, :, :, end:])
+        return F.concat(*parts, dim=-1) if len(parts) > 1 else parts[0]
     B, H, L, D = x.shape
     half = D // 2
     inv = 1.0 / (base ** (F.arange(0, half).astype("float32") / half))
@@ -67,14 +83,21 @@ def _rope(F, x, base=500000.0):
     ang = pos.reshape((L, 1)) * inv.reshape((1, half))      # (L, half)
     cos = F.cos(ang).reshape((1, 1, L, half)).astype(x.dtype)
     sin = F.sin(ang).reshape((1, 1, L, half)).astype(x.dtype)
-    x1 = x[:, :, :, :half]
-    x2 = x[:, :, :, half:]
+    if interleaved:
+        pairs = x.reshape((B, H, L, half, 2))
+        x1 = F.slice_axis(pairs, axis=4, begin=0, end=1) \
+            .reshape((B, H, L, half))
+        x2 = F.slice_axis(pairs, axis=4, begin=1, end=2) \
+            .reshape((B, H, L, half))
+    else:
+        x1 = x[:, :, :, :half]
+        x2 = x[:, :, :, half:]
     return F.concat(x1 * cos - x2 * sin, x1 * sin + x2 * cos, dim=-1)
 
 
 class LlamaBlock(HybridBlock):
     def __init__(self, units, hidden, heads, kv_heads, attn_impl="fused",
-                 sp_axis="sp", **kwargs):
+                 sp_axis="sp", rope_base=500000.0, **kwargs):
         super().__init__(**kwargs)
         if units % heads or heads % kv_heads:
             raise MXNetError("units % heads and heads % kv_heads must be 0")
@@ -87,6 +110,7 @@ class LlamaBlock(HybridBlock):
         self._hd = units // heads
         self._attn_impl = attn_impl
         self._sp_axis = sp_axis
+        self._rope_base = float(rope_base)
         with self.name_scope():
             self.q_proj = Dense(units, flatten=False, use_bias=False,
                                 in_units=units, prefix="q_")
@@ -115,8 +139,8 @@ class LlamaBlock(HybridBlock):
             .transpose((0, 2, 1, 3))
         v = self.v_proj(h).reshape((B, L, self._kv, self._hd)) \
             .transpose((0, 2, 1, 3))
-        q = _rope(F, q)
-        k = _rope(F, k)
+        q = _rope(F, q, self._rope_base)
+        k = _rope(F, k, self._rope_base)
         if self._attn_impl != "fused":
             # sequence/context parallelism: ring or Ulysses attention over
             # the current mesh's sp axis (falls back to local attention
@@ -143,7 +167,7 @@ class LlamaBlock(HybridBlock):
 class LlamaModel(HybridBlock):
     def __init__(self, vocab_size=128256, num_layers=2, units=64,
                  hidden=172, heads=4, kv_heads=2, attn_impl="fused",
-                 sp_axis="sp", remat=None, **kwargs):
+                 sp_axis="sp", remat=None, rope_base=500000.0, **kwargs):
         super().__init__(**kwargs)
         self._units = units
         # activation rematerialization per decoder block (the reference's
@@ -159,7 +183,7 @@ class LlamaModel(HybridBlock):
             for i in range(num_layers):
                 blk = LlamaBlock(units, hidden, heads, kv_heads,
                                  attn_impl=attn_impl, sp_axis=sp_axis,
-                                 prefix=f"layer{i}_")
+                                 rope_base=rope_base, prefix=f"layer{i}_")
                 self.register_child(blk, f"layer{i}")
                 self.blocks.append(blk)
             self.norm = RMSNorm(units, prefix="final_norm_")

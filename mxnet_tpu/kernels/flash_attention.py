@@ -13,7 +13,10 @@ literals (see ``_zi``).  This kernel is the TPU branch of
 alone: a kernel the compiler refuses raises, nothing falls back to the
 dense path.
 
-Layout: q, k, v are (batch, heads, seq, head_dim); segment ids are
+Layout: q, k are (batch, heads, seq, head_dim) and v is (batch, heads,
+seq, value_dim); value_dim may differ from head_dim (latent attention:
+192-wide keys, 128-wide values), in which case out, dO and dV take v's
+width and dQ, dK take q's.  Segment ids are
 (batch, seq) int32 — attention only flows between positions with EQUAL
 segment ids (padding mask: valid tokens segment 1, pad tokens 0).
 
@@ -286,12 +289,14 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, has_seg):
 
 def _fwd_single(q, k, v, seg_q, seg_kv, causal, scale, hb, interpret):
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv = k.shape[2], v.shape[3]
     n_h = H // hb
     has_seg = seg_q is not None
-    spec_q = pl.BlockSpec((1, hb, Lq, D), lambda b, h: (b, h, _zi(), _zi()))
-    spec_k = pl.BlockSpec((1, hb, Lk, D), lambda b, h: (b, h, _zi(), _zi()))
-    in_specs = [spec_q, spec_k, spec_k]
+
+    def whole(L, d):
+        return pl.BlockSpec((1, hb, L, d), lambda b, h: (b, h, _zi(), _zi()))
+
+    in_specs = [whole(Lq, D), whole(Lk, D), whole(Lk, Dv)]
     inputs = [q, k, v]
     if has_seg:
         in_specs += [
@@ -308,12 +313,12 @@ def _fwd_single(q, k, v, seg_q, seg_kv, causal, scale, hb, interpret):
         grid=(B, n_h),
         in_specs=in_specs,
         out_specs=[
-            spec_q,
+            whole(Lq, Dv),
             pl.BlockSpec((1, hb, Lq, _STAT),
                          lambda b, h: (b, h, _zi(), _zi())),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Lq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Lq, _STAT), jnp.float32),
         ],
         interpret=interpret,
@@ -325,7 +330,7 @@ def _fwd_single(q, k, v, seg_q, seg_kv, causal, scale, hb, interpret):
 def _fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_k, block_h,
          interpret):
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv = k.shape[2], v.shape[3]
     bq, bk = _pick_block(Lq, block_q), _pick_block(Lk, block_k)
     single = Lq == bq and Lk == bk
     hb = block_h if block_h else _pick_block_h(H, bq, bk, single)
@@ -342,7 +347,7 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_k, block_h,
     in_specs = [
         pl.BlockSpec((1, hb, bq, D), lambda b, h, i, j: (b, h, i, _zi())),
         pl.BlockSpec((1, hb, bk, D), lambda b, h, i, j: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bk, D), lambda b, h, i, j: (b, h, j, _zi())),
+        pl.BlockSpec((1, hb, bk, Dv), lambda b, h, i, j: (b, h, j, _zi())),
     ]
     inputs = [q, k, v]
     if has_seg:
@@ -360,18 +365,19 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_k, block_h,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, hb, bq, D), lambda b, h, i, j: (b, h, i, _zi())),
+            pl.BlockSpec((1, hb, bq, Dv),
+                         lambda b, h, i, j: (b, h, i, _zi())),
             pl.BlockSpec((1, hb, bq, _STAT),
                          lambda b, h, i, j: (b, h, i, _zi())),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Lq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Lq, _STAT), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((hb, bq, _LANES), jnp.float32),
             pltpu.VMEM((hb, bq, _LANES), jnp.float32),
-            pltpu.VMEM((hb, bq, D), jnp.float32),
+            pltpu.VMEM((hb, bq, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -514,14 +520,16 @@ def _bwd_fused(q, k, v, seg_q, seg_kv, lse_b, delta_b, do, causal, scale,
                hb, interpret):
     """pallas_call wrapper for the single-tile fused backward."""
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv = k.shape[2], v.shape[3]
     n_h = H // hb
     has_seg = seg_q is not None
-    spec_q = pl.BlockSpec((1, hb, Lq, D), lambda b, h: (b, h, _zi(), _zi()))
-    spec_k = pl.BlockSpec((1, hb, Lk, D), lambda b, h: (b, h, _zi(), _zi()))
-    spec_stat = pl.BlockSpec((1, hb, Lq, _STAT),
-                             lambda b, h: (b, h, _zi(), _zi()))
-    in_specs = [spec_q, spec_k, spec_k, spec_q, spec_stat, spec_stat]
+
+    def whole(L, d):
+        return pl.BlockSpec((1, hb, L, d), lambda b, h: (b, h, _zi(), _zi()))
+
+    spec_q, spec_k, spec_v = whole(Lq, D), whole(Lk, D), whole(Lk, Dv)
+    spec_stat = whole(Lq, _STAT)
+    in_specs = [spec_q, spec_k, spec_v, whole(Lq, Dv), spec_stat, spec_stat]
     inputs = [q, k, v, do, lse_b, delta_b]
     if has_seg:
         in_specs += [
@@ -537,7 +545,7 @@ def _bwd_fused(q, k, v, seg_q, seg_kv, lse_b, delta_b, do, causal, scale,
                           has_seg=has_seg),
         grid=(B, n_h),
         in_specs=in_specs,
-        out_specs=[spec_q, spec_k, spec_k],
+        out_specs=[spec_q, spec_k, spec_v],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -551,7 +559,7 @@ def _bwd_fused(q, k, v, seg_q, seg_kv, lse_b, delta_b, do, causal, scale,
 def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
          block_q, block_k, block_h, interpret):
     B, H, Lq, D = q.shape
-    Lk = k.shape[2]
+    Lk, Dv = k.shape[2], v.shape[3]
     bq, bk = _pick_block(Lq, block_q), _pick_block(Lk, block_k)
     single = "bwd" if (Lq == bq and Lk == bk) else False
     hb = block_h if block_h else _pick_block_h(H, bq, bk, single)
@@ -576,8 +584,8 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
     dq_specs = [
         pl.BlockSpec((1, hb, bq, D), lambda b, h, i, j: (b, h, i, _zi())),
         pl.BlockSpec((1, hb, bk, D), lambda b, h, i, j: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bk, D), lambda b, h, i, j: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bq, D), lambda b, h, i, j: (b, h, i, _zi())),
+        pl.BlockSpec((1, hb, bk, Dv), lambda b, h, i, j: (b, h, j, _zi())),
+        pl.BlockSpec((1, hb, bq, Dv), lambda b, h, i, j: (b, h, i, _zi())),
         pl.BlockSpec((1, hb, bq, _STAT),
                      lambda b, h, i, j: (b, h, i, _zi())),
         pl.BlockSpec((1, hb, bq, _STAT),
@@ -587,8 +595,8 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
     dkv_specs = [
         pl.BlockSpec((1, hb, bq, D), lambda b, h, j, i: (b, h, i, _zi())),
         pl.BlockSpec((1, hb, bk, D), lambda b, h, j, i: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bk, D), lambda b, h, j, i: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bq, D), lambda b, h, j, i: (b, h, i, _zi())),
+        pl.BlockSpec((1, hb, bk, Dv), lambda b, h, j, i: (b, h, j, _zi())),
+        pl.BlockSpec((1, hb, bq, Dv), lambda b, h, j, i: (b, h, i, _zi())),
         pl.BlockSpec((1, hb, bq, _STAT),
                      lambda b, h, j, i: (b, h, i, _zi())),
         pl.BlockSpec((1, hb, bq, _STAT),
@@ -633,7 +641,8 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
         in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, hb, bk, D), lambda b, h, j, i: (b, h, j, _zi())),
-            pl.BlockSpec((1, hb, bk, D), lambda b, h, j, i: (b, h, j, _zi())),
+            pl.BlockSpec((1, hb, bk, Dv),
+                         lambda b, h, j, i: (b, h, j, _zi())),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -641,7 +650,7 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
         ],
         scratch_shapes=[
             pltpu.VMEM((hb, bk, D), jnp.float32),
-            pltpu.VMEM((hb, bk, D), jnp.float32),
+            pltpu.VMEM((hb, bk, Dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -659,9 +668,9 @@ def flash_attention(q, k, v, seg_q=None, seg_kv=None, causal=False,
                     interpret=False):
     """Blockwise (flash) attention: softmax(scale * Q K^T + mask) V.
 
-    q, k, v: (B, H, L, D); seg_q/seg_kv: (B, L) int32 segment ids (None =
-    no masking); positions attend only within equal segment ids.  Returns
-    (B, H, Lq, D) in q's dtype.  ``block_h=0`` auto-picks the head-block
+    q, k: (B, H, L, D), v: (B, H, L, Dv), Dv == D or not; seg_q/seg_kv:
+    (B, L) int32 segment ids (None = no masking); positions attend only
+    within equal segment ids.  Returns (B, H, Lq, Dv) in q's dtype.  ``block_h=0`` auto-picks the head-block
     (largest divisor of H under the VMEM budget).  ``interpret=True`` runs
     the Pallas interpreter (CPU tests).
 

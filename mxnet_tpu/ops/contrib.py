@@ -77,19 +77,18 @@ def _interleaved_matmul_selfatt_valatt(qkv, att, heads=1):
     return out.reshape(L, B, -1)
 
 
-def _flash_eligible(seq, head_dim):
+def _flash_eligible(seq, head_dim, value_dim=None):
     """Whether the Pallas TPU flash kernel's tiling applies to these shapes
-    (lane-aligned seq blocks); the platform choice itself happens at XLA
-    lowering via lax.platform_dependent, never by host-side guessing: a
-    program lowered for the CPU (the test platform, or a host-side island
-    of a TPU process) carries the dense branch only, one lowered for a TPU
-    — attached or merely described — carries the kernel.
+    (lane-aligned seq blocks; query/key width ``head_dim`` and value width
+    ``value_dim``, the same unless given); the platform choice itself
+    happens at XLA lowering via lax.platform_dependent, never by host-side
+    guessing: a program lowered for the CPU (the test platform, or a
+    host-side island of a TPU process) carries the dense branch only, one
+    lowered for a TPU — attached or merely described — carries the kernel.
 
-    The seq floor (MXNET_FLASH_MIN_SEQ, default 256) is a measured
-    crossover, not structural: through the earlier chip access, dense
-    attention beat the flash kernel at BERT-base seq 128 (0.50 vs 0.41
-    MFU) and lost at 512 (0.35 vs 0.43); by 2048 dense memory is
-    prohibitive.  Not re-measured on today's code (ROADMAP A2/A8).
+    The seq floor (MXNET_FLASH_MIN_SEQ, default 256) is a crossover, not
+    structural; it has not been re-measured on today's code (PERF.md,
+    Open questions).
 
     Shapes alone decide: a kernel the TPU compiler refuses raises at
     compile time, it never silently takes the dense path."""
@@ -97,7 +96,9 @@ def _flash_eligible(seq, head_dim):
     if not config.get_int("MXNET_FUSED_ATTENTION", 1):
         return False
     floor = config.get_int("MXNET_FLASH_MIN_SEQ", 256)
-    return seq >= floor and seq % 128 == 0 and head_dim % 8 == 0
+    value_dim = head_dim if value_dim is None else value_dim
+    return seq >= floor and seq % 128 == 0 and head_dim % 8 == 0 \
+        and value_dim % 8 == 0
 
 
 def _flash(q, k, v, seg_q, seg_kv, causal, scale):
@@ -192,14 +193,14 @@ def _attend(q, k, v, valid_length, causal):
     path) and the dense fallback skips the pad mask."""
     jnp = _jnp()
     L, D = q.shape[2], q.shape[3]
-    scale = 1.0 / float(D) ** 0.5
+    scale = 1.0 / float(D) ** 0.5       # by the query/key width; v's may differ
     if valid_length is None:
         seg = None
     else:
         steps = jnp.arange(L, dtype=jnp.int32)
         seg = (steps[None, :] < valid_length.astype(jnp.int32)[:, None]) \
             .astype(jnp.int32)                      # (B, L): 1=valid, 0=pad
-    if _flash_eligible(L, D):
+    if _flash_eligible(L, D, v.shape[3]):
         import jax
 
         if seg is None:
@@ -229,7 +230,8 @@ def _attend(q, k, v, valid_length, causal):
 @_attention_region
 def _masked_att_qkv(q, k, v, valid_length=None, num_kv_groups=1,
                     causal=False):
-    """Masked attention over SEPARATE (B, H, L, D) q/k/v tensors — the
+    """Masked attention over SEPARATE q, k (B, H, L, D) and v (B, H, L, Dv)
+    tensors (Dv may differ from D: latent attention) — the
     modern-LLM entry point (no interleave round-trip; the BERT-era
     ``masked_selfatt`` keeps the reference transformer.cc layout).
 

@@ -26,7 +26,10 @@ performance path.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
+import warnings
 
 import numpy as _np
 
@@ -57,7 +60,8 @@ _M_MICROBATCHES = _tel.counter(
 
 __all__ = ["DeviceMesh", "make_mesh", "data_parallel_ctxs", "TrainStep",
            "allreduce", "allgather", "current_mesh", "set_mesh",
-           "attention", "ring_attention", "ulysses_attention"]
+           "attention", "ring_attention", "ulysses_attention",
+           "report_counter"]
 
 
 def __getattr__(name):
@@ -384,6 +388,93 @@ class _HostPhase:
         return False
 
 
+# -- counters a block reports from inside the step -------------------------------
+# A block cannot bank a traced value in the registry itself: inside the step
+# it is a tracer.  It hands it to ``report_counter``; the TrainStep tracing on
+# this thread returns every step's values beside the losses and banks them in
+# ``telemetry.REGISTRY`` when the losses are fetched (they are outputs of the
+# same program: no extra wait for the device).  Always on, like the registry's
+# other counters, whatever MXNET_TELEMETRY says: a step that reports returns a
+# few scalars a step more, and banking is one device-to-host copy of them per
+# fetched dispatch; a net that reports nothing pays nothing.
+
+_reports = threading.local()
+_UNDER_REMAT = object()     # the sink while a remat'd forward is traced
+_warned_under_remat = set()
+
+
+def report_counter(name, value, labels=None, kind="sum"):
+    """Report ``value`` (an array, reduced over its elements, or a number)
+    under the registry name ``name`` from inside a ``TrainStep``'s forward.
+    ``kind="sum"``: a counter (``*_total``) that grows by every step's sum;
+    ``kind="max"``: a gauge that keeps the largest value any step showed.
+    Several reports under one name and labels in one step combine the same
+    way.  Outside a TrainStep's trace (an imperative forward) this does
+    nothing.  Under ``TrainStep(remat=True)`` a value cannot leave the
+    checkpointed forward: it is not collected, and the first report of each
+    name says so in a warning."""
+    sink = getattr(_reports, "sink", None)
+    if sink is None:
+        return
+    if sink is _UNDER_REMAT:
+        if name not in _warned_under_remat:
+            _warned_under_remat.add(name)
+            warnings.warn(
+                f"report_counter({name!r}): the TrainStep runs the net under "
+                "remat, so the values it reports are not collected and the "
+                "counter stays where it is", RuntimeWarning, stacklevel=2)
+        return
+    import jax.numpy as jnp
+    if kind not in ("sum", "max"):
+        raise MXNetError(f"report_counter kind {kind!r}: want sum|max")
+    raw = value._data if isinstance(value, NDArray) else jnp.asarray(value)
+    raw = jnp.sum(raw) if kind == "sum" else jnp.max(raw)
+    key = (name, tuple(sorted((labels or {}).items())), kind)
+    if key in sink:
+        raw = sink[key] + raw if kind == "sum" \
+            else jnp.maximum(sink[key], raw)
+    sink[key] = raw
+
+
+@contextlib.contextmanager
+def _collect_reports(sink=None):
+    """The dict ``report_counter`` fills on this thread while the body
+    runs (or ``_UNDER_REMAT``, which collects nothing and warns)."""
+    outer = getattr(_reports, "sink", None)
+    _reports.sink = sink = {} if sink is None else sink
+    try:
+        yield sink
+    finally:
+        _reports.sink = outer
+
+
+def _bank_reports(reports):
+    """Per-step values ``{(name, labels, kind): (steps,) array}`` into the
+    registry; one device-to-host copy for all of them."""
+    import jax
+    for (name, labels, kind), per_step in jax.device_get(reports).items():
+        labels = dict(labels) or None
+        if kind == "sum":
+            _tel.counter(name, labels=labels).inc(int(_np.sum(per_step)))
+        else:
+            gauge = _tel.gauge(name, labels=labels)
+            gauge.set(max(gauge.value, float(_np.max(per_step))))
+
+
+class _StepLosses(NDArray):
+    """The losses of a dispatch whose step reported counters: fetching them
+    (``asnumpy`` and what goes through it) banks the counters too, once."""
+
+    __slots__ = ("_reports",)
+
+    def asnumpy(self):
+        out = super().asnumpy()
+        reports, self._reports = self._reports, None
+        if reports:
+            _bank_reports(reports)
+        return out
+
+
 class TrainStep:
     """One fully-fused, mesh-sharded training step.
 
@@ -680,12 +771,17 @@ class TrainStep:
             the (pre-zeroed) grad slots."""
             d_nd, l_nd = NDArray._from_data(d), NDArray._from_data(l)
             scope = _rnd.trace_key_scope(key)
+            reports = {}
             with scope, autograd._scope(recording=True, training=True):
                 if remat:
+                    # a value reported from inside the checkpointed
+                    # forward could not leave it: nothing is collected
                     from .gluon.utils import remat_call
-                    out = remat_call(net, d_nd)
+                    with _collect_reports(_UNDER_REMAT):
+                        out = remat_call(net, d_nd)
                 else:
-                    out = net(d_nd)
+                    with _collect_reports() as reports:
+                        out = net(d_nd)
                 with _regions.scope("loss"):
                     loss = loss_fn(out, l_nd)
                     if loss.shape:
@@ -693,7 +789,7 @@ class TrainStep:
             # the tape re-enters each op's region around its vjp, so the
             # backward's instructions carry the forward's names
             autograd.backward([loss])
-            return loss
+            return loss, reports
 
         def apply_update():
             with _regions.scope("optimizer"):
@@ -740,11 +836,11 @@ class TrainStep:
                     optzr.rescale_grad = rescale
 
                     if n_micro == 1:
-                        loss = forward_loss(key, d, l)
+                        loss, reports = forward_loss(key, d, l)
                         apply_update()
                         new_p = tuple(p._data._slot.value for p in params)
                         new_s = tuple(s._slot.value for s in state_nds)
-                        return new_p, new_s, loss._data
+                        return new_p, new_s, loss._data, reports
 
                     # microbatched: (B, ...) -> (n_micro, B/n_micro, ...)
                     # keeping each microbatch on the declared data layout
@@ -766,18 +862,21 @@ class TrainStep:
                         with swap_slot_values(
                                 [(g, jnp.zeros(p.shape, g.dtype))
                                  for g, p in zip(grad_nds, trainable)]):
-                            loss = forward_loss(k_i, dd, ll)
+                            loss, reports = forward_loss(k_i, dd, ll)
                             g = tuple(gn._slot.value for gn in grad_nds)
                         # fixed-association accumulation: acc + micro_i,
                         # in scan order
                         acc = tuple(a + gi for a, gi in zip(acc, g))
-                        return acc, loss._data
+                        return acc, (loss._data, reports)
 
                     zeros = tuple(
                         jnp.zeros(p.shape, p._data._grad.dtype)
                         for p in trainable)
-                    acc, losses = jax.lax.scan(micro, zeros,
-                                               (keys, dm, lm))
+                    acc, (losses, reports) = jax.lax.scan(
+                        micro, zeros, (keys, dm, lm))
+                    # a step's report is its microbatches' combined
+                    reports = {k: v.sum(0) if k[2] == "sum" else v.max(0)
+                               for k, v in reports.items()}
                     inv = jnp.asarray(1.0 / n_micro, losses.dtype)
                     mean_g = tuple(a * jnp.asarray(1.0 / n_micro, a.dtype)
                                    for a in acc)
@@ -785,7 +884,7 @@ class TrainStep:
                         apply_update()
                         new_p = tuple(p._data._slot.value for p in params)
                         new_s = tuple(s._slot.value for s in state_nds)
-                        return new_p, new_s, (losses.sum() * inv)
+                        return new_p, new_s, (losses.sum() * inv), reports
             finally:
                 (optzr._update_count, optzr._index_update_count,
                  optzr._get_lr, optzr.rescale_grad) = saved_opt
@@ -799,7 +898,7 @@ class TrainStep:
         d_sh, l_sh = self._data_shardings(len(data.shape), len(label.shape))
         p_sh, s_sh = self._shardings()
         in_sh = (repl, repl, repl, repl, p_sh, s_sh, d_sh, l_sh)
-        out_sh = (p_sh, s_sh, repl)
+        out_sh = (p_sh, s_sh, repl, repl)
         donate = (4, 5) if self._donate else ()
         if _ttrace._ENABLED:
             _M_RETRACES.inc()
@@ -827,13 +926,14 @@ class TrainStep:
                 else:
                     key, t, lr_vec = xs
                     dd, ll = d, l
-                new_p, new_s, loss = raw(key, t, lr_vec, rescale,
-                                         p_vals, s_vals, dd, ll)
-                return (new_p, new_s), loss
+                new_p, new_s, loss, reports = raw(key, t, lr_vec, rescale,
+                                                  p_vals, s_vals, dd, ll)
+                return (new_p, new_s), (loss, reports)
 
             xs = (keys, ts, lr_vecs, d, l) if stacked else (keys, ts, lr_vecs)
-            (p, s), losses = jax.lax.scan(body, (param_vals, state_vals), xs)
-            return p, s, losses
+            (p, s), (losses, reports) = jax.lax.scan(
+                body, (param_vals, state_vals), xs)
+            return p, s, losses, reports
 
         repl = self.mesh.replicated()
         p_sh, s_sh = self._shardings()
@@ -841,7 +941,7 @@ class TrainStep:
         d_sh, l_sh = self._data_shardings(data_ndim - lead,
                                           label_ndim - lead, stacked=stacked)
         in_sh = (repl, repl, repl, repl, p_sh, s_sh, d_sh, l_sh)
-        out_sh = (p_sh, s_sh, repl)
+        out_sh = (p_sh, s_sh, repl, repl)
         donate = (4, 5) if self._donate else ()
         if _ttrace._ENABLED:
             _M_RETRACES.inc()
@@ -943,8 +1043,7 @@ class TrainStep:
             return jax.random.split(_rnd.get_key(), steps), ts, lr_vecs, \
                 rescale
 
-        return NDArray._from_data(self._dispatch(
-            fn, bookkeeping, data, label, stacked, steps))
+        return self._dispatch(fn, bookkeeping, data, label, stacked, steps)
 
     # -- call -----------------------------------------------------------------
     def __call__(self, data, label):
@@ -969,8 +1068,7 @@ class TrainStep:
             ts, lr_vecs, rescale = self._advance(1)
             return _rnd.get_key(), ts[0], lr_vecs[0], rescale
 
-        return NDArray._from_data(self._dispatch(
-            fn, bookkeeping, data, label, False, 1))
+        return self._dispatch(fn, bookkeeping, data, label, False, 1)
 
     def _advance(self, steps):
         """Host-side bookkeeping of ``steps`` steps up front: advance the
@@ -998,8 +1096,9 @@ class TrainStep:
         them, ``enqueue`` calls the jitted program — asynchronously: it
         returns before the device has finished, so its time says nothing
         about the chip — and ``writeback`` hands the new arrays to the
-        parameter and state handles.  Returns the program's losses (a jax
-        array)."""
+        parameter and state handles.  Returns the program's losses as an
+        NDArray; where the step's blocks reported counters
+        (``report_counter``), fetching the losses banks them."""
         import jax
         # one flag read per dispatch (graftcheck GC05); the StepClock
         # treats each dispatch as one "step"
@@ -1025,7 +1124,7 @@ class TrainStep:
             if self._n_micro > 1:
                 _M_MICROBATCHES.inc(self._n_micro * steps)
         with _HostPhase("enqueue", enabled):
-            new_p, new_s, losses = fn(*scalars, p_vals, s_vals, d, l)
+            new_p, new_s, losses, reports = fn(*scalars, p_vals, s_vals, d, l)
         with _HostPhase("writeback", enabled):
             for p, v in zip(self._params, new_p):
                 p._data._set_data(v)
@@ -1033,4 +1132,8 @@ class TrainStep:
                 s._set_data(v)
         if enabled:
             _sclock.STEP_CLOCK.end_step()
-        return losses
+        if not reports:
+            return NDArray._from_data(losses)
+        out = _StepLosses._from_data(losses)
+        out._reports = reports
+        return out

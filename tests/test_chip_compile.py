@@ -110,6 +110,49 @@ def test_flash_kernel_compiles_for_v5e(one_chip, shape, dtype, causal, seg,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_flash_kernels_compile_with_192_wide_keys_and_128_wide_values(
+        one_chip):
+    """Latent attention's shapes at the benchmark's size: causal, seq 4096,
+    so the three streaming kernels, forward and backward."""
+    from mxnet_tpu.kernels.flash_attention import flash_attention
+    qk = jax.ShapeDtypeStruct((2, 32, 4096, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 32, 4096, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, None, None, True, 192 ** -0.5)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+        .lower(qk, qk, v).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text, kernel
+
+
+def test_routed_experts_compile_to_grouped_kernels_for_v5e(one_chip):
+    """``contrib.moe_experts`` at the benchmark's widths, under
+    jax_enable_x64 as the package runs: the compiler turns each
+    ``ragged_dot`` into a Mosaic call of its own, forward and backward."""
+    from mxnet_tpu.ops.moe import _moe_experts
+
+    def struct(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, w, gate, up, down, experts):
+        y, _tokens = _moe_experts(x, w, experts, gate, up, down, first=0)
+        return y.astype(jnp.float32).sum()
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            struct((8192, 2048)), struct((8192, 6), jnp.float32),
+            struct((16, 2048, 768)), struct((16, 2048, 768)),
+            struct((16, 768, 2048)), struct((8192, 6), jnp.int32)) \
+            .compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 9
+    assert "ragged-dot" in text
+
+
 def test_illegal_block_raises_not_falls_back(one_chip):
     """A block the TPU lowering cannot take (4 rows: not a multiple of 8)
     raises at compile time — nothing switches to the dense path."""

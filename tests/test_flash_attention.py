@@ -235,3 +235,57 @@ def test_flash_grad_parity_multi_tile(causal):
     for name, a, b in zip("qkv", g1, g2):
         d = float(jnp.max(jnp.abs(a - b)))
         assert d < 1e-4, f"multi-tile d{name} max diff {d}"
+
+
+# -- a value width that differs from the query/key width (latent attention) ---
+
+def _inputs_qk_v(D=24, Dv=16, B=2, H=4, L=256, seed=11):
+    r = np.random.RandomState(seed)
+    q = jnp.asarray(r.randn(B, H, L, D), jnp.float32)
+    k = jnp.asarray(r.randn(B, H, L, D), jnp.float32)
+    v = jnp.asarray(r.randn(B, H, L, Dv), jnp.float32)
+    return q, k, v
+
+
+@pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
+                         ids=["single_tile", "streaming"])
+def test_flash_value_width_differs_causal(blocks):
+    """24-wide queries and keys, 16-wide values, causal: out, dO and dV
+    take v's width, dQ and dK q's, in the single-tile kernels and in the
+    three streaming ones, against the dense oracle."""
+    q, k, v = _inputs_qk_v()
+    scale = 1.0 / q.shape[-1] ** 0.5
+    out = flash_attention(q, k, v, None, None, True, scale, interpret=True,
+                          **blocks)
+    ref = _dense_sdpa(q, k, v, None, True, scale)
+    assert out.shape == (2, 4, 256, 16) == ref.shape
+    assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
+    w = jnp.asarray(np.random.RandomState(3).randn(*out.shape), jnp.float32)
+
+    def loss_flash(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, None, None, True, scale,
+                                       interpret=True, **blocks) * w)
+
+    def loss_dense(q, k, v):
+        return jnp.sum(_dense_sdpa(q, k, v, None, True, scale) * w)
+
+    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b, like in zip("qkv", g1, g2, (q, k, v)):
+        assert a.shape == like.shape
+        d = float(jnp.max(jnp.abs(a - b)))
+        assert d < 1e-4, f"d{name} max diff {d}"
+
+
+def test_masked_att_qkv_takes_a_narrower_value():
+    """The op that the latent-attention block calls: eligible by both
+    widths, scaled by the query/key width, v's width out."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops.contrib import _flash_eligible
+    assert _flash_eligible(256, 24, 16) and _flash_eligible(4096, 192, 128)
+    assert not _flash_eligible(256, 24, 12)
+    q, k, v = _inputs_qk_v()
+    out = mx.nd.contrib.masked_att_qkv(mx.nd.array(q), mx.nd.array(k),
+                                       mx.nd.array(v), None, causal=True)
+    ref = _dense_sdpa(q, k, v, None, True, 1.0 / 24 ** 0.5)
+    np.testing.assert_allclose(out.asnumpy(), np.asarray(ref), atol=1e-5)

@@ -165,3 +165,176 @@ def test_moe_expert_parallel_trainstep():
     sharded = run(mesh)
     np.testing.assert_allclose(single, sharded, rtol=1e-4, atol=1e-5)
     assert sharded[-1] < sharded[0]
+
+
+# -- DroplessMoE: sigmoid router over all experts, experts held, no drop ------
+
+from mxnet_tpu.gluon.contrib.moe import DroplessMoE  # noqa: E402
+
+
+def _route(x, w, b, **kw):
+    weights, experts = mx.nd.contrib.moe_router(
+        mx.nd.array(x), mx.nd.array(w), mx.nd.array(b), **kw)
+    return weights.asnumpy(), experts.asnumpy()
+
+
+def test_router_sigmoid_bias_for_choice_not_weight_normalised_scaled():
+    r = np.random.RandomState(0)
+    x, w = r.randn(6, 8).astype("float32"), r.randn(5, 8).astype("float32")
+    b = np.array([0, 0, 10.0, 0, -10.0], "float32")
+    s = 1.0 / (1.0 + np.exp(-(x @ w.T)))
+    weights, experts = _route(x, w, b, k=2, scale=2.5, normalize=True)
+    assert experts.dtype == np.int32 and weights.dtype == np.float32
+    # the bias decides the choice: expert 2 always first, expert 4 never
+    assert (experts[:, 0] == 2).all() and (experts != 4).all()
+    second = np.argsort(-np.where(np.arange(5) == 2, -1, s + b), axis=1)[:, 0]
+    assert (experts[:, 1] == second).all()
+    # ... and stays out of the weights: sigmoid scores, normalised, scaled
+    picked = np.take_along_axis(s, experts, axis=1)
+    np.testing.assert_allclose(
+        weights, 2.5 * picked / picked.sum(1, keepdims=True), rtol=1e-5)
+    raw, _ = _route(x, w, b, k=2, scale=1.0, normalize=False)
+    np.testing.assert_allclose(raw, picked, rtol=1e-5)
+
+
+def test_router_tie_goes_to_the_lower_expert():
+    x = np.ones((3, 4), "float32")
+    w = np.zeros((6, 4), "float32")         # every score sigmoid(0)
+    _, experts = _route(x, w, np.zeros(6, "float32"), k=3)
+    assert (experts == np.array([0, 1, 2])).all()
+    _, experts = _route(x, w, np.array([0, 1, 0, 1, 0, 0], "float32"), k=3)
+    assert (experts == np.array([1, 3, 0])).all()
+
+
+def _dropless(held, E=8, k=3, units=8, hidden=16, shared=1, seed=0):
+    moe = DroplessMoE(units, hidden, E, k, experts_held=held,
+                      num_shared_experts=shared, routed_scaling_factor=2.0)
+    mx.random.seed(seed)
+    moe.initialize(mx.init.Normal(0.5))
+    return moe
+
+
+def _dropless_oracle(moe, x, held):
+    """Token by token, expert by expert, in numpy."""
+    p = {k[len(moe.prefix):]: v.data().asnumpy()
+         for k, v in moe.collect_params().items()}
+    silu = lambda a: a / (1.0 + np.exp(-a))             # noqa: E731
+    s = 1.0 / (1.0 + np.exp(-(x @ p["router_weight"].T)))
+    top = np.argsort(-(s + p["router_bias"]), axis=1, kind="stable")[:, :3]
+    out = np.zeros_like(x)
+    for n in range(x.shape[0]):
+        w = s[n, top[n]] / (s[n, top[n]].sum() + 1e-20) * 2.0
+        for j, e in enumerate(top[n]):
+            if held[0] <= e < held[0] + held[1]:
+                l = e - held[0]
+                h = silu(x[n] @ p["experts_gate"][l]) \
+                    * (x[n] @ p["experts_up"][l])
+                out[n] += w[j] * (h @ p["experts_down"][l])
+    sh = silu(x @ p["shared_gate_weight"].T) * (x @ p["shared_up_weight"].T)
+    return out + sh @ p["shared_down_weight"].T
+
+
+@pytest.mark.parametrize("held", [(0, 8), (2, 3), (7, 1)])
+def test_dropless_moe_matches_per_token_oracle(held):
+    moe = _dropless(held)
+    x = np.random.RandomState(1).randn(2, 12, 8).astype("float32")
+    y = moe(mx.nd.array(x)).asnumpy()
+    ref = _dropless_oracle(moe, x.reshape(-1, 8), held).reshape(x.shape)
+    np.testing.assert_allclose(y, ref, rtol=1e-4, atol=1e-5)
+
+
+def test_dropless_moe_drops_no_token_under_a_planted_imbalance():
+    """Every token sent to ONE held expert (a router bias no score can
+    beat): 32 tokens on an expert whose even share is 32 * 3 / 8 = 12, all
+    served, and counted."""
+    moe = _dropless((2, 3))
+    bias = np.zeros(8, "float32")
+    bias[3] = 100.0
+    moe.router.bias.set_data(mx.nd.array(bias))
+    x = np.random.RandomState(2).randn(32, 8).astype("float32")
+    xn = mx.nd.array(x)
+    weights, experts = moe.router(xn)
+    y, tokens = moe.experts(xn, weights, experts)
+    assert tokens.asnumpy().tolist()[1] == 32
+    assert (experts.asnumpy()[:, 0] == 3).all()
+    ref = _dropless_oracle(moe, x, (2, 3))
+    np.testing.assert_allclose(moe(xn).asnumpy(), ref, rtol=1e-4, atol=1e-5)
+    # every token got its routed part: none is the shared part alone
+    assert (np.abs(y.asnumpy()).sum(axis=1) > 0).all()
+
+
+def test_dropless_moe_gradients_and_frozen_bias():
+    moe = _dropless((0, 8))
+    x = mx.nd.array(np.random.RandomState(3).randn(16, 8).astype("float32"))
+    x.attach_grad()
+    with autograd.record():
+        loss = (moe(x) ** 2).sum()
+    loss.backward()
+    assert moe.router.bias.grad_req == "null"
+    for name, p in moe.collect_params().items():
+        if p.grad_req != "null":
+            g = p.grad().asnumpy()
+            assert np.isfinite(g).all() and np.abs(g).sum() > 0, name
+    assert np.abs(x.grad.asnumpy()).sum() > 0
+    # against finite differences through the whole layer, on the input
+    eps, xv = 1e-3, x.asnumpy()
+    d = np.zeros_like(xv)
+    d[5, 2] = eps
+    up = (moe(mx.nd.array(xv + d)) ** 2).sum().asscalar()
+    dn = (moe(mx.nd.array(xv - d)) ** 2).sum().asscalar()
+    assert x.grad.asnumpy()[5, 2] == pytest.approx((up - dn) / (2 * eps),
+                                                   rel=2e-2)
+
+
+def test_dropless_moe_shares_its_experts_over_the_ep_axis():
+    moe = _dropless((0, 8))
+    for p in (moe.experts.gate, moe.experts.up, moe.experts.down):
+        assert p.sharding == ("ep", None, None)
+
+
+@pytest.mark.parametrize("bias_on_held", [0.0, 100.0],
+                         ids=["even_router", "all_on_one_held_expert"])
+def test_moe_experts_equal_masked_dense_products(bias_on_held):
+    """The op's one path (pair buffers of all N*k rows, absent experts'
+    pairs last) against every held expert run densely over ALL tokens under
+    its weight: the result and every gradient, also when every token lands
+    on one held expert (64 of the 192 pairs on it)."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.moe import _moe_experts, _moe_router
+    r = np.random.RandomState(4)
+    x = jnp.asarray(r.randn(64, 8), jnp.float32)
+    rw = jnp.asarray(r.randn(16, 8), jnp.float32)
+    rb = jnp.zeros(16, jnp.float32).at[5].set(bias_on_held)
+    gate, up = (jnp.asarray(r.randn(2, 8, 12), jnp.float32) for _ in "gu")
+    down = jnp.asarray(r.randn(2, 12, 8), jnp.float32)
+    weights, experts = _moe_router(x, rw, rb, k=3, scale=2.0)
+    cot = jnp.asarray(r.randn(64, 8), jnp.float32)
+    first = 4
+
+    def op(x, weights, gate, up, down):
+        y, tokens = _moe_experts(x, weights, experts, gate, up, down,
+                                 first=first)
+        return (y * cot).sum(), tokens
+
+    def dense(x, weights, gate, up, down):
+        y = jnp.zeros_like(x)
+        for e in range(gate.shape[0]):
+            w = jnp.where(experts == first + e, weights, 0.0).sum(axis=1)
+            h = jax.nn.silu(x @ gate[e]) * (x @ up[e])
+            y = y + w[:, None] * (h @ down[e])
+        return (y * cot).sum(), None
+
+    args = (x, weights, gate, up, down)
+    (got, tokens), g_got = jax.value_and_grad(
+        op, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    (want, _), g_want = jax.value_and_grad(
+        dense, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    held = np.asarray(experts) - first
+    assert np.asarray(tokens).tolist() == [int((held == e).sum())
+                                           for e in range(2)]
+    assert (int(tokens[1]) == 64) == bool(bias_on_held)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5)

@@ -363,6 +363,21 @@ def _sweep_override(name):
             [nd.array(r.randn(2, 2, 4, 8).astype(np.float32)),
              nd.array(r.randn(2, 2, 4, 8).astype(np.float32)),
              nd.array(r.randn(2, 2, 4, 8).astype(np.float32))], {}),
+        # dropless MoE: router over 8 experts (weight in a Dense layout),
+        # then the routed experts 2..4 on the router's own choices
+        "contrib.moe_router": lambda: (
+            [nd.array(r.randn(6, 4).astype(np.float32)),
+             nd.array(r.randn(8, 4).astype(np.float32)),
+             nd.array(r.randn(8).astype(np.float32))],
+            {"k": 2, "scale": 2.0}),
+        "contrib.moe_experts": lambda: (
+            [nd.array(r.randn(6, 4).astype(np.float32)),
+             nd.array(r.rand(6, 2).astype(np.float32)),
+             nd.array(r.randint(0, 8, (6, 2)).astype(np.int32)),
+             nd.array(r.randn(3, 4, 5).astype(np.float32)),
+             nd.array(r.randn(3, 4, 5).astype(np.float32)),
+             nd.array(r.randn(3, 5, 4).astype(np.float32))],
+            {"first": 2}),
         # encdec: q (Lq, B, H*D), kv (Lk, B, 2*H*D) interleaved k/v
         "contrib.masked_encdec_att": lambda: (
             [nd.array(r.randn(4, 2, 8).astype(np.float32)),
@@ -632,6 +647,14 @@ FD_SKIP = {
     "contrib.masked_encdec_att": "float32 softmax core (same class as "
                                  "masked_selfatt); transformer grads in "
                                  "test_model_zoo",
+    "contrib.moe_router": "float32 router core with an integer output "
+                          "(the chosen experts) and a top-k choice that "
+                          "a finite difference can flip; weights and "
+                          "grads pinned by test_moe",
+    "contrib.moe_experts": "integer selector input (expert ids) and an "
+                           "integer output (tokens per expert); grads "
+                           "against finite differences and a per-token "
+                           "oracle in test_moe",
     # ISSUE 14 satellite: the mha-named fused wrapper + the SP entry
     # share the masked_selfatt float32-softmax-core class; their grads
     # are covered by test_contrib_ops.test_multihead_attention_grads_flow

@@ -243,6 +243,82 @@ def test_tpu_flash_attention_grad_consistency():
                                    err_msg=f"d{name} mismatch")
 
 
+def test_tpu_flash_attention_value_width_streaming_consistency():
+    """Latent attention's shapes on the chip: 192-wide queries and keys,
+    128-wide values, causal, seq 1024 in blocks of 512, so the three
+    STREAMING kernels (flash_fwd, flash_bwd_dq, flash_bwd_dkv) run, forward
+    and backward, against the dense path on the cpu ctx.  The benchmark's
+    MLA cell starts with a closed attention branch and so checks the
+    kernels' forward only (perfbench/reference/mla_moe_train.py)."""
+    r = np.random.RandomState(23)
+    B, H, L, D, Dv = 1, 4, 1024, 192, 128
+    qn = (r.randn(B, H, L, D) * 0.3).astype(np.float32)
+    kn = (r.randn(B, H, L, D) * 0.3).astype(np.float32)
+    vn = (r.randn(B, H, L, Dv) * 0.3).astype(np.float32)
+    wn = r.randn(B, H, L, Dv).astype(np.float32)
+    outs, grads = {}, {}
+    for ctx in _ctxs():
+        q, k, v = (mx.nd.array(a, ctx=ctx) for a in (qn, kn, vn))
+        for t in (q, k, v):
+            t.attach_grad()
+        with autograd.record():
+            out = mx.nd.contrib.masked_att_qkv(q, k, v, None, causal=True)
+            loss = (out * mx.nd.array(wn, ctx=ctx)).sum()
+        loss.backward()
+        outs[str(ctx)] = out.asnumpy()
+        grads[str(ctx)] = [t.grad.asnumpy() for t in (q, k, v)]
+    (oa, ob), (a, b) = outs.values(), grads.values()
+    assert oa.shape == (B, H, L, Dv)
+    np.testing.assert_allclose(oa, ob, rtol=5e-2, atol=5e-3)
+    for name, ga, gb in zip("qkv", a, b):
+        assert ga.shape == gb.shape
+        np.testing.assert_allclose(ga, gb, rtol=5e-2, atol=5e-2,
+                                   err_msg=f"d{name} mismatch")
+
+
+def test_tpu_moe_experts_consistency():
+    """The routed experts' grouped products (the TPU compiler's own Mosaic
+    calls for ``ragged_dot``) against the cpu ctx's masked dense products,
+    forward and backward, with an even router and with every token planted
+    on one held expert: on the chip the rows past the held pairs are never
+    written, which only a run there shows."""
+    r = np.random.RandomState(24)
+    N, U, I, E, held, k = 1024, 256, 128, 32, 4, 4
+    xn = r.randn(N, U).astype(np.float32)
+    rw = (r.randn(E, U) * 0.05).astype(np.float32)
+    mats = [(r.randn(held, U, I) * 0.05).astype(np.float32),
+            (r.randn(held, U, I) * 0.05).astype(np.float32),
+            (r.randn(held, I, U) * 0.05).astype(np.float32)]
+    for bias_on in (0.0, 100.0):
+        rb = np.zeros(E, np.float32)
+        rb[9] = bias_on
+        res = {}
+        for ctx in _ctxs():
+            x = mx.nd.array(xn, ctx=ctx)
+            ws = [mx.nd.array(m, ctx=ctx) for m in mats]
+            for t in [x] + ws:
+                t.attach_grad()
+            weights, experts = mx.nd.contrib.moe_router(
+                x, mx.nd.array(rw, ctx=ctx), mx.nd.array(rb, ctx=ctx), k=k,
+                scale=2.0)
+            with autograd.record():
+                y, tokens = mx.nd.contrib.moe_experts(
+                    x, weights, experts, *ws, first=8)
+                loss = (y * y).sum()
+            loss.backward()
+            res[str(ctx)] = ([y.asnumpy(), x.grad.asnumpy()]
+                             + [w.grad.asnumpy() for w in ws],
+                             tokens.asnumpy())
+        (a, ta), (b, tb) = res.values()
+        assert np.array_equal(ta, tb)
+        assert (tb[1] == N) == bool(bias_on)
+        for name, ga, gb in zip(("y", "dx", "dgate", "dup", "ddown"), a, b):
+            assert np.isfinite(gb).all(), name
+            np.testing.assert_allclose(ga, gb, rtol=5e-2,
+                                       atol=5e-2 * np.abs(ga).max(),
+                                       err_msg=f"{name} mismatch")
+
+
 def test_tpu_sparse_dot_consistency():
     """csr SpMM kernel (gather + segment-sum) cpu-vs-tpu."""
     from mxnet_tpu.ndarray import sparse
