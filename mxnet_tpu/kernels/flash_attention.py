@@ -20,10 +20,25 @@ width and dQ, dK take q's.  Segment ids are
 (batch, seq) int32 — attention only flows between positions with EQUAL
 segment ids (padding mask: valid tokens segment 1, pad tokens 0).
 
-Grid design (canonical TPU flash schedule, head-blocked): grid
-(B, n_h, n_q, n_kv) with the kv dimension innermost — TPU grid steps run
-sequentially per core, so the running (m, l, acc) live in VMEM scratch
-across kv steps and the output block writes once on the last kv step.
+Grid design (canonical TPU flash schedule, head-blocked).  The streaming
+kernels (``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``: more than one
+tile a sequence) run a grid of (B, n_h, scheduled tiles): the two sequence
+dimensions are ONE grid dimension over the tiles ``_tile_schedule`` lists,
+made at trace time from the lengths, the blocks and ``causal``.  A tile no
+entry of which passes the causal test is not in the grid at all (no step,
+no fetch); a tile every entry of which passes runs the body without the
+causal mask; only the tiles the diagonal crosses build the iota mask.  The
+(q block, kv block) of each step and its flags ride in three int32 tables
+handed over as scalar prefetch, read by the BlockSpec index maps and by the
+body.  A non-causal call gets the full rectangle from the same builder (a
+table lookup costs it ~0.12 us a step against index maps computed from the
+grid indices: measured, PERF.md PR 30).  Tiles run row by row with the kv
+block innermost (``flash_bwd_dkv``: column by column, q block innermost) —
+TPU grid steps run sequentially per core, so the running (m, l, acc) live
+in VMEM scratch across a row's tiles and the output block writes once on
+the row's last tile.  The single-tile kernels (``flash_fwd_single``,
+``flash_bwd_fused``: whole sequence in one block) keep the plain (B, n_h)
+grid.
 Each step processes a BLOCK OF HEADS (block_h) at once via batched
 dot_generals: with head_dim 64 a single-head (bq, 64) x (64, bk) matmul
 underfills the MXU and the per-step fixed cost (grid loop + DMA
@@ -39,8 +54,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..telemetry import metrics as _metrics
 
 __all__ = ["flash_attention"]
 
@@ -112,6 +130,93 @@ def _pick_block(L, want):
     return L
 
 
+# A tile's class under the causal test, and where it stands in its row:
+# what a step of the streaming grids reads from its flags word.
+_SKIPPED, _UNMASKED, _MASKED = 0, 1, 2
+_KINDS = ("skipped", "unmasked", "masked")
+_FIRST, _LAST, _KIND_SHIFT = 1, 2, 2
+
+
+def _tile_schedule(Lq, Lk, bq, bk, causal, by_column=False):
+    """The tiles a streaming kernel visits, in order, as three int32
+    tables (q block, kv block, flags) and the number of tiles of each class
+    ``(skipped, unmasked, masked)``.  Pure NumPy, made at trace time.
+
+    Class of tile (iq, ik) under ``qi >= ki``: skipped when no entry passes
+    (``iq*bq + bq - 1 < ik*bk``), unmasked when every entry does
+    (``iq*bq >= ik*bk + bk - 1``), masked when the diagonal crosses it;
+    without ``causal`` every tile is unmasked.  Rows run one after the
+    other, kv blocks ascending (``by_column``: columns, q blocks
+    ascending — the dk/dv accumulation).  ``flags = kind << 2 | last << 1
+    | first``: first and last visited tile of the row, where the scratch
+    is zeroed and the output block written.  A skipped tile is listed only
+    where a row has no other (causal with Lk > Lq: key columns no query
+    reaches): it runs neither body, and its first/last flags write the
+    zeros that row's output is owed."""
+    n_q, n_kv = Lq // bq, Lk // bk
+    iq, ik = np.meshgrid(np.arange(n_q), np.arange(n_kv), indexing="ij")
+    kind = np.full((n_q, n_kv), _UNMASKED)
+    if causal:
+        kind[iq * bq < ik * bk + bk - 1] = _MASKED
+        kind[iq * bq + bq - 1 < ik * bk] = _SKIPPED
+    counts = tuple(int(n) for n in np.bincount(kind.ravel(), minlength=3))
+    if by_column:
+        iq, ik, kind = iq.T, ik.T, kind.T
+    visit = kind != _SKIPPED
+    visit[~visit.any(axis=1), 0] = True
+    seen = np.cumsum(visit, axis=1)
+    first = visit & (seen == 1)
+    last = visit & (seen == seen[:, -1:])
+    flags = kind << _KIND_SHIFT | last * _LAST | first * _FIRST
+    return (iq[visit].astype(np.int32), ik[visit].astype(np.int32),
+            flags[visit].astype(np.int32)), counts
+
+
+def _scheduled(kernel, B, n_h, Lq, Lk, bq, bk, causal, by_column=False):
+    """Tables of ``_tile_schedule`` as device constants, with the call's
+    tiles banked in ``mxnet_flash_tiles_total`` (the schedule is static, so
+    the count is taken where the kernel is built: once a traced call)."""
+    tables, counts = _tile_schedule(Lq, Lk, bq, bk, causal, by_column)
+    for kind, n in zip(_KINDS, counts):
+        _metrics.counter(
+            "mxnet_flash_tiles_total",
+            "Tiles of the streaming flash kernels' grids by class under "
+            "the causal test, over batch and head blocks, a traced call.",
+            labels={"kernel": kernel, "kind": kind}).inc(B * n_h * n)
+    return [jnp.asarray(t) for t in tables]
+
+
+def _step_tile(tq_ref, tk_ref, tf_ref):
+    """(iq, ik, first, last, kind) of this grid step, from the tables."""
+    t = pl.program_id(2)
+    flags = tf_ref[t]
+    return (tq_ref[t], tk_ref[t], (flags & _FIRST) != 0,
+            (flags & _LAST) != 0, flags >> _KIND_SHIFT)
+
+
+def _run_tile(body, kind, causal):
+    """Run ``body(causal)`` as the tile's class asks: with the causal mask
+    where the diagonal crosses the tile, without it where every entry
+    passes, not at all on a skipped tile.  A non-causal schedule holds
+    unmasked tiles only, so its kernel compiles the one body."""
+    if not causal:
+        body(False)
+        return
+    pl.when(kind == _MASKED)(functools.partial(body, True))
+    pl.when(kind == _UNMASKED)(functools.partial(body, False))
+
+
+_Q, _KV = 0, 1   # which of the schedule's tables names a step's block
+
+
+def _rows_spec(hb, blk, width, table):
+    """A head block's rows of the step's q block (``table=_Q``) or kv
+    block (``_KV``), ``width`` wide, in the streaming grids (b, h, t)."""
+    return pl.BlockSpec(
+        (1, hb, blk, width),
+        lambda b, h, t, *tables: (b, h, tables[table][t], _zi()))
+
+
 def _mask_block(sq_ref, skv_ref, causal, iq, ik, bq, bk):
     """(bq, bk) bool mask for one tile, or None when the tile needs no
     masking at all (seg_q=None, non-causal — the static no-mask
@@ -179,6 +284,15 @@ def _seg_lane_spec(blk, index_map):
                         lambda *g: (*index_map(*g), _zi(), _zi()))
 
 
+def _seg_specs(blk, table):
+    """(row layout, lane layout) BlockSpecs of the step's q (``_Q``) or kv
+    (``_KV``) block of segment ids, in the streaming grids (b, h, t)."""
+    return (pl.BlockSpec((1, blk, _LANES), lambda b, h, t, *tables:
+                         (b, tables[table][t], _zi())),
+            _seg_lane_spec(blk, lambda b, h, t, *tables:
+                           (b, tables[table][t])))
+
+
 def _apply_mask(s, mask):
     return s if mask is None else \
         jnp.where(mask[None], s, jnp.float32(_NEG_INF))
@@ -197,42 +311,43 @@ def _bmm(a, b, contract_a, contract_b):
 # forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, n_kv, has_seg):
+def _fwd_kernel(tq_ref, tk_ref, tf_ref, q_ref, k_ref, v_ref, *rest,
+                causal, scale, has_seg):
     if has_seg:
         sq_ref, skv_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
         sq_ref = skv_ref = None
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    iq, ik, first, last, kind = _step_tile(tq_ref, tk_ref, tf_ref)
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _M_FLOOR)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal + whole q block above the diagonal => every entry masked:
-    # skip the tile's compute entirely (the accumulators pass through)
-    bq_, bk_ = q_ref.shape[2], k_ref.shape[2]
-    live = jnp.bool_(True) if not causal \
-        else (iq * bq_ + bq_ - 1 >= ik * bk_)
-
-    @pl.when(live)
-    def _tile():
+    def _tile(causal):
         # scale is folded into q (a (Hb, bq, d) multiply) instead of into
-        # the (Hb, bq, bk) score tile — the kernel is VPU-bound on tile-
-        # sized elementwise passes, so every saved pass counts
+        # the (Hb, bq, bk) score tile — the kernel is bound on tile-sized
+        # elementwise passes, so every saved pass counts
         q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)        # (Hb, bq, d)
         k = k_ref[0]                                          # (Hb, bk, d)
         v = v_ref[0]
         bq, bk = q.shape[1], k.shape[1]
 
         s = _bmm(q, k, 2, 2)                                  # (Hb, bq, bk)
-        # NOTE a data-dependent uniform-tile fast path (skip the mask when
-        # all segment ids in the tile agree) was measured SLOWER here —
-        # the pl.when-branched body defeats Mosaic's grid pipelining.
-        # The mask only vanishes via the STATIC specialization (seg=None)
+        # NOTE two predicates were tried for leaving the mask out of a
+        # tile.  One computed from segment-id DATA in VMEM (skip the mask
+        # when all ids of the tile agree) was measured SLOWER: the branch
+        # is known only once the tile is fetched and defeats Mosaic's grid
+        # pipelining, so the segment mask only vanishes via the STATIC
+        # specialization (seg=None).  The causal one is a SCALAR of the
+        # prefetched schedule, known before the step (_run_tile's two
+        # bodies), and costs the pipelining nothing — but buys little: at
+        # (2, 32, 4096, 192/128) the three kernels take 18.73 ms with it
+        # and 18.81 with one masked body on the same tiles (v5e, PR 30);
+        # the mask's iota, compare and select ride passes that the loads
+        # and stores of the 1 MB score tile bound anyway
         s = _apply_mask(s, _mask_block(sq_ref, skv_ref, causal, iq, ik,
                                        bq, bk))
 
@@ -251,7 +366,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, scale, n_kv, has_seg):
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ik == n_kv - 1)
+    _run_tile(_tile, kind, causal)
+
+    @pl.when(last)
     def _finish():
         l = l_scr[:, :, :1]
         safe_l = jnp.where(l == jnp.float32(0.0), jnp.float32(1.0), l)  # fully-masked rows
@@ -342,46 +459,37 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_k, block_h,
         # whole sequence in one tile: direct-softmax kernel, no streaming
         return _fwd_single(q, k, v, seg_q, seg_kv, causal, scale, hb,
                            interpret)
-    grid = (B, n_h, n_q, n_kv)
     has_seg = seg_q is not None
-    in_specs = [
-        pl.BlockSpec((1, hb, bq, D), lambda b, h, i, j: (b, h, i, _zi())),
-        pl.BlockSpec((1, hb, bk, D), lambda b, h, i, j: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bk, Dv), lambda b, h, i, j: (b, h, j, _zi())),
-    ]
+    tables = _scheduled("flash_fwd", B, n_h, Lq, Lk, bq, bk, causal)
+    q_rows = functools.partial(_rows_spec, hb, bq, table=_Q)
+    kv_rows = functools.partial(_rows_spec, hb, bk, table=_KV)
+    in_specs = [q_rows(D), kv_rows(D), kv_rows(Dv)]
     inputs = [q, k, v]
     if has_seg:
-        seg_q = _seg_row_layout(seg_q, Lq)
-        seg_kv = _seg_lane_layout(seg_kv, Lk, bk)
-        in_specs += [
-            pl.BlockSpec((1, bq, _LANES), lambda b, h, i, j: (b, i, _zi())),
-            _seg_lane_spec(bk, lambda b, h, i, j: (b, j)),
-        ]
-        inputs += [seg_q, seg_kv]
+        in_specs += [_seg_specs(bq, _Q)[0], _seg_specs(bk, _KV)[1]]
+        inputs += [_seg_row_layout(seg_q, Lq),
+                   _seg_lane_layout(seg_kv, Lk, bk)]
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                          n_kv=n_kv, has_seg=has_seg),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, hb, bq, Dv),
-                         lambda b, h, i, j: (b, h, i, _zi())),
-            pl.BlockSpec((1, hb, bq, _STAT),
-                         lambda b, h, i, j: (b, h, i, _zi())),
-        ],
+                          has_seg=has_seg),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=(B, n_h, tables[0].shape[0]),
+            in_specs=in_specs,
+            out_specs=[q_rows(Dv), q_rows(_STAT)],
+            scratch_shapes=[
+                pltpu.VMEM((hb, bq, _LANES), jnp.float32),
+                pltpu.VMEM((hb, bq, _LANES), jnp.float32),
+                pltpu.VMEM((hb, bq, Dv), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Lq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, Lq, _STAT), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((hb, bq, _LANES), jnp.float32),
-            pltpu.VMEM((hb, bq, _LANES), jnp.float32),
-            pltpu.VMEM((hb, bq, Dv), jnp.float32),
-        ],
         interpret=interpret,
         name="flash_fwd",
-    )(*inputs)
+    )(*tables, *inputs)
     return out, lse[..., 0]  # lse (B, H, Lq)
 
 
@@ -389,26 +497,20 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_k, block_h,
 # backward
 # --------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               *rest, causal, scale, n_kv, has_seg):
+def _dq_kernel(tq_ref, tk_ref, tf_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+               delta_ref, *rest, causal, scale, has_seg):
     if has_seg:
         sq_ref, skv_ref, dq_ref, dq_scr = rest
     else:
         dq_ref, dq_scr = rest
         sq_ref = skv_ref = None
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+    iq, ik, first, last, kind = _step_tile(tq_ref, tk_ref, tf_ref)
 
-    @pl.when(ik == 0)
+    @pl.when(first)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    bq_, bk_ = q_ref.shape[2], k_ref.shape[2]
-    live = jnp.bool_(True) if not causal \
-        else (iq * bq_ + bq_ - 1 >= ik * bk_)
-
-    @pl.when(live)
-    def _tile():
+    def _tile(causal):
         # scale folded into the q load (s must match the fwd logits) and
         # into the dq finish below — never a (Hb, bq, bk) tile pass
         q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)        # (Hb, bq, d)
@@ -427,33 +529,31 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta)         # ds * scale deferred to _finish
         dq_scr[...] += _bmm(ds.astype(k.dtype), k, 2, 1)      # (Hb, bq, d)
 
-    @pl.when(ik == n_kv - 1)
+    _run_tile(_tile, kind, causal)
+
+    @pl.when(last)
     def _finish():
         dq_ref[0] = (dq_scr[...]
                      * jnp.float32(scale)).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                *rest, causal, scale, n_q, has_seg):
+def _dkv_kernel(tq_ref, tk_ref, tf_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, *rest, causal, scale, has_seg):
     if has_seg:
         sqT_ref, skvT_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
     else:
         dk_ref, dv_ref, dk_scr, dv_scr = rest
         sqT_ref = skvT_ref = None
-    ik = pl.program_id(2)   # kv block: outer
-    iq = pl.program_id(3)   # q block: inner (sequential accumulation)
+    # the schedule runs column by column here: first and last are those of
+    # the kv block's column, whose q blocks accumulate one after the other
+    iq, ik, first, last, kind = _step_tile(tq_ref, tk_ref, tf_ref)
 
-    @pl.when(iq == 0)
+    @pl.when(first)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    bq_, bk_ = q_ref.shape[2], k_ref.shape[2]
-    live = jnp.bool_(True) if not causal \
-        else (iq * bq_ + bq_ - 1 >= ik * bk_)
-
-    @pl.when(live)
-    def _tile():
+    def _tile(causal):
         q = q_ref[0]                                          # (Hb, bq, d)
         qs = q * jnp.asarray(scale, q_ref.dtype)   # scaled copy: sT only —
         # dk below must use RAW q (its scale is applied once in _finish)
@@ -473,7 +573,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dsT = pT * (dpT - delta)      # dsT * scale deferred to _finish
         dk_scr[...] += _bmm(dsT.astype(q.dtype), q, 2, 1)     # (Hb, bk, d)
 
-    @pl.when(iq == n_q - 1)
+    _run_tile(_tile, kind, causal)
+
+    @pl.when(last)
     def _finish():
         dk_ref[0] = (dk_scr[...]
                      * jnp.float32(scale)).astype(dk_ref.dtype)
@@ -581,80 +683,62 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
         return _bwd_fused(q, k, v, seg_q, seg_kv, lse_b, delta_b, do,
                           causal, scale, hb, interpret)
 
-    dq_specs = [
-        pl.BlockSpec((1, hb, bq, D), lambda b, h, i, j: (b, h, i, _zi())),
-        pl.BlockSpec((1, hb, bk, D), lambda b, h, i, j: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bk, Dv), lambda b, h, i, j: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bq, Dv), lambda b, h, i, j: (b, h, i, _zi())),
-        pl.BlockSpec((1, hb, bq, _STAT),
-                     lambda b, h, i, j: (b, h, i, _zi())),
-        pl.BlockSpec((1, hb, bq, _STAT),
-                     lambda b, h, i, j: (b, h, i, _zi())),
-    ]
-    dq_inputs = [q, k, v, do, lse_b, delta_b]
-    dkv_specs = [
-        pl.BlockSpec((1, hb, bq, D), lambda b, h, j, i: (b, h, i, _zi())),
-        pl.BlockSpec((1, hb, bk, D), lambda b, h, j, i: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bk, Dv), lambda b, h, j, i: (b, h, j, _zi())),
-        pl.BlockSpec((1, hb, bq, Dv), lambda b, h, j, i: (b, h, i, _zi())),
-        pl.BlockSpec((1, hb, bq, _STAT),
-                     lambda b, h, j, i: (b, h, i, _zi())),
-        pl.BlockSpec((1, hb, bq, _STAT),
-                     lambda b, h, j, i: (b, h, i, _zi())),
-    ]
-    dkv_inputs = [q, k, v, do, lse_b, delta_b]
+    # one list of specs serves both kernels: each reads its step's blocks
+    # from its own schedule (dq row by row, dkv column by column)
+    q_rows = functools.partial(_rows_spec, hb, bq, table=_Q)
+    kv_rows = functools.partial(_rows_spec, hb, bk, table=_KV)
+    in_specs = [q_rows(D), kv_rows(D), kv_rows(Dv), q_rows(Dv),
+                q_rows(_STAT), q_rows(_STAT)]
+    inputs = [q, k, v, do, lse_b, delta_b]
+    dq_seg_specs, dq_segs, dkv_seg_specs, dkv_segs = [], [], [], []
     if has_seg:
         # two layouts of each segment-id vector: per-sublane-row for the
         # dq kernel's (bq, bk) mask, per-lane for the dkv (bk, bq) mask
-        seg_qr = _seg_row_layout(seg_q, Lq)
-        seg_kvl = _seg_lane_layout(seg_kv, Lk, bk)
-        seg_qT = _seg_lane_layout(seg_q, Lq, bq)
-        seg_kvT = _seg_row_layout(seg_kv, Lk)
-        dq_specs += [
-            pl.BlockSpec((1, bq, _LANES), lambda b, h, i, j: (b, i, _zi())),
-            _seg_lane_spec(bk, lambda b, h, i, j: (b, j)),
-        ]
-        dq_inputs += [seg_qr, seg_kvl]
-        dkv_specs += [
-            _seg_lane_spec(bq, lambda b, h, j, i: (b, i)),
-            pl.BlockSpec((1, bk, _LANES), lambda b, h, j, i: (b, j, _zi())),
-        ]
-        dkv_inputs += [seg_qT, seg_kvT]
+        q_row, q_lane = _seg_specs(bq, _Q)
+        kv_row, kv_lane = _seg_specs(bk, _KV)
+        dq_seg_specs = [q_row, kv_lane]
+        dq_segs = [_seg_row_layout(seg_q, Lq),
+                   _seg_lane_layout(seg_kv, Lk, bk)]
+        dkv_seg_specs = [q_lane, kv_row]
+        dkv_segs = [_seg_lane_layout(seg_q, Lq, bq),
+                    _seg_row_layout(seg_kv, Lk)]
 
+    dq_tables = _scheduled("flash_bwd_dq", B, n_h, Lq, Lk, bq, bk, causal)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=scale,
-                          n_kv=n_kv, has_seg=has_seg),
-        grid=(B, n_h, n_q, n_kv),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, hb, bq, D),
-                               lambda b, h, i, j: (b, h, i, _zi())),
+                          has_seg=has_seg),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(dq_tables),
+            grid=(B, n_h, dq_tables[0].shape[0]),
+            in_specs=in_specs + dq_seg_specs,
+            out_specs=q_rows(D),
+            scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(*dq_inputs)
+    )(*dq_tables, *inputs, *dq_segs)
 
+    dkv_tables = _scheduled("flash_bwd_dkv", B, n_h, Lq, Lk, bq, bk, causal,
+                            by_column=True)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          n_q=n_q, has_seg=has_seg),
-        grid=(B, n_h, n_kv, n_q),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, hb, bk, D), lambda b, h, j, i: (b, h, j, _zi())),
-            pl.BlockSpec((1, hb, bk, Dv),
-                         lambda b, h, j, i: (b, h, j, _zi())),
-        ],
+                          has_seg=has_seg),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(dkv_tables),
+            grid=(B, n_h, dkv_tables[0].shape[0]),
+            in_specs=in_specs + dkv_seg_specs,
+            out_specs=[kv_rows(D), kv_rows(Dv)],
+            scratch_shapes=[
+                pltpu.VMEM((hb, bk, D), jnp.float32),
+                pltpu.VMEM((hb, bk, Dv), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((hb, bk, D), jnp.float32),
-            pltpu.VMEM((hb, bk, Dv), jnp.float32),
-        ],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(*dkv_inputs)
+    )(*dkv_tables, *inputs, *dkv_segs)
     return dq, dk, dv
 
 

@@ -113,8 +113,21 @@ def test_flash_kernel_compiles_for_v5e(one_chip, shape, dtype, causal, seg,
 def test_flash_kernels_compile_with_192_wide_keys_and_128_wide_values(
         one_chip):
     """Latent attention's shapes at the benchmark's size: causal, seq 4096,
-    so the three streaming kernels, forward and backward."""
+    so the three streaming kernels, forward and backward, each over the
+    scheduled tiles alone: 36 of a head's 8 x 8, 2,304 of the rectangle's
+    4,096 a call (``mxnet_flash_tiles_total``, banked where the kernel is
+    built)."""
     from mxnet_tpu.kernels.flash_attention import flash_attention
+    from mxnet_tpu.telemetry import metrics
+
+    def tiles(kernel):
+        return {kind: getattr(metrics.REGISTRY.get(
+            "mxnet_flash_tiles_total",
+            labels={"kernel": kernel, "kind": kind}), "value", 0)
+            for kind in ("masked", "unmasked", "skipped")}
+
+    kernels = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    before = {kernel: tiles(kernel) for kernel in kernels}
     qk = jax.ShapeDtypeStruct((2, 32, 4096, 192), jnp.bfloat16,
                               sharding=one_chip)
     v = jax.ShapeDtypeStruct((2, 32, 4096, 128), jnp.bfloat16,
@@ -126,8 +139,12 @@ def test_flash_kernels_compile_with_192_wide_keys_and_128_wide_values(
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
         .lower(qk, qk, v).compile().as_text()
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for kernel in kernels:
         assert kernel in text, kernel
+        grown = {kind: n - before[kernel][kind]
+                 for kind, n in tiles(kernel).items()}
+        assert grown == {"masked": 512, "unmasked": 1792, "skipped": 1792}, \
+            (kernel, grown)
 
 
 def test_routed_experts_compile_to_grouped_kernels_for_v5e(one_chip):

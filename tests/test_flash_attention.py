@@ -16,7 +16,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from mxnet_tpu.kernels.flash_attention import flash_attention
+from mxnet_tpu.kernels.flash_attention import (
+    _FIRST, _KIND_SHIFT, _LAST, _MASKED, _SKIPPED, _UNMASKED,
+    _tile_schedule, flash_attention)
 from mxnet_tpu.ops.contrib import _dense_sdpa
 
 
@@ -235,6 +237,179 @@ def test_flash_grad_parity_multi_tile(causal):
     for name, a, b in zip("qkv", g1, g2):
         d = float(jnp.max(jnp.abs(a - b)))
         assert d < 1e-4, f"multi-tile d{name} max diff {d}"
+
+
+# -- the tile schedule of the streaming kernels --------------------------------
+
+SCHEDULES = [
+    # (Lq, Lk, bq, bk, causal)
+    pytest.param(4096, 4096, 512, 512, True, id="cell_8x8_causal"),
+    pytest.param(512, 512, 128, 128, True, id="4x4_causal"),
+    pytest.param(512, 512, 128, 128, False, id="4x4_full"),
+    pytest.param(512, 512, 256, 128, True, id="bq_twice_bk_causal"),
+    pytest.param(512, 512, 128, 256, True, id="bk_twice_bq_causal"),
+    pytest.param(384, 512, 128, 64, True, id="3x8_unequal_blocks_causal"),
+    pytest.param(256, 512, 128, 128, True, id="Lk_twice_Lq_causal"),
+    pytest.param(512, 256, 128, 128, True, id="Lq_twice_Lk_causal"),
+    pytest.param(128, 512, 128, 128, True, id="one_row_causal"),
+    pytest.param(256, 512, 128, 128, False, id="cross_lengths_full"),
+]
+
+
+@pytest.mark.parametrize("by_column", [False, True], ids=["rows", "columns"])
+@pytest.mark.parametrize("Lq,Lk,bq,bk,causal", SCHEDULES)
+def test_tile_schedule_matches_the_dense_mask(Lq, Lk, bq, bk, causal,
+                                              by_column):
+    """The builder alone, against the dense ``qi >= ki`` mask: a tile's
+    class is what the mask reads there (none true / all true / mixed),
+    every tile that is not skipped is listed exactly once, a skipped one
+    only as the sole tile of a line that has no other, and each row's
+    (``by_column``: each column's) first and last flags sit on its first
+    and last listed tile."""
+    (tq, tk, flags), counts = _tile_schedule(Lq, Lk, bq, bk, causal,
+                                             by_column)
+    assert tq.dtype == tk.dtype == flags.dtype == np.int32
+    dense = (np.arange(Lq)[:, None] >= np.arange(Lk)[None, :]) if causal \
+        else np.ones((Lq, Lk), bool)
+    n_q, n_kv = Lq // bq, Lk // bk
+    want = {}
+    for iq in range(n_q):
+        for ik in range(n_kv):
+            tile = dense[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk]
+            want[iq, ik] = _UNMASKED if tile.all() else \
+                _MASKED if tile.any() else _SKIPPED
+    assert counts == tuple(list(want.values()).count(c)
+                           for c in (_SKIPPED, _UNMASKED, _MASKED))
+
+    listed = list(zip(tq.tolist(), tk.tolist()))
+    assert len(set(listed)) == len(listed)
+    kinds = {t: f >> _KIND_SHIFT for t, f in zip(listed, flags.tolist())}
+    assert all(kinds[t] == want[t] for t in listed)
+    assert {t for t, c in want.items() if c != _SKIPPED} <= set(listed)
+
+    # lines in order, the inner index ascending within a line; the flags on
+    # the line's two ends and nowhere else
+    outer, inner = (tk, tq) if by_column else (tq, tk)
+    n_outer = n_kv if by_column else n_q
+    assert sorted(set(outer.tolist())) == list(range(n_outer))
+    assert np.all(np.diff(outer) >= 0)
+    for o in range(n_outer):
+        at = np.flatnonzero(outer == o)
+        assert np.all(np.diff(inner[at]) > 0)
+        assert [bool(f & _FIRST) for f in flags[at]] == \
+            [i == 0 for i in range(len(at))]
+        assert [bool(f & _LAST) for f in flags[at]] == \
+            [i == len(at) - 1 for i in range(len(at))]
+        skipped = [t for t in at if flags[t] >> _KIND_SHIFT == _SKIPPED]
+        assert not skipped or len(at) == 1
+    if causal and not by_column:
+        # row 0 under equal blocks: its only tile is the masked one
+        first_row = flags[tq == 0]
+        if bq == bk:
+            assert first_row.tolist() == \
+                [_MASKED << _KIND_SHIFT | _LAST | _FIRST]
+
+
+def test_tile_schedule_at_the_cell_shape_counts():
+    """Batch 2 x 32 heads x (8 x 8 tiles), causal: 36 of 64 tiles a head
+    are visited, 8 of them masked — the numbers PERF.md quotes."""
+    (tq, _tk, _f), counts = _tile_schedule(4096, 4096, 512, 512, True)
+    assert len(tq) == 36 and counts == (28, 28, 8)
+    assert tuple(64 * n for n in counts) == (1792, 1792, 512)
+
+
+def _dense_oracle(q, k, v, seg_q, seg_kv, causal, scale):
+    """Dense attention with a rectangular mask (``_dense_sdpa`` takes one
+    segment vector and square scores)."""
+    att = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    if seg_q is not None:
+        att = jnp.where(seg_q[:, None, :, None] == seg_kv[:, None, None, :],
+                        att, -1e9)
+    if causal:
+        cm = np.arange(q.shape[2])[:, None] >= np.arange(k.shape[2])[None]
+        att = jnp.where(cm[None, None], att, -1e9)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(att, -1), v)
+
+
+TILE_GRIDS = [
+    # Lq, Lk, block_q, block_k, causal, segment ids
+    pytest.param(512, 512, 128, 128, True, False, id="4x4_causal"),
+    pytest.param(512, 512, 128, 128, True, True, id="4x4_causal_segids"),
+    pytest.param(512, 512, 128, 128, False, True, id="4x4_full_segids"),
+    pytest.param(512, 512, 256, 128, True, False, id="2x4_causal_bq_wider"),
+    pytest.param(512, 512, 128, 256, True, True,
+                 id="4x2_causal_bk_wider_segids"),
+    pytest.param(256, 512, 128, 128, False, False, id="2x4_cross_full"),
+    pytest.param(256, 512, 64, 128, False, True,
+                 id="4x4_cross_full_segids"),
+    pytest.param(256, 512, 128, 128, True, False,
+                 id="2x4_causal_unreached_keys"),
+]
+
+
+@pytest.mark.parametrize("Lq,Lk,bq,bk,causal,seg", TILE_GRIDS)
+def test_flash_streaming_parity_over_tile_grids(Lq, Lk, bq, bk, causal, seg):
+    """Forward and the three gradients of the streaming kernels against the
+    dense oracle on grids where skipped, unmasked and masked tiles all
+    occur in one call (4 x 4 causal), with and without segment ids, with
+    unequal blocks, and with Lq != Lk; key columns no query reaches
+    (causal, Lk > Lq) get exact zeros for dk and dv."""
+    r = np.random.RandomState(13)
+    B, H, D = 2, 2, 32
+    q = jnp.asarray(r.randn(B, H, Lq, D), jnp.float32)
+    k = jnp.asarray(r.randn(B, H, Lk, D), jnp.float32)
+    v = jnp.asarray(r.randn(B, H, Lk, D), jnp.float32)
+    seg_q = seg_kv = None
+    if seg:
+        # three segments a row, their borders inside tiles
+        seg_q = jnp.asarray(np.stack([np.arange(Lq) // 200,
+                                      np.arange(Lq) // 170]), jnp.int32)
+        seg_kv = jnp.asarray(np.stack([np.arange(Lk) // 200,
+                                       np.arange(Lk) // 170]), jnp.int32)
+    scale = 1.0 / D ** 0.5
+    w = jnp.asarray(r.randn(B, H, Lq, D), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, seg_q, seg_kv, causal, scale,
+                               block_q=bq, block_k=bk, interpret=True)
+
+    def dense(q, k, v):
+        return _dense_oracle(q, k, v, seg_q, seg_kv, causal, scale)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               rtol=1e-5, atol=1e-5)
+    g1 = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    g2 = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    for name, a, b in zip("qkv", g1, g2):
+        d = float(jnp.max(jnp.abs(a - b)))
+        assert d < 1e-4, f"d{name} max diff {d}"
+    if causal and Lk > Lq:
+        assert not np.any(np.asarray(g1[1][:, :, Lq:]))
+        assert not np.any(np.asarray(g1[2][:, :, Lq:]))
+
+
+def test_flash_tiles_counter_counts_a_traced_call():
+    """``mxnet_flash_tiles_total{kernel, kind}`` grows by the call's tiles
+    over batch and head blocks, where the kernel is built."""
+    from mxnet_tpu.telemetry import metrics
+
+    def read(kernel):
+        return [getattr(metrics.REGISTRY.get(
+            "mxnet_flash_tiles_total",
+            labels={"kernel": kernel, "kind": kind}), "value", 0)
+            for kind in ("skipped", "unmasked", "masked")]
+
+    names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    before = {n: read(n) for n in names}
+    q = jnp.zeros((2, 4, 512, 16), jnp.float32)
+    jax.grad(lambda q: flash_attention(
+        q, q, q, None, None, True, 0.25, block_q=128, block_k=128,
+        block_h=2, interpret=True).sum())(q)
+    for n in names:   # 2 x (4 / 2) head blocks x (6, 6, 4) tiles
+        assert [a - b for a, b in zip(read(n), before[n])] == [24, 24, 16]
 
 
 # -- a value width that differs from the query/key width (latent attention) ---
