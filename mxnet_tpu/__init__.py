@@ -44,8 +44,8 @@ def _apply_x64():
 
 def _apply_compile_cache():
     # One persistent compile cache for every entry point (chip_smoke.py,
-    # bench.py children, the serving replica): a chip call starts with no
-    # compiled code, and BERT-base alone compiles for most of a minute.
+    # the benchmark's runner, the serving replica): a chip call starts with
+    # no compiled code, and BERT-base alone compiles for most of a minute.
     # Where JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and
     # nothing is set here.  Otherwise the cache lives at ONE fixed path in
     # the checkout (git-ignored): the path is part of the cache key, so a

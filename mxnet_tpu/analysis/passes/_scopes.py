@@ -65,12 +65,6 @@ HOT_PATHS = {
     "telemetry/costmodel.py": {"__call__", "_probe", "wrap_jit",
                                "wrap_jit_if_armed", "_on_time_span"},
     "telemetry/httpd.py": {"do_GET"},
-    # perf-regression gate (ISSUE 16): the steady-state capture window is
-    # the measured region of every snapshot lane — a host sync inside it
-    # would serialize the dispatches it is counting (lane warmup/drain
-    # syncs deliberately sit OUTSIDE these functions)
-    "telemetry/perfgate.py": {"_steady_capture", "_metric_value",
-                              "_site_rollup"},
     # elastic control plane (ISSUE 11): the controller's monitor loop
     # polls several times a second and the heartbeat note sits on the
     # worker's step path — both must stay host-sync-free and

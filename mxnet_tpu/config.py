@@ -100,31 +100,6 @@ KNOWN_VARS = {
         "/statusz (knobs, world, stepclock verdict, serving gauges), "
         "/ledger.json (cost + op ledgers).  0 binds an ephemeral port; "
         "unset (default) = no server."),
-    # perf-regression observatory (ISSUE 16: telemetry.perfgate +
-    # tools/perfgate.py + tools/onchip_sweep.py)
-    "MXNET_PERFGATE_BASELINE": (
-        None, str,
-        "Path of the committed analytic perf baseline the gate diffs "
-        "against (tools/perfgate.py --check, /perfgate.json, "
-        "telemetry_report --perf-diff).  Unset (default) = the repo's "
-        "tests/perf_baseline.json."),
-    "MXNET_PERFGATE_LANES": (
-        None, str,
-        "Comma-separated lane filter for perfgate snapshot/check runs "
-        "(e.g. 'bert_headline,trainer_fused_kvstore').  Unset (default) "
-        "= every registered lane; a filtered --check is reported as "
-        "PARTIAL."),
-    "MXNET_PERFGATE_CHILD_TIMEOUT_S": (
-        "420", float,
-        "Per-lane wall budget for the perfgate snapshot child processes "
-        "(each lane compiles + runs its steady-state window on the CPU "
-        "backend in a fresh interpreter)."),
-    "MXNET_PERFGATE_MFU_BAND": (
-        "0.25", float,
-        "Relative band for the on-chip sweep's measured-vs-analytic MFU "
-        "assertion (tools/onchip_sweep.py: "
-        "analytic MFU counts ALL XLA-emitted flops, so it sits a few "
-        "percent above the hand-derived number)."),
     "MXNET_STEPCLOCK_WINDOW": (
         "64", int,
         "Steps the StepClock keeps for the rolling input-/comms-/compute-"
@@ -185,16 +160,6 @@ KNOWN_VARS = {
         "jax matmul precision for float32 ops: default|high|highest. "
         "'highest' gives true-f32 MXNet numerics (3/6-pass bf16 on the MXU); "
         "set 'default' to trade accuracy for raw MXU throughput."),
-    "MXNET_FUSED_ATTENTION": (
-        "1", int,
-        "If 1 (default), contrib.masked_selfatt lowers to the Pallas flash "
-        "attention kernel on TPU (seq multiple of 128); 0 forces the dense "
-        "masked-softmax fallback everywhere."),
-    "MXNET_FLASH_MIN_SEQ": (
-        "256", int,
-        "Shortest sequence the flash kernel handles; below it the dense "
-        "path wins on measured v5e step time (XLA's fused softmax beats "
-        "per-grid-step kernel cost at tiny (L, L) tiles)."),
     "MXNET_TPU_JIT_IMPERATIVE": (
         "1", int,
         "If 1, imperative op dispatch goes through a per-(op,shape,dtype,attrs) "
@@ -494,16 +459,6 @@ KNOWN_VARS = {
         "Directory the on-demand-compiled native libraries are built "
         "into and loaded from (default mxnet_tpu/src/build/ inside the "
         "checkout; set it when the package dir is read-only)."),
-    # flash-attention kernel tuning (single-tile kernels only)
-    "MXNET_FLASH_BLOCK_H_FWD": (
-        None, int,
-        "Force the head-block size of the single-tile flash-attention "
-        "FORWARD kernel (must divide the head count; non-divisors fall "
-        "through to the auto pick). Unset = VMEM-budget auto-tune."),
-    "MXNET_FLASH_BLOCK_H_BWD": (
-        None, int,
-        "Force the head-block size of the single-tile flash-attention "
-        "BACKWARD kernel (same divisibility contract as _FWD)."),
 }
 
 _lock = threading.Lock()

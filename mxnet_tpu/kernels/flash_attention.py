@@ -98,17 +98,6 @@ def _pick_block_h(H, bq, bk, single_tile=False):
     the fused BWD holds s/p/dp/ds simultaneously — hb=4 there needs
     16.3M scoped vmem against the 16.0M in-context limit (measured OOM
     inside the full train step), so bwd gets 3MB → hb=3."""
-    if single_tile:   # knobs apply ONLY to the single-tile kernels — the
-        # streaming grids carry running scratch the forced tile would blow
-        from .. import config
-        forced = config.get(
-            "MXNET_FLASH_BLOCK_H_BWD" if single_tile == "bwd"
-            else "MXNET_FLASH_BLOCK_H_FWD")
-        if forced and H % int(forced) == 0:
-            # non-divisor head counts FALL THROUGH to the auto pick (not an
-            # error): the knob targets one model's shape, but the same
-            # process also compiles other head counts
-            return int(forced)
     if single_tile == "bwd":
         budget = 3 * 1024 * 1024
     elif single_tile:

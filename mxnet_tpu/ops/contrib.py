@@ -77,27 +77,27 @@ def _interleaved_matmul_selfatt_valatt(qkv, att, heads=1):
     return out.reshape(L, B, -1)
 
 
+# Below this sequence length attention stays dense on a TPU too.  A
+# crossover, not structural, last measured on an older toolchain; ROADMAP A5
+# is to read both sides of it in the benchmark's cells and then decide here,
+# in code.
+_FLASH_MIN_SEQ = 256
+
+
 def _flash_eligible(seq, head_dim, value_dim=None):
     """Whether the Pallas TPU flash kernel's tiling applies to these shapes
-    (lane-aligned seq blocks; query/key width ``head_dim`` and value width
-    ``value_dim``, the same unless given); the platform choice itself
-    happens at XLA lowering via lax.platform_dependent, never by host-side
-    guessing: a program lowered for the CPU (the test platform, or a
-    host-side island of a TPU process) carries the dense branch only, one
-    lowered for a TPU — attached or merely described — carries the kernel.
-
-    The seq floor (MXNET_FLASH_MIN_SEQ, default 256) is a crossover, not
-    structural; it has not been re-measured on today's code (PERF.md,
-    Open questions).
+    (lane-aligned seq blocks of at least ``_FLASH_MIN_SEQ``; query/key width
+    ``head_dim`` and value width ``value_dim``, the same unless given); the
+    platform choice itself happens at XLA lowering via
+    lax.platform_dependent, never by host-side guessing: a program lowered
+    for the CPU (the test platform, or a host-side island of a TPU process)
+    carries the dense branch only, one lowered for a TPU — attached or
+    merely described — carries the kernel.
 
     Shapes alone decide: a kernel the TPU compiler refuses raises at
     compile time, it never silently takes the dense path."""
-    from .. import config
-    if not config.get_int("MXNET_FUSED_ATTENTION", 1):
-        return False
-    floor = config.get_int("MXNET_FLASH_MIN_SEQ", 256)
     value_dim = head_dim if value_dim is None else value_dim
-    return seq >= floor and seq % 128 == 0 and head_dim % 8 == 0 \
+    return seq >= _FLASH_MIN_SEQ and seq % 128 == 0 and head_dim % 8 == 0 \
         and value_dim % 8 == 0
 
 

@@ -138,9 +138,10 @@ def _softrelu(x):
 def _gelu_tanh_default():
     """Knob-resolved default for gelu's ``approximate`` attr (ISSUE 7
     satellite: the tanh form is an untried MFU lever, ROADMAP A3).
-    Resolved when an executable is first built for the attr set — same
-    trace-time-knob contract as MXNET_FUSED_ATTENTION; pass an explicit
-    ``approximate=`` (it is part of the jit cache key) to flip per call."""
+    Resolved when an executable is first built for the attr set (a knob
+    read at trace time: a later change of the environment does not reach a
+    program already built); pass an explicit ``approximate=`` (it is part
+    of the jit cache key) to flip per call."""
     from .. import config
     return bool(config.get_int("MXNET_GELU_TANH", 0))
 

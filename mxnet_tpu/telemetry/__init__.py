@@ -69,7 +69,6 @@ from . import costmodel          # noqa: E402 — needs metrics loaded
 from . import aggregate          # noqa: E402 — needs tracer/metrics/stepclock
 from . import flightrec          # noqa: E402 — needs aggregate
 from . import httpd              # noqa: E402 — needs metrics/costmodel
-from . import perfgate           # noqa: E402 — needs config/costmodel
 from .ledger import record_op
 from .metrics import (  # noqa: F401
     DEFAULT_BUCKETS, REGISTRY, Counter, Gauge, Histogram, MetricsRegistry,

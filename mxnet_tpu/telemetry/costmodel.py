@@ -33,8 +33,8 @@ into a first-class observability layer (ISSUE 12 tentpole):
   XLA counted, not a hand-derived 6N formula), arithmetic intensity, and
   the compute- vs memory-bound roofline verdict against the chip's peak
   flops and HBM bandwidth (``MXNET_PEAK_FLOPS`` / ``MXNET_PEAK_HBM_GBS``
-  override the built-in device table).  ``bench.py`` embeds this in every
-  BENCH row; ``telemetry.report(cost=True)`` renders the site table.
+  override the built-in device table).  ``telemetry.report(cost=True)``
+  renders the site table.
 - **fits-per-shape estimator** (:func:`estimate_memory`) — analytic
   per-device HBM for one fused training step (params + optimizer state +
   grads + batch + activations) under a declarative rule pack on a named
@@ -86,11 +86,12 @@ _COMPILE_TICK = 0
 _PENDING_COMPILE_S: list = []
 _pending_lock = threading.Lock()
 
-# CPU "peaks" exist ONLY so the hardware-free perf gate
-# (telemetry/perfgate.py, tests/perf_baseline.json) has a fixed ridge to
-# compute its analytic figures against; they describe no machine.  No
-# measured row can carry an MFU against them: bench.py and chip_smoke.py
-# refuse to run without a TPU.
+# CPU "peaks" describe no machine.  They exist so that what reads
+# peak_flops()/roofline() in a process whose first device is the CPU gets a
+# fixed ridge: the auto-sharder's analytic step-time model
+# (autoshard/planner.py; tests/autoshard_plan_golden.json freezes its plans)
+# and telemetry_report --cost.  No measured row carries an MFU against
+# them: chip_smoke.py and the benchmark's runner refuse to run without a TPU.
 _CPU_GATE_PEAK_FLOPS = 5e11
 _CPU_GATE_PEAK_BYTES_PER_S = 5e10
 # Per-chip peaks keyed by jax's ``device_kind``: (bf16 flop/s, HBM B/s).
@@ -538,8 +539,8 @@ def wrap_jit_if_armed(jf, site):
 
 def peak_flops(dtype="bfloat16"):
     """Per-chip peak flops for MFU accounting.  MXNET_PEAK_FLOPS wins;
-    else the device table (bf16 peaks; /4 for float32).  The CPU gets the
-    perf gate's nominal figure; an unlisted TPU kind raises."""
+    else the device table (bf16 peaks; /4 for float32).  The CPU gets a
+    nominal figure that describes no machine; an unlisted TPU kind raises."""
     v = config.get_float("MXNET_PEAK_FLOPS", 0.0)
     if v > 0:
         return v

@@ -60,3 +60,24 @@ def seeded(request):
 def ctx():
     from mxnet_tpu.test_utils import default_context
     return default_context()
+
+
+@pytest.fixture
+def fresh_heartbeat():
+    """``resilience.heartbeat`` keeps a worker's phase, step, error, last
+    beat and beater thread as module state: one process, one worker.  A
+    test that starts a beater or moves the phase gets the state of a fresh
+    process and leaves behind what it found, so that tests sharing a pytest
+    worker do not read each other's phase."""
+    from mxnet_tpu.resilience import heartbeat as hb
+    fresh = {"_phase": "spawned", "_step": None, "_error": None,
+             "_last_beat": None, "_thread": None, "_stop": None}
+    with hb._lock:
+        found = {name: getattr(hb, name) for name in fresh}
+        for name, value in fresh.items():
+            setattr(hb, name, value)
+    yield hb
+    hb.stop()
+    with hb._lock:
+        for name, value in found.items():
+            setattr(hb, name, value)

@@ -8,7 +8,7 @@
    cannot see an illegal block, a kernel GSPMD cannot partition or a program
    that does not fit.  A compile that passes is not a chip run.
 2. chip_smoke.py's phase functions at a tiny size on the CPU, and the
-   refusals: chip_smoke.py and bench.py never fall back to the CPU.
+   refusal: chip_smoke.py never falls back to the CPU.
 
 The topology is described inside a module-scoped fixture (never at import,
 in a skipif or in parametrize): only the worker that runs this file loads
@@ -345,17 +345,6 @@ def _run(code, **env):
     return subprocess.run([sys.executable, "-c", code], cwd=ROOT,
                           env={**base, **env}, capture_output=True,
                           text=True, timeout=120)
-
-
-def test_bench_parent_is_jax_free_and_child_refuses_the_cpu():
-    p = _run("import sys, bench; "
-             "print('jax' in sys.modules or 'mxnet_tpu' in sys.modules)")
-    assert p.stdout.strip() == "False", p.stderr
-    p = _run("import bench, sys; sys.exit(bench.main())",
-             MXNET_BENCH_CHILD="1", MXNET_BENCH_MODEL="bert_3_128_2")
-    row = json.loads(p.stdout.strip().splitlines()[-1])
-    assert p.returncode == 1 and row["value"] == 0.0
-    assert "measures the TPU" in row["extra"]["error"]
 
 
 @pytest.mark.parametrize("module", ["mxnet_tpu.resilience.controller",

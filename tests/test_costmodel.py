@@ -360,12 +360,12 @@ def test_httpd_statusz_and_ledger(server, armed):
     assert "/metrics" in body
 
 
-def test_httpd_healthz_probe(server, monkeypatch, tmp_path):
+def test_httpd_healthz_probe(server, monkeypatch, tmp_path, fresh_heartbeat):
     """ISSUE 13 satellite: /healthz is the router's liveness probe —
     200 with no heartbeat armed (the reply itself proves liveness), 200
     + {phase, heartbeat_age_s} while the armed beater is fresh, 503 once
     it goes stale past MXNET_ROUTER_HANG_S."""
-    from mxnet_tpu.resilience import heartbeat as hb
+    hb = fresh_heartbeat
     status, ctype, body = _get(server, "/healthz")
     rec = json.loads(body)
     assert status == 200 and ctype == "application/json"

@@ -58,7 +58,7 @@ def _events(summary, kind):
 
 # -- protocol units ---------------------------------------------------------
 
-def test_heartbeat_protocol_roundtrip(tmp_path, monkeypatch):
+def test_heartbeat_protocol_roundtrip(tmp_path, monkeypatch, fresh_heartbeat):
     d = str(tmp_path / "hb")
     monkeypatch.setenv("MXNET_ELASTIC_HEARTBEAT_DIR", d)
     monkeypatch.setenv("MXNET_DIST_RANK", "2")
@@ -87,7 +87,7 @@ def test_heartbeat_protocol_roundtrip(tmp_path, monkeypatch):
     assert 2 in recs and 3 not in recs
 
 
-def test_heartbeat_inert_without_dir(monkeypatch):
+def test_heartbeat_inert_without_dir(monkeypatch, fresh_heartbeat):
     monkeypatch.delenv("MXNET_ELASTIC_HEARTBEAT_DIR", raising=False)
     assert not hb.enabled()
     assert hb.start() is False

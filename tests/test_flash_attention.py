@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from mxnet_tpu.kernels.flash_attention import (
     _FIRST, _KIND_SHIFT, _LAST, _MASKED, _SKIPPED, _UNMASKED,
-    _tile_schedule, flash_attention)
+    _pick_block_h, _tile_schedule, flash_attention)
 from mxnet_tpu.ops.contrib import _dense_sdpa
 
 
@@ -308,6 +308,22 @@ def test_tile_schedule_matches_the_dense_mask(Lq, Lk, bq, bk, causal,
         if bq == bk:
             assert first_row.tolist() == \
                 [_MASKED << _KIND_SHIFT | _LAST | _FIRST]
+
+
+@pytest.mark.parametrize("H, bq, bk, single_tile, block_h", [
+    (12, 512, 512, True, 4),      # bert_base.train_s512 (and _dp4), forward
+    (12, 512, 512, "bwd", 3),     # the same cells, the fused backward
+    (16, 512, 512, True, 4),      # bert_large.train_s512, forward
+    (16, 512, 512, "bwd", 2),     # bert_large.train_s512, backward
+    (32, 512, 512, False, 1),     # kanana_2_30b_a3b.train_s4096: streaming,
+                                  # one head a grid step (ROADMAP A1)
+    (32, 256, 256, False, 4),     # streaming with 256 x 256 blocks
+])
+def test_pick_block_h_at_the_cells_shapes(H, bq, bk, single_tile, block_h):
+    """The kernels' head block at the shapes the benchmark's cells run: no
+    option changes it, so a change of these numbers is a change to what a
+    cell compiles, and wants a chip reading."""
+    assert _pick_block_h(H, bq, bk, single_tile) == block_h
 
 
 def test_tile_schedule_at_the_cell_shape_counts():

@@ -4,7 +4,7 @@ every door (the ``gelu`` op, ``LeakyReLU(act_type='gelu')``,
 ``gluon.nn.GELU``) and the ``MXNET_GELU_TANH`` default knob.
 
 The knob resolves when an executable is FIRST BUILT for the attr set
-(trace time, same contract as MXNET_FUSED_ATTENTION) — the knob tests
+(trace time: a program already built keeps what it read) — the knob tests
 use fresh shapes so jax traces anew under the flipped environment.
 """
 

@@ -285,3 +285,43 @@ def test_fused_telemetry_metrics():
     finally:
         telemetry.disable()
         telemetry.clear()
+
+
+def test_trainer_fused_pushpull_counts():
+    """A trainer's gradient set through the fused pushpull: 22 tensors (an
+    embedding, two layers of four square weights, two ffn weights and four
+    vectors, a head), 2 replicas each, one 25 MB bucket.  One warm step and
+    two steady ones: one fused pushpull and one bucket a step, every key
+    fused, nothing through the per-key push/pull, nothing built once warm."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.analysis.runtime import no_retrace
+    shapes = [(256, 64)]
+    for _ in range(2):
+        shapes += [(64, 64)] * 4 + [(64, 256), (256, 64)] + [(64,)] * 4
+    shapes += [(64, 256)]
+    assert len(shapes) == 22
+    dtypes = ["float32"] * len(shapes)
+    keys = [f"w{i}" for i in range(len(shapes))]
+    kv = mx.kv.create("local")
+    kv.set_bucket_size(25)
+    for k, s in zip(keys, shapes):
+        kv.init(k, nd.zeros(s))
+    vals = _make_values(shapes, dtypes, n_rep=2)
+    outs = [nd.zeros(s) for s in shapes]
+    telemetry.enable()
+    try:
+        telemetry.REGISTRY.reset()
+        kv.pushpull_list(keys, vals, outs)
+        outs[0].asnumpy()
+        with no_retrace():
+            for _ in range(2):
+                kv.pushpull_list(keys, vals, outs)
+            outs[0].asnumpy()
+        counts = {name: telemetry.counter(f"mxnet_kvstore_{name}_total").value
+                  for name in ("fused_pushpulls", "fused_buckets",
+                               "fused_keys", "push_bytes", "pull_bytes")}
+        assert counts == {"fused_pushpulls": 3, "fused_buckets": 3,
+                          "fused_keys": 66, "push_bytes": 0, "pull_bytes": 0}
+    finally:
+        telemetry.disable()
+        telemetry.clear()

@@ -269,3 +269,153 @@ def test_allreduce_subset_of_mesh(ctxs):
     for o in out:
         assert_almost_equal(o.asnumpy(), np.full((2,), 10.0, "float32"))
     assert parallel.current_mesh().size == N_DEV  # untouched
+
+
+# -- TrainStep.run at steady state: exact counts -------------------------------
+# What a dispatch of the fused step costs in programs, counted on the CPU: one
+# executable for the step, one dispatch a run(), nothing built once warm.  The
+# cases are the forms the benchmark's cells dispatch (steps=4, a dp=4 mesh,
+# the MLA + MoE decoder whose step has a fourth output, the counters) and the
+# forms the repo's other lanes train with.  Counts only: a CPU run says
+# nothing about time.
+
+def _token_loss(out, labels):
+    logits = out[-1] if isinstance(out, (tuple, list)) else out
+    return mx.nd.softmax_cross_entropy(
+        logits.reshape((-1, logits.shape[-1])).astype("float32"),
+        labels.reshape((-1,))) / labels.size
+
+
+def _tokens(vocab, *shape):
+    r = np.random.RandomState(0)
+    return (nd.array(r.randint(0, vocab, shape).astype(np.int32)),
+            nd.array(r.randint(0, vocab, shape).astype(np.int32)))
+
+
+def _dp_mesh(n):
+    import jax
+    from mxnet_tpu import parallel
+    return parallel.make_mesh(shape=(n,), axis_names=("dp",),
+                              devices=jax.devices()[:n])
+
+
+def _bert_step(batch, seq, steps, dp=1, **step_kw):
+    from mxnet_tpu import parallel
+    from mxnet_tpu.gluon.model_zoo import bert
+    net = bert.bert_model("bert_3_128_2", vocab_size=512, max_length=seq,
+                          dropout=0.0)
+    net.initialize(mx.initializer.Normal(0.02))
+    step = parallel.TrainStep(net, _token_loss,
+                              mx.optimizer.Adam(learning_rate=1e-4),
+                              mesh=_dp_mesh(dp), **step_kw)
+    toks, labs = _tokens(512, steps, batch, seq)
+    return lambda: step.run(toks, labs)
+
+
+def _llama_causal_step(**step_kw):
+    from mxnet_tpu import parallel
+    from mxnet_tpu.gluon.model_zoo.llama import LlamaModel
+    net = LlamaModel(vocab_size=512, num_layers=2, units=64, hidden=172,
+                     heads=4, kv_heads=2, remat=False)
+    net.initialize(mx.initializer.Normal(0.02))
+    step = parallel.TrainStep(net, _token_loss,
+                              mx.optimizer.Adam(learning_rate=1e-4),
+                              mesh=_dp_mesh(1), **step_kw)
+    toks, labs = _tokens(512, 1, 2, 256)
+    return lambda: step.run(toks, labs)
+
+
+def _llama_fsdp_step():
+    from mxnet_tpu import parallel, sharding
+    from mxnet_tpu.gluon.model_zoo.llama import llama_model
+    net = llama_model("llama_tiny", vocab_size=64)
+    net.initialize(mx.initializer.Normal(0.05))
+    step = parallel.TrainStep(
+        net, _token_loss, mx.optimizer.Adam(learning_rate=1e-3),
+        mesh=parallel.DeviceMesh(shape=(2, 2, 2),
+                                 axis_names=("dp", "fsdp", "tp")),
+        donate=True, partition_rules=sharding.llama_fsdp_rules(),
+        data_spec=("dp",))
+    toks, labs = _tokens(64, 16, 16)
+    return lambda: step(toks, labs)
+
+
+def _mla_moe_step():
+    from mxnet_tpu import parallel
+    from mxnet_tpu.gluon.model_zoo import mla_moe
+    net = mla_moe.MLAMoEModel(
+        256, 2, 64, 96,
+        dict(heads=4, qk_nope=16, qk_rope=8, v_head=16, kv_lora_rank=32,
+             rope_base=1e6),
+        dict(hidden_size=32, num_experts=16, num_experts_per_token=3,
+             experts_held=(4, 8), num_shared_experts=2,
+             routed_scaling_factor=2.448), prefix="mlamoe_")
+    net.initialize(mx.initializer.Normal(0.02))
+    step = parallel.TrainStep(
+        net, _token_loss, mx.optimizer.Adam(learning_rate=1e-3),
+        mesh=_dp_mesh(1))
+    toks, labs = _tokens(256, 2, 2, 16)
+    return lambda: step.run(toks, labs)
+
+
+# case -> (builder, the one-per-op programs of TrainStep._resolve's imperative
+#          forward, tokens a dispatch's routed layers report)
+_STEADY_CASES = {
+    "bert_b4_s32_steps2": (lambda: _bert_step(4, 32, 2), 20, 0),
+    "bert_b2_s512_steps2": (lambda: _bert_step(2, 512, 2), 20, 0),
+    "llama_causal_steps1": (_llama_causal_step, 38, 0),
+    "llama_tiny_dp2_fsdp2_tp2_donated": (_llama_fsdp_step, 37, 0),
+    "bert_steps4": (lambda: _bert_step(4, 32, 4), 20, 0),
+    "bert_dp4_steps4": (lambda: _bert_step(8, 32, 4, dp=4), 20, 0),
+    "mla_moe_counters_steps2": (_mla_moe_step, 59, 2 * 2 * 16),
+    "bert_n_micro2": (lambda: _bert_step(4, 32, 2, n_micro=2), 20, 0),
+    "llama_causal_remat": (lambda: _llama_causal_step(remat=True), 38, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(_STEADY_CASES))
+def test_trainstep_run_steady_state(case, monkeypatch):
+    """One warm dispatch, then two that build nothing: every dispatch is
+    one call of one executable (``compiles_in_window == 0`` decides
+    ``correct`` in every benchmark cell; this holds it before a chip minute
+    is spent)."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.analysis.runtime import no_retrace
+    from mxnet_tpu.telemetry import REGISTRY, costmodel
+    build, resolve_programs, moe_tokens = _STEADY_CASES[case]
+    monkeypatch.setenv("MXNET_COSTMODEL_MEMORY", "0")
+    mx.random.seed(0)
+    dispatch = build()
+
+    def counts():
+        return tuple(getattr(REGISTRY.get(name), "value", 0) for name in (
+            "mxnet_sharding_step_dispatches_total",
+            "mxnet_sharding_retraces_total", "mxnet_moe_tokens_total"))
+
+    telemetry.enable()
+    costmodel.disarm()      # arming anew drops the op registry's programs
+    costmodel.arm()
+    costmodel.LEDGER.clear()
+    try:
+        d0, t0, m0 = counts()
+        assert np.isfinite(dispatch().asnumpy()).all()
+        assert counts() == (d0 + 1, t0 + 1, m0 + moe_tokens)
+        with no_retrace():
+            for _ in range(2):
+                assert np.isfinite(dispatch().asnumpy()).all()
+        assert counts() == (d0 + 3, t0 + 1, m0 + 3 * moe_tokens)
+        sites = costmodel.LEDGER.site_summary()
+        step = sites.pop("parallel.TrainStep")
+        assert (step["executables"], step["calls"]) == (1, 3)
+        # What a first dispatch builds beside the step: _resolve runs the net
+        # once imperatively to finish deferred init, one small program an op
+        # and shape, results thrown away.  A TrainStep that resolves from
+        # shapes alone (as lowered() does) takes this count to 0.
+        assert all(s.startswith("op:") for s in sites), sorted(sites)
+        resolve_forward_programs = sum(
+            s["executables"] for s in sites.values())
+        assert resolve_forward_programs == resolve_programs
+    finally:
+        costmodel.disarm()
+        costmodel.LEDGER.clear()
+        telemetry.disable()

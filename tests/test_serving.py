@@ -14,6 +14,11 @@ jitted decode/prefill entries are module-level in serving.models, so
 engines with equal config + shapes share executables.
 """
 
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -28,14 +33,18 @@ EOS = 2
 BOS = 1
 
 
-@pytest.fixture(scope="module")
-def llama_net():
-    mx.random.seed(7)
-    np.random.seed(7)
+def _llama_tiny(seed):
+    mx.random.seed(seed)
+    np.random.seed(seed)
     net = llama.llama_model("llama_tiny", vocab_size=101)
     net.initialize(mx.initializer.Normal(0.05))
     net(mx.nd.array(np.zeros((1, 4), np.int32)))     # finish deferred init
     return net
+
+
+@pytest.fixture(scope="module")
+def llama_net():
+    return _llama_tiny(7)
 
 
 @pytest.fixture(scope="module")
@@ -485,12 +494,7 @@ def draft_net():
     """A DIVERGENT draft (same llama_tiny config — the module-level jits
     are shared — different seed): low acceptance, so the target-token
     fallback path is exercised on every few dispatches."""
-    mx.random.seed(23)
-    np.random.seed(23)
-    net = llama.llama_model("llama_tiny", vocab_size=101)
-    net.initialize(mx.initializer.Normal(0.05))
-    net(mx.nd.array(np.zeros((1, 4), np.int32)))
-    return net
+    return _llama_tiny(23)
 
 
 def test_spec_decode_token_identical_mixed_batch(llama_net, draft_net):
@@ -731,3 +735,76 @@ def test_serving_request_span_tree(llama_net):
         telemetry.clear()
         if not telemetry.env_enabled():
             telemetry.disable()
+
+
+# -- the scheduler's exact counts ---------------------------------------------
+# Serving has no benchmark cell yet: these counts are all that holds its
+# scheduler still.  Each workload runs in a child (tests/_serving_counts_child.py)
+# because programs built and compiles depend on what a process compiled before;
+# the counters include the child's one warm request (3 prompt tokens, 2 new).
+
+_SCHEDULER_COUNTS = {
+    "continuous": {
+        "dispatches": 20,
+        "compiles_in_workload": 2,
+        "counters": {
+            "mxnet_serving_decode_steps_total": 15,
+            "mxnet_serving_prefill_positions_total": 100,
+            "mxnet_serving_prefix_hit_tokens_total": 8,
+            "mxnet_serving_prefix_hits_total": 1,
+            "mxnet_serving_requests_completed_total": 7,
+            "mxnet_serving_token_positions_total": 160,
+            "mxnet_serving_tokens_total": 50,
+        },
+    },
+    "spec_decode": {
+        "dispatches": 36,
+        "compiles_in_workload": 0,
+        "counters": {
+            "mxnet_serving_accepted_draft_tokens_count": 29,
+            "mxnet_serving_accepted_draft_tokens_sum": 0,
+            "mxnet_serving_decode_steps_total": 8,
+            "mxnet_serving_draft_steps_total": 24,
+            "mxnet_serving_prefill_positions_total": 80,
+            "mxnet_serving_requests_completed_total": 5,
+            "mxnet_serving_token_positions_total": 208,
+            "mxnet_serving_tokens_total": 34,
+        },
+    },
+}
+
+
+@pytest.mark.parametrize("workload", list(_SCHEDULER_COUNTS))
+def test_scheduler_counts_are_exact(workload):
+    """Dispatches, programs and the scheduler's counters of one workload
+    after one warm request, in a fresh process.
+
+    ``continuous`` (6 requests over 4 slots with the prefix cache): 5
+    prefills, the tail chunk of the one request whose first 8 tokens hit the
+    prefix cache (through ``llama_multi``) and 14 decode steps = 20
+    dispatches.  Its 2 compiles inside the workload are both that tail-chunk
+    program: the warm request hits no prefix, so the first hit builds it,
+    and the armed ledger's memory analysis (``MXNET_COSTMODEL_MEMORY``, on
+    by default) compiles it once more; a second pass of the workload
+    compiles nothing.  ``spec_decode`` (k = 3, a draft of other weights that
+    is never accepted): 4 target + 4 draft prefills, 21 draft steps, 7
+    verify steps = 36 dispatches, and no compile: the warm request has
+    built all three programs."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = {k: v for k, v in os.environ.items()
+           if k != "MXNET_COSTMODEL_MEMORY"}
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(here), here, env.get("PYTHONPATH", "")])
+    done = subprocess.run(
+        [sys.executable, os.path.join(here, "_serving_counts_child.py"),
+         workload], env=env, capture_output=True, text=True, timeout=240)
+    assert done.returncode == 0, done.stderr[-2000:]
+    got = json.loads(done.stdout.strip().splitlines()[-1])
+    want = _SCHEDULER_COUNTS[workload]
+    assert got["dispatches"] == want["dispatches"]
+    assert got["compiles_in_workload"] == want["compiles_in_workload"]
+    assert got["executables"] == {"serving.llama_prefill": 1,
+                                  "serving.llama_decode": 1,
+                                  "serving.llama_multi": 1}
+    assert {k: got["counters"][k] for k in want["counters"]} \
+        == want["counters"]

@@ -90,10 +90,6 @@ def main(argv=None):
                     help="also render each rank's analytic cost ledger "
                          "(per-site flops / arithmetic intensity / "
                          "peak-HBM / roofline verdict)")
-    ap.add_argument("--perf-diff", metavar="BASELINE",
-                    help="diff every rank's exported cost ledger against "
-                         "a committed perfgate baseline "
-                         "(tests/perf_baseline.json); exit 2 on drift")
     args = ap.parse_args(argv)
     if not args.dir:
         ap.error("no collection dir: pass --dir or set MXNET_TELEMETRY_DIR")
@@ -145,48 +141,6 @@ def main(argv=None):
                 print(f"cost ledger — rank {r['rank']}:")
                 for line in cm.site_table_lines(r["cost"]):
                     print(line)
-
-    if args.perf_diff:
-        # post-mortem gate (ISSUE 16 satellite): dumps from elastic /
-        # router runs diffed offline against the committed analytic
-        # baseline — per-site flops/bytes/peak-HBM only, since a shard
-        # captures one workload, not the gate's lane matrix
-        pg = telemetry.perfgate
-        cm = telemetry.costmodel
-        try:
-            base = pg.load_baseline(args.perf_diff)
-        except pg.BaselineError as e:
-            print(f"perf-diff: {e}", file=sys.stderr)
-            return 2
-        drifted = False
-        for s in snaps:
-            block = s.get("costmodel") or {}
-            summ = cm.summarize_entries(block.get("entries") or (),
-                                        block.get("calls") or {})
-            counters = {e.get("name"): e.get("value")
-                        for e in s.get("metrics", ())
-                        if e.get("kind") == "counter" and e.get("value")}
-            delta = pg.live_delta(base, summ, counters)
-            drifted = drifted or not delta["ok"]
-            if args.json:
-                print(json.dumps({"rank": s.get("rank"),
-                                  "perf_diff": delta}, indent=1,
-                                 sort_keys=True))
-                continue
-            print(f"perf-diff — rank {s.get('rank')} vs {args.perf_diff} "
-                  f"({delta['overlap_sites']} overlapping sites):")
-            for lane, v in sorted(delta["lanes"].items()):
-                if v["verdict"] == "no-overlap":
-                    continue
-                print(f"  [{v['verdict'].upper():<5}] {lane}")
-                for f in v["failures"][:8]:
-                    rel = f" (rel {f['rel']:+.2%})" if "rel" in f else ""
-                    print(f"      {f['metric']}: baseline={f['base']!r} "
-                          f"live={f['got']!r}{rel}")
-        if drifted:
-            print("perf-diff verdict: DRIFT", file=sys.stderr)
-            return 2
-        print("perf-diff verdict: ok")
 
     if args.trace:
         with open(args.trace, "w") as f:
