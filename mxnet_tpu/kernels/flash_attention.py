@@ -21,24 +21,32 @@ width and dQ, dK take q's.  Segment ids are
 segment ids (padding mask: valid tokens segment 1, pad tokens 0).
 
 Grid design (canonical TPU flash schedule, head-blocked).  The streaming
-kernels (``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``: more than one
-tile a sequence) run a grid of (B, n_h, scheduled tiles): the two sequence
-dimensions are ONE grid dimension over the tiles ``_tile_schedule`` lists,
-made at trace time from the lengths, the blocks and ``causal``.  A tile no
-entry of which passes the causal test is not in the grid at all (no step,
-no fetch); a tile every entry of which passes runs the body without the
-causal mask; only the tiles the diagonal crosses build the iota mask.  The
-(q block, kv block) of each step and its flags ride in three int32 tables
-handed over as scalar prefetch, read by the BlockSpec index maps and by the
-body.  A non-causal call gets the full rectangle from the same builder (a
-table lookup costs it ~0.12 us a step against index maps computed from the
-grid indices: measured, PERF.md PR 30).  Tiles run row by row with the kv
-block innermost (``flash_bwd_dkv``: column by column, q block innermost) —
-TPU grid steps run sequentially per core, so the running (m, l, acc) live
-in VMEM scratch across a row's tiles and the output block writes once on
-the row's last tile.  The single-tile kernels (``flash_fwd_single``,
-``flash_bwd_fused``: whole sequence in one block) keep the plain (B, n_h)
-grid.
+kernels (``flash_fwd`` and ``flash_bwd``: more than one tile a sequence)
+run a grid of (B, n_h, scheduled tiles): the two sequence dimensions are
+ONE grid dimension over the tiles ``_tile_schedule`` lists, made at trace
+time from the lengths, the blocks and ``causal``.  A tile no entry of which
+passes the causal test is not in the grid at all (no step, no fetch); a
+tile every entry of which passes runs the body without the causal mask;
+only the tiles the diagonal crosses build the iota mask.  The (q block, kv
+block) of each step and its flags ride in three int32 tables handed over as
+scalar prefetch, read by the BlockSpec index maps and by the body.  A
+non-causal call gets the full rectangle from the same builder (a table
+lookup costs it ~0.12 us a step against index maps computed from the grid
+indices: measured, PERF.md PR 30).  TPU grid steps run sequentially per
+core, so accumulators live in VMEM scratch across steps.  The forward runs
+row by row with the kv block innermost: the running (m, l, acc) of a row,
+its output block written once on the row's last tile.  The backward is ONE
+kernel that runs column by column with the q block innermost and computes
+s, p, dp and ds once a tile for all three gradients: dk and dv accumulate
+down a kv block's column and write on its last tile, while dq accumulates
+in a scratch that holds the WHOLE query length of the head block,
+(hb, Lq, D) float32, from the first tile of a (b, h) to its last, where it
+is scaled, cast and written to an output block whose index depends on
+(b, h) alone.  That scratch and its output block grow with Lq, so the call
+reckons its VMEM from the shapes (``_bwd_vmem_bytes``) and asks the
+compiler for it; a length that cannot fit raises.  The single-tile kernels
+(``flash_fwd_single``, ``flash_bwd_fused``: whole sequence in one block)
+keep the plain (B, n_h) grid.
 Each step processes a BLOCK OF HEADS (block_h) at once via batched
 dot_generals: with head_dim 64 a single-head (bq, 64) x (64, bk) matmul
 underfills the MXU and the per-step fixed cost (grid loop + DMA
@@ -229,8 +237,8 @@ def _mask_block(sq_ref, skv_ref, causal, iq, ik, bq, bk):
 
 
 def _mask_block_T(sqT_ref, skvT_ref, causal, iq, ik, bq, bk):
-    """(bk, bq) mask (or None) — the TRANSPOSED tile for the dk/dv
-    kernel, built directly from transposed segment layouts (sqT
+    """(bk, bq) mask (or None) — the TRANSPOSED tile of the streaming
+    backward, built directly from transposed segment layouts (sqT
     (1, 1, SUBLANES, bq) q ids over lanes, skvT (1, bk, LANES) kv ids over
     sublanes) because Mosaic cannot legalize a bool vector transpose
     (`tpu.transpose` on i1)."""
@@ -333,8 +341,8 @@ def _fwd_kernel(tq_ref, tk_ref, tf_ref, q_ref, k_ref, v_ref, *rest,
         # specialization (seg=None).  The causal one is a SCALAR of the
         # prefetched schedule, known before the step (_run_tile's two
         # bodies), and costs the pipelining nothing — but buys little: at
-        # (2, 32, 4096, 192/128) the three kernels take 18.73 ms with it
-        # and 18.81 with one masked body on the same tiles (v5e, PR 30);
+        # (2, 32, 4096, 192/128) the three kernels of PR 30 take 18.73 ms
+        # with it and 18.81 with one masked body on the same tiles (v5e);
         # the mask's iota, compare and select ride passes that the loads
         # and stores of the 1 MB score tile bound anyway
         s = _apply_mask(s, _mask_block(sq_ref, skv_ref, causal, iq, ik,
@@ -486,66 +494,35 @@ def _fwd(q, k, v, seg_q, seg_kv, causal, scale, block_q, block_k, block_h,
 # backward
 # --------------------------------------------------------------------------
 
-def _dq_kernel(tq_ref, tk_ref, tf_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-               delta_ref, *rest, causal, scale, has_seg):
+def _bwd_kernel(tq_ref, tk_ref, tf_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                delta_ref, *rest, causal, scale, has_seg):
+    """Streaming backward: dq, dk and dv from ONE pass over the scheduled
+    tiles — s, p, dp and ds once a tile, every block fetched once.  The
+    schedule runs column by column: dk, dv accumulate over a kv block's
+    column (first and last are that column's) while dq accumulates for the
+    whole query length of the head block, in a scratch that stays in VMEM
+    from the first tile of a (b, h) to its last."""
     if has_seg:
-        sq_ref, skv_ref, dq_ref, dq_scr = rest
+        sqT_ref, skvT_ref, *rest = rest
     else:
-        dq_ref, dq_scr = rest
-        sq_ref = skv_ref = None
+        sqT_ref = skvT_ref = None
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
     iq, ik, first, last, kind = _step_tile(tq_ref, tk_ref, tf_ref)
+    t = pl.program_id(2)
 
-    @pl.when(first)
-    def _init():
+    @pl.when(t == 0)
+    def _init_rows():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _tile(causal):
-        # scale folded into the q load (s must match the fwd logits) and
-        # into the dq finish below — never a (Hb, bq, bk) tile pass
-        q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)        # (Hb, bq, d)
-        k = k_ref[0]                                          # (Hb, bk, d)
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)                    # (Hb, bq, d)
-        lse = lse_ref[0][:, :, :1]                            # (Hb, bq, 1)
-        delta = delta_ref[0][:, :, :1]                        # (Hb, bq, 1)
-        bq, bk = q.shape[1], k.shape[1]
-
-        s = _bmm(q, k, 2, 2)                                  # (Hb, bq, bk)
-        s = _apply_mask(s, _mask_block(sq_ref, skv_ref, causal, iq, ik,
-                                       bq, bk))
-        p = jnp.exp(s - lse)          # masked entries: exp(-1e30 - lse) = 0
-        dp = _bmm(do.astype(v.dtype), v, 2, 2)                # (Hb, bq, bk)
-        ds = p * (dp - delta)         # ds * scale deferred to _finish
-        dq_scr[...] += _bmm(ds.astype(k.dtype), k, 2, 1)      # (Hb, bq, d)
-
-    _run_tile(_tile, kind, causal)
-
-    @pl.when(last)
-    def _finish():
-        dq_ref[0] = (dq_scr[...]
-                     * jnp.float32(scale)).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(tq_ref, tk_ref, tf_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                delta_ref, *rest, causal, scale, has_seg):
-    if has_seg:
-        sqT_ref, skvT_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
-    else:
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
-        sqT_ref = skvT_ref = None
-    # the schedule runs column by column here: first and last are those of
-    # the kv block's column, whose q blocks accumulate one after the other
-    iq, ik, first, last, kind = _step_tile(tq_ref, tk_ref, tf_ref)
-
     @pl.when(first)
-    def _init():
+    def _init_column():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     def _tile(causal):
         q = q_ref[0]                                          # (Hb, bq, d)
         qs = q * jnp.asarray(scale, q_ref.dtype)   # scaled copy: sT only —
-        # dk below must use RAW q (its scale is applied once in _finish)
+        # dk below must use RAW q (scaled once, in _finish_column)
         k = k_ref[0]                                          # (Hb, bk, d)
         v = v_ref[0]
         do = do_ref[0]                                        # (Hb, bq, d)
@@ -559,25 +536,33 @@ def _dkv_kernel(tq_ref, tk_ref, tf_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         pT = jnp.exp(sT - lse)        # masked entries -> exact 0.0
         dv_scr[...] += _bmm(pT.astype(do.dtype), do, 2, 1)    # (Hb, bk, d)
         dpT = _bmm(v, do, 2, 2)                               # (Hb, bk, bq)
-        dsT = pT * (dpT - delta)      # dsT * scale deferred to _finish
-        dk_scr[...] += _bmm(dsT.astype(q.dtype), q, 2, 1)     # (Hb, bk, d)
+        dsT = (pT * (dpT - delta)).astype(q.dtype)  # scale: in the finishes
+        dk_scr[...] += _bmm(dsT, q, 2, 1)                     # (Hb, bk, d)
+        # contract over bk (dim 1 of both operands): ds·k without a
+        # transpose op, onto the rows of this step's q block
+        rows = pl.ds(pl.multiple_of(iq * bq, bq), bq)
+        dq_scr[:, rows, :] += _bmm(dsT, k, 1, 1)              # (Hb, bq, d)
 
     _run_tile(_tile, kind, causal)
 
     @pl.when(last)
-    def _finish():
+    def _finish_column():
         dk_ref[0] = (dk_scr[...]
                      * jnp.float32(scale)).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _finish_rows():
+        dq_ref[0] = (dq_scr[...]
+                     * jnp.float32(scale)).astype(dq_ref.dtype)
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       *rest, causal, scale, has_seg):
     """Single-tile fused backward (n_q == n_kv == 1, i.e. seq <= block):
     dq, dk, dv from ONE pass — s and p computed once, dk/dv contract over
-    the q dim (no transposes), inputs loaded once instead of twice.  The
-    split dq/dkv kernels remain for multi-tile (long-seq) grids where
-    dk/dv accumulation runs across q blocks."""
+    the q dim (no transposes), inputs loaded once.  Above one tile
+    ``_bwd_kernel`` does the same over the scheduled tiles."""
     if has_seg:
         sq_ref, skv_ref, dq_ref, dk_ref, dv_ref = rest
     else:
@@ -647,6 +632,30 @@ def _bwd_fused(q, k, v, seg_q, seg_kv, lse_b, delta_b, do, causal, scale,
     )(*inputs)
 
 
+_VMEM_BYTES = 128 * 2 ** 20          # a TensorCore's VMEM (v4 to v6e)
+_VMEM_SCOPED_DEFAULT = 16 * 2 ** 20  # what the compiler gives a kernel unasked
+
+
+def _bwd_vmem_bytes(hb, bq, bk, Lq, D, Dv, itemsize):
+    """VMEM the streaming backward plans for, from its shapes: every input
+    and output block twice (the pipeline's double buffer), the three f32
+    accumulators — dq's spans the whole query length — and the tile's
+    intermediates.  The last dim pads to the lane width."""
+    def rows(n, width, size):
+        return hb * n * -(-width // _LANES) * _LANES * size
+
+    inputs = (rows(bq, D, itemsize) + rows(bk, D, itemsize)
+              + rows(bk, Dv, itemsize) + rows(bq, Dv, itemsize)
+              + 2 * rows(bq, _STAT, 4))
+    outputs = (rows(Lq, D, itemsize) + rows(bk, D, itemsize)
+               + rows(bk, Dv, itemsize))
+    scratch = rows(Lq, D, 4) + rows(bk, D, 4) + rows(bk, Dv, 4)
+    # sT, pT, dpT, dsT in f32, the bf16 copies the products take and dsT
+    # turned for the dq product
+    tile = 6 * rows(bk, bq, 4)
+    return 2 * (inputs + outputs) + scratch + tile
+
+
 def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
          block_q, block_k, block_h, interpret):
     B, H, Lq, D = q.shape
@@ -672,63 +681,55 @@ def _bwd(q, k, v, seg_q, seg_kv, out, lse, do, causal, scale,
         return _bwd_fused(q, k, v, seg_q, seg_kv, lse_b, delta_b, do,
                           causal, scale, hb, interpret)
 
-    # one list of specs serves both kernels: each reads its step's blocks
-    # from its own schedule (dq row by row, dkv column by column)
+    vmem = _bwd_vmem_bytes(hb, bq, bk, Lq, D, Dv, q.dtype.itemsize)
+    if vmem > _VMEM_BYTES:
+        raise ValueError(
+            f"flash_attention backward: the gradient of {Lq} query rows "
+            f"stays in VMEM across a head's tiles, and with the blocks that "
+            f"is {vmem / 2**20:.0f} MiB of the chip's "
+            f"{_VMEM_BYTES / 2**20:.0f} MiB; split the sequence over chips")
+
     q_rows = functools.partial(_rows_spec, hb, bq, table=_Q)
     kv_rows = functools.partial(_rows_spec, hb, bk, table=_KV)
     in_specs = [q_rows(D), kv_rows(D), kv_rows(Dv), q_rows(Dv),
                 q_rows(_STAT), q_rows(_STAT)]
     inputs = [q, k, v, do, lse_b, delta_b]
-    dq_seg_specs, dq_segs, dkv_seg_specs, dkv_segs = [], [], [], []
     if has_seg:
-        # two layouts of each segment-id vector: per-sublane-row for the
-        # dq kernel's (bq, bk) mask, per-lane for the dkv (bk, bq) mask
-        q_row, q_lane = _seg_specs(bq, _Q)
-        kv_row, kv_lane = _seg_specs(bk, _KV)
-        dq_seg_specs = [q_row, kv_lane]
-        dq_segs = [_seg_row_layout(seg_q, Lq),
-                   _seg_lane_layout(seg_kv, Lk, bk)]
-        dkv_seg_specs = [q_lane, kv_row]
-        dkv_segs = [_seg_lane_layout(seg_q, Lq, bq),
-                    _seg_row_layout(seg_kv, Lk)]
+        # the transposed (bk, bq) mask: q ids over lanes, kv ids over rows
+        in_specs += [_seg_specs(bq, _Q)[1], _seg_specs(bk, _KV)[0]]
+        inputs += [_seg_lane_layout(seg_q, Lq, bq),
+                   _seg_row_layout(seg_kv, Lk)]
 
-    dq_tables = _scheduled("flash_bwd_dq", B, n_h, Lq, Lk, bq, bk, causal)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale,
+    tables = _scheduled("flash_bwd", B, n_h, Lq, Lk, bq, bk, causal,
+                        by_column=True)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, causal=causal, scale=scale,
                           has_seg=has_seg),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(dq_tables),
-            grid=(B, n_h, dq_tables[0].shape[0]),
-            in_specs=in_specs + dq_seg_specs,
-            out_specs=q_rows(D),
-            scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(*dq_tables, *inputs, *dq_segs)
-
-    dkv_tables = _scheduled("flash_bwd_dkv", B, n_h, Lq, Lk, bq, bk, causal,
-                            by_column=True)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                          has_seg=has_seg),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(dkv_tables),
-            grid=(B, n_h, dkv_tables[0].shape[0]),
-            in_specs=in_specs + dkv_seg_specs,
-            out_specs=[kv_rows(D), kv_rows(Dv)],
+            num_scalar_prefetch=len(tables),
+            grid=(B, n_h, tables[0].shape[0]),
+            in_specs=in_specs,
+            out_specs=[
+                # all of the head block's query rows: written once, on the
+                # last tile of the (b, h)
+                pl.BlockSpec((1, hb, Lq, D), lambda b, h, t, *tables:
+                             (b, h, _zi(), _zi())),
+                kv_rows(D), kv_rows(Dv)],
             scratch_shapes=[
+                pltpu.VMEM((hb, Lq, D), jnp.float32),
                 pltpu.VMEM((hb, bk, D), jnp.float32),
                 pltpu.VMEM((hb, bk, Dv), jnp.float32),
             ]),
         out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(vmem, _VMEM_SCOPED_DEFAULT)),
         interpret=interpret,
-        name="flash_bwd_dkv",
-    )(*dkv_tables, *inputs, *dkv_segs)
-    return dq, dk, dv
+        name="flash_bwd",
+    )(*tables, *inputs)
 
 
 # --------------------------------------------------------------------------

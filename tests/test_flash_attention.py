@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from mxnet_tpu.kernels.flash_attention import (
     _FIRST, _KIND_SHIFT, _LAST, _MASKED, _SKIPPED, _UNMASKED,
-    _pick_block_h, _tile_schedule, flash_attention)
+    _bwd_vmem_bytes, _pick_block_h, _tile_schedule, flash_attention)
 from mxnet_tpu.ops.contrib import _dense_sdpa
 
 
@@ -89,7 +89,7 @@ def test_flash_no_segment_ids():
         ref = _dense_sdpa(q, k, v, ones, causal, scale)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
-        # backward: no-seg dq/dkv kernels against the dense autodiff
+        # backward: the no-seg kernel against the dense autodiff
         w = jnp.asarray(np.random.RandomState(4).randn(*q.shape),
                         jnp.float32)
 
@@ -216,9 +216,11 @@ def test_masked_att_qkv_gqa_flash_shape():
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_grad_parity_multi_tile(causal):
-    """Explicit small blocks force the SPLIT dq/dkv kernels (multi-tile
-    grids) — the default-path tests at L<=512 take the single-tile fused
-    backward, so this pins the long-seq accumulation path."""
+    """Explicit small blocks force the STREAMING backward (``flash_bwd``:
+    one kernel over a multi-tile grid, dk and dv accumulated down a column,
+    dq across a head's columns) — the default-path tests at L<=512 take
+    the single-tile fused backward, so this pins the long-seq accumulation
+    path."""
     q, k, v, seg = _inputs(jnp.float32)
     scale = 1.0 / q.shape[-1] ** 0.5
     w = jnp.asarray(_valid_mask(seg), jnp.float32)
@@ -348,30 +350,50 @@ def _dense_oracle(q, k, v, seg_q, seg_kv, causal, scale):
 
 
 TILE_GRIDS = [
-    # Lq, Lk, block_q, block_k, causal, segment ids
-    pytest.param(512, 512, 128, 128, True, False, id="4x4_causal"),
-    pytest.param(512, 512, 128, 128, True, True, id="4x4_causal_segids"),
-    pytest.param(512, 512, 128, 128, False, True, id="4x4_full_segids"),
-    pytest.param(512, 512, 256, 128, True, False, id="2x4_causal_bq_wider"),
-    pytest.param(512, 512, 128, 256, True, True,
+    # Lq, Lk, block_q, block_k, causal, segment ids, block_h (0: picked)
+    pytest.param(512, 512, 128, 128, True, False, 0, id="4x4_causal"),
+    pytest.param(512, 512, 128, 128, True, True, 0, id="4x4_causal_segids"),
+    pytest.param(512, 512, 128, 128, False, True, 0, id="4x4_full_segids"),
+    pytest.param(512, 512, 256, 128, True, False, 0,
+                 id="2x4_causal_bq_wider"),
+    pytest.param(512, 512, 128, 256, True, True, 0,
                  id="4x2_causal_bk_wider_segids"),
-    pytest.param(256, 512, 128, 128, False, False, id="2x4_cross_full"),
-    pytest.param(256, 512, 64, 128, False, True,
+    pytest.param(256, 512, 128, 128, False, False, 0, id="2x4_cross_full"),
+    pytest.param(256, 512, 64, 128, False, True, 0,
                  id="4x4_cross_full_segids"),
-    pytest.param(256, 512, 128, 128, True, False,
+    pytest.param(256, 512, 128, 128, True, False, 0,
                  id="2x4_causal_unreached_keys"),
+    # what a dq kept in VMEM across a head's columns can get wrong
+    pytest.param(512, 256, 128, 128, True, False, 0,
+                 id="4x2_causal_more_queries_than_keys"),
+    pytest.param(384, 128, 128, 128, True, False, 0,
+                 id="3x1_causal_one_column"),
+    pytest.param(512, 512, 128, 128, True, False, 4,
+                 id="4x4_causal_four_heads_a_step"),
+    pytest.param(256, 512, 128, 128, True, True, 2,
+                 id="2x4_causal_unreached_keys_two_heads_a_step_segids"),
+    pytest.param(512, 384, 256, 128, False, False, 2,
+                 id="2x3_full_two_heads_a_step"),
 ]
 
 
-@pytest.mark.parametrize("Lq,Lk,bq,bk,causal,seg", TILE_GRIDS)
-def test_flash_streaming_parity_over_tile_grids(Lq, Lk, bq, bk, causal, seg):
+@pytest.mark.parametrize("Lq,Lk,bq,bk,causal,seg,hb", TILE_GRIDS)
+def test_flash_streaming_parity_over_tile_grids(Lq, Lk, bq, bk, causal, seg,
+                                                hb):
     """Forward and the three gradients of the streaming kernels against the
     dense oracle on grids where skipped, unmasked and masked tiles all
     occur in one call (4 x 4 causal), with and without segment ids, with
     unequal blocks, and with Lq != Lk; key columns no query reaches
-    (causal, Lk > Lq) get exact zeros for dk and dv."""
+    (causal, Lk > Lq) get exact zeros for dk and dv.  The backward keeps
+    dq for the whole query length in VMEM from a (b, h)'s first tile to
+    its last: every case runs several (b, h) one after the other with
+    different data, so a scratch left from the one before would show; the
+    last cases add more queries than keys, a single column (every dq row
+    gets one contribution), and more than one head a grid step (``hb``: 0
+    lets the kernel pick, which is every head of these small shapes when
+    the tile is small)."""
     r = np.random.RandomState(13)
-    B, H, D = 2, 2, 32
+    B, H, D = 2, 4 if hb else 2, 32
     q = jnp.asarray(r.randn(B, H, Lq, D), jnp.float32)
     k = jnp.asarray(r.randn(B, H, Lk, D), jnp.float32)
     v = jnp.asarray(r.randn(B, H, Lk, D), jnp.float32)
@@ -387,7 +409,8 @@ def test_flash_streaming_parity_over_tile_grids(Lq, Lk, bq, bk, causal, seg):
 
     def flash(q, k, v):
         return flash_attention(q, k, v, seg_q, seg_kv, causal, scale,
-                               block_q=bq, block_k=bk, interpret=True)
+                               block_q=bq, block_k=bk, block_h=hb,
+                               interpret=True)
 
     def dense(q, k, v):
         return _dense_oracle(q, k, v, seg_q, seg_kv, causal, scale)
@@ -409,7 +432,8 @@ def test_flash_streaming_parity_over_tile_grids(Lq, Lk, bq, bk, causal, seg):
 
 def test_flash_tiles_counter_counts_a_traced_call():
     """``mxnet_flash_tiles_total{kernel, kind}`` grows by the call's tiles
-    over batch and head blocks, where the kernel is built."""
+    over batch and head blocks, where the kernel is built: once for the
+    forward and once for the one backward kernel."""
     from mxnet_tpu.telemetry import metrics
 
     def read(kernel):
@@ -418,7 +442,8 @@ def test_flash_tiles_counter_counts_a_traced_call():
             labels={"kernel": kernel, "kind": kind}), "value", 0)
             for kind in ("skipped", "unmasked", "masked")]
 
-    names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    names = ("flash_fwd", "flash_bwd")
+    gone = ("flash_bwd_dq", "flash_bwd_dkv")
     before = {n: read(n) for n in names}
     q = jnp.zeros((2, 4, 512, 16), jnp.float32)
     jax.grad(lambda q: flash_attention(
@@ -426,6 +451,60 @@ def test_flash_tiles_counter_counts_a_traced_call():
         block_h=2, interpret=True).sum())(q)
     for n in names:   # 2 x (4 / 2) head blocks x (6, 6, 4) tiles
         assert [a - b for a, b in zip(read(n), before[n])] == [24, 24, 16]
+    for n in gone:
+        assert read(n) == [0, 0, 0]
+
+
+# -- the VMEM plan of the streaming backward ----------------------------------
+
+MIB = 2 ** 20
+
+
+@pytest.mark.parametrize("hb,bq,bk,Lq,D,Dv,itemsize,mib", [
+    # kanana_2_30b_a3b.train_s4096: one head a step, 192 pads to 256 lanes;
+    # double-buffered blocks 2.5 in + 4.75 out (dq 2 x 2), accumulators
+    # 4.75 (dq 4), six score tiles 6
+    pytest.param(1, 512, 512, 4096, 192, 128, 2, 18.0, id="mla_cell"),
+    pytest.param(1, 512, 512, 4096, 192, 128, 4, 24.25, id="mla_cell_f32"),
+    # BERT above one tile: 64-wide heads pad to 128 lanes
+    pytest.param(1, 512, 512, 2048, 64, 64, 2, 11.0, id="bert_seq2048"),
+    pytest.param(1, 512, 512, 8192, 64, 64, 2, 17.0, id="bert_seq8192"),
+    pytest.param(4, 256, 256, 4096, 64, 64, 2, 28.0,
+                 id="bert_seq4096_blocks256_four_heads"),
+    pytest.param(1, 512, 512, 32768, 128, 128, 2, 41.0, id="seq32768_d128"),
+])
+def test_bwd_vmem_bytes_at_known_shapes(hb, bq, bk, Lq, D, Dv, itemsize,
+                                        mib):
+    """What the streaming backward asks the compiler for
+    (``vmem_limit_bytes``), from the shapes alone; literals, so that a
+    change to the plan is a change to what a cell compiles.  The resident
+    dq is the term that grows with the query length: 6 bytes a padded lane
+    and row in bf16 (two output buffers and the f32 accumulator)."""
+    got = _bwd_vmem_bytes(hb, bq, bk, Lq, D, Dv, itemsize)
+    assert got == int(mib * MIB), got / MIB
+    longer = _bwd_vmem_bytes(hb, bq, bk, 2 * Lq, D, Dv, itemsize)
+    lanes = -(-D // 128) * 128
+    assert longer - got == hb * Lq * lanes * (2 * itemsize + 4)
+
+
+def test_bwd_raises_where_dq_cannot_stay_in_vmem():
+    """A query length whose gradient does not fit the chip's VMEM raises a
+    ValueError that names the length, at trace time (shapes only: nothing
+    is allocated); the forward, which keeps no such array, traces."""
+    L = 512 * 512
+    q = jax.ShapeDtypeStruct((1, 1, L, 128), jnp.bfloat16)
+
+    def out(q, k, v):
+        return flash_attention(q, k, v, None, None, True, 0.1)
+
+    assert jax.eval_shape(out, q, q, q).shape == (1, 1, L, 128)
+    with pytest.raises(ValueError, match=rf"{L} query rows.*VMEM"):
+        jax.eval_shape(jax.grad(lambda *a: out(*a).sum(), argnums=(0, 1, 2)),
+                       q, q, q)
+    # a quarter of that length is within the plan, and traces
+    q = jax.ShapeDtypeStruct((1, 1, 65536, 128), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda *a: out(*a).astype(jnp.float32).sum(),
+                            argnums=(0, 1, 2)), q, q, q)
 
 
 # -- a value width that differs from the query/key width (latent attention) ---
@@ -443,7 +522,7 @@ def _inputs_qk_v(D=24, Dv=16, B=2, H=4, L=256, seed=11):
 def test_flash_value_width_differs_causal(blocks):
     """24-wide queries and keys, 16-wide values, causal: out, dO and dV
     take v's width, dQ and dK q's, in the single-tile kernels and in the
-    three streaming ones, against the dense oracle."""
+    two streaming ones, against the dense oracle."""
     q, k, v = _inputs_qk_v()
     scale = 1.0 / q.shape[-1] ** 0.5
     out = flash_attention(q, k, v, None, None, True, scale, interpret=True,

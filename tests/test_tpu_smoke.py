@@ -245,13 +245,13 @@ def test_tpu_flash_attention_grad_consistency():
 
 def test_tpu_flash_attention_value_width_streaming_consistency():
     """Latent attention's shapes on the chip: 192-wide queries and keys,
-    128-wide values, causal, seq 1024 in blocks of 512, so the three
-    STREAMING kernels (flash_fwd, flash_bwd_dq, flash_bwd_dkv) run, forward
-    and backward, against the dense path on the cpu ctx.  The benchmark's
-    MLA cell starts with a closed attention branch and so checks the
-    kernels' forward only (perfbench/reference/mla_moe_train.py)."""
+    128-wide values, causal, the benchmark cell's seq 4096 in blocks of
+    512, so the two STREAMING kernels (flash_fwd and the one backward,
+    flash_bwd, with dq for all 4,096 query rows of a head kept in VMEM
+    across its 8 columns) run, forward and backward, against the dense path
+    (``_dense_sdpa``) on the cpu ctx."""
     r = np.random.RandomState(23)
-    B, H, L, D, Dv = 1, 4, 1024, 192, 128
+    B, H, L, D, Dv = 1, 2, 4096, 192, 128
     qn = (r.randn(B, H, L, D) * 0.3).astype(np.float32)
     kn = (r.randn(B, H, L, D) * 0.3).astype(np.float32)
     vn = (r.randn(B, H, L, Dv) * 0.3).astype(np.float32)
