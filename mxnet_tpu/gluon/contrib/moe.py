@@ -20,13 +20,15 @@ dispatch so XLA tiles everything onto the MXU, no dynamic gather/scatter:
  - auxiliary load-balance loss (Switch eq. 4): E · Σ_e f_e · p_e, returned
    alongside the output so callers add ``aux_weight * aux`` to their loss.
 
-``DroplessMoE`` is the second design, the one DeepSeek-V3-shaped models
-train with: a sigmoid router with a choice-only bias over ALL experts of the
-layer, no capacity and no dropped token, bias-free SwiGLU experts run as
-grouped matrix products over the (token, expert) pairs sorted by expert
-(``ops/moe.py``), shared experts every token passes, and a notion of the
-experts THIS device holds: the layer expert parallelism needs, run without
-its exchange.
+``DroplessMoE`` is the second design, the one today's large open MoE
+decoders train with: a top-k router over ALL experts of the layer (sigmoid
+scores with a choice-only bias, DeepSeek-V3's, or softmax scores with none,
+``qwen3_next``'s), no capacity and no dropped token, bias-free SwiGLU experts
+run as grouped matrix products over the (token, expert) pairs sorted by
+expert (``ops/moe.py``), shared experts every token passes (as they are, or
+each token's multiplied by ``sigmoid(x w_g)``: ``shared_gate``), and a notion
+of the experts THIS device holds: the layer expert parallelism needs, run
+without its exchange.
 """
 
 from __future__ import annotations
@@ -183,22 +185,27 @@ class SwiGLU(HybridBlock):
 
 
 class _Router(HybridBlock):
-    """Sigmoid top-k router with a choice-only bias; ``(weights (N, k)
-    float32, experts (N, k) int32)`` of ``contrib.moe_router``.  The bias
-    takes no gradient (``grad_req="null"``): what moves it in the published
-    recipe is a balancing rule outside the loss."""
+    """Top-k router; ``(weights (N, k) float32, experts (N, k) int32)`` of
+    ``contrib.moe_router``.  ``score="sigmoid"`` comes with a choice-only
+    bias, which takes no gradient (``grad_req="null"``): what moves it in
+    the published recipe is a balancing rule outside the loss.
+    ``score="softmax"`` has no bias."""
 
-    def __init__(self, units, num_experts, k, scale, normalize, **kwargs):
+    def __init__(self, units, num_experts, k, scale, normalize,
+                 score="sigmoid", **kwargs):
         super().__init__(**kwargs)
         self._attrs = dict(k=int(k), scale=float(scale),
                            normalize=bool(normalize))
+        if score != "sigmoid":      # the default stays out of the op's
+            self._attrs["score"] = score    # attributes: its cache key
         with self.name_scope():
             self.weight = self.params.get(
                 "weight", shape=(num_experts, units), init=None)
             self.bias = self.params.get(
-                "bias", shape=(num_experts,), init="zeros", grad_req="null")
+                "bias", shape=(num_experts,), init="zeros",
+                grad_req="null") if score == "sigmoid" else None
 
-    def hybrid_forward(self, F, x, weight, bias):
+    def hybrid_forward(self, F, x, weight, bias=None):
         return F.contrib.moe_router(x, weight, bias, **self._attrs)
 
 
@@ -227,7 +234,8 @@ class _Experts(HybridBlock):
 
 
 class DroplessMoE(HybridBlock):
-    """Dropless routed feed-forward with shared experts (DeepSeek-V3's).
+    """Dropless routed feed-forward with shared experts (DeepSeek-V3's,
+    and with ``score="softmax"``, ``shared_gate=True`` ``qwen3_next``'s).
 
     Parameters
     ----------
@@ -242,10 +250,14 @@ class DroplessMoE(HybridBlock):
         ``num_shared_experts * hidden_size`` on every token (0 = none).
     routed_scaling_factor, norm_topk_prob : the router's scale, and whether
         the chosen weights are divided by their sum first.
+    score : ``"sigmoid"`` (scores with a choice-only bias that takes no
+        gradient) or ``"softmax"`` over the layer's experts (no bias).
+    shared_gate : bool — each token's shared-expert output is multiplied
+        by ``sigmoid(x w_g)``, ``w_g`` (units, 1).
 
     ``__call__(x) -> y`` with x (B, L, units) or (N, units); y = routed +
     shared.  No capacity, no dropped token, no auxiliary loss.  Children:
-    ``router``, ``experts``, ``shared``.  Under a ``parallel.TrainStep`` the
+    ``router``, ``experts``, ``shared``, ``shared_gate``.  Under a ``parallel.TrainStep`` the
     layer reports, per step, ``mxnet_moe_pairs_total{layer}`` (pairs that
     fell on held experts), ``mxnet_moe_tokens_total`` (tokens routed, summed
     over layers) and ``mxnet_moe_expert_tokens_max{layer}`` (the fullest
@@ -255,7 +267,8 @@ class DroplessMoE(HybridBlock):
     def __init__(self, units, hidden_size, num_experts,
                  num_experts_per_token, experts_held=None,
                  num_shared_experts=0, routed_scaling_factor=1.0,
-                 norm_topk_prob=True, expert_axis="ep", **kwargs):
+                 norm_topk_prob=True, score="sigmoid", shared_gate=False,
+                 expert_axis="ep", **kwargs):
         super().__init__(**kwargs)
         first, count = experts_held or (0, num_experts)
         if num_experts_per_token > num_experts:
@@ -264,16 +277,23 @@ class DroplessMoE(HybridBlock):
             raise MXNetError(
                 f"experts_held {(first, count)} is not a range of the "
                 f"layer's {num_experts} experts")
+        if score not in ("sigmoid", "softmax"):
+            raise MXNetError(f"score {score!r}: want sigmoid|softmax")
+        if shared_gate and not num_shared_experts:
+            raise MXNetError("shared_gate without a shared expert")
         self._units = units
         with self.name_scope():
             self.router = _Router(units, num_experts, num_experts_per_token,
                                   routed_scaling_factor, norm_topk_prob,
-                                  prefix="router_")
+                                  score=score, prefix="router_")
             self.experts = _Experts(units, hidden_size, first, count,
                                     expert_axis, prefix="experts_")
             self.shared = SwiGLU(units, num_shared_experts * hidden_size,
                                  prefix="shared_") \
                 if num_shared_experts else None
+            self.shared_gate = Dense(
+                1, flatten=False, use_bias=False, in_units=units,
+                prefix="shgate_") if shared_gate else None
 
     def hybrid_forward(self, F, x):
         from ... import parallel, regions
@@ -286,6 +306,8 @@ class DroplessMoE(HybridBlock):
         parallel.report_counter("mxnet_moe_tokens_total", xf.shape[0])
         parallel.report_counter("mxnet_moe_expert_tokens_max", tokens,
                                 labels=layer, kind="max")
-        if self.shared is not None:
+        if self.shared_gate is not None:
+            y = y + F.sigmoid(self.shared_gate(xf)) * self.shared(xf)
+        elif self.shared is not None:
             y = y + self.shared(xf)
         return F.reshape(y, shape=x.shape)

@@ -42,20 +42,27 @@ LLAMA_CONFIGS = {
 
 class RMSNorm(HybridBlock):
     """Root-mean-square norm (no mean subtraction, no bias) — Llama's
-    norm; computed in f32 like the reference implementations."""
+    norm; computed in f32 like the reference implementations.
+    ``zero_centered``: the scale is ``1 + weight`` and the weight starts
+    at 0 (``qwen3_next``'s form)."""
 
-    def __init__(self, units, eps=1e-5, **kwargs):
+    def __init__(self, units, eps=1e-5, zero_centered=False, **kwargs):
         super().__init__(**kwargs)
         self._eps = eps
+        self._zero_centered = bool(zero_centered)
         with self.name_scope():
-            self.weight = self.params.get("weight", shape=(units,),
-                                          init="ones")
+            self.weight = self.params.get(
+                "weight", shape=(units,),
+                init="zeros" if zero_centered else "ones")
 
     def hybrid_forward(self, F, x, weight):
         xf = x.astype("float32")
         var = (xf * xf).mean(axis=-1, keepdims=True)
         out = xf * F.rsqrt(var + self._eps)
-        return (out * weight.astype("float32")).astype(x.dtype)
+        scale = weight.astype("float32")
+        if self._zero_centered:
+            scale = scale + 1.0
+        return (out * scale).astype(x.dtype)
 
 
 def _rope(F, x, base=500000.0, rotate=None, interleaved=False):

@@ -19,6 +19,7 @@ from . import random_ops  # noqa: F401
 from . import linalg  # noqa: F401
 from . import contrib  # noqa: F401
 from . import moe  # noqa: F401
+from . import linear_attention  # noqa: F401
 from . import vision  # noqa: F401
 from . import quantization  # noqa: F401
 from . import sparse_ops  # noqa: F401

@@ -1,5 +1,7 @@
-"""Dropless mixture-of-experts ops: a sigmoid top-k router over all experts
-of a layer, and the routed feed-forward of the experts THIS device holds.
+"""Dropless mixture-of-experts ops: a top-k router over all experts of a
+layer (sigmoid scores with a choice-only bias, DeepSeek-V3's, or softmax
+scores with none, ``qwen3_next``'s), and the routed feed-forward of the
+experts THIS device holds.
 
 The layer expert parallelism needs, run without its exchange: the router
 scores every token against all E experts of the layer; the device holds the
@@ -36,22 +38,31 @@ from .registry import register
 
 
 @register("contrib.moe_router", num_outputs=2)
-def _moe_router(x, weight, bias, k=1, scale=1.0, normalize=True):
-    """Sigmoid top-k routing with a choice-only bias (DeepSeek-V3's
-    ``noaux_tc`` with one group).  ``x`` (N, U); ``weight`` (E, U), the
-    layout of a Dense weight; ``bias`` (E,), added to the scores for the
-    CHOICE alone.  Returns ``(weights (N, k) float32, experts (N, k)
-    int32)``: the sigmoid scores (without the bias) at the chosen experts,
-    divided by their sum + 1e-20 if ``normalize``, times ``scale``.  The
-    product runs in float32 at the highest precision whatever the
-    activations' type; of equal scores the lower expert wins."""
+def _moe_router(x, weight, bias=None, k=1, scale=1.0, normalize=True,
+                score="sigmoid"):
+    """Top-k routing over all E experts of a layer.  ``x`` (N, U);
+    ``weight`` (E, U), the layout of a Dense weight.  ``score``:
+    ``"sigmoid"`` (DeepSeek-V3's ``noaux_tc`` with one group) or
+    ``"softmax"`` over the E logits (``qwen3_next``'s).  ``bias`` (E,) or
+    None: added to the scores for the CHOICE alone.  Returns ``(weights
+    (N, k) float32, experts (N, k) int32)``: the scores (without the bias)
+    at the chosen experts, divided by their sum + 1e-20 if ``normalize``,
+    times ``scale``.  The product runs in float32 at the highest precision
+    whatever the activations' type; of equal scores the lower expert
+    wins."""
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
     logits = jnp.einsum("nu,eu->ne", x.astype(f32), weight.astype(f32),
                         precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, experts = jax.lax.top_k(scores + bias.astype(f32), k)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"moe_router score {score!r}: want sigmoid|softmax")
+    _, experts = jax.lax.top_k(
+        scores if bias is None else scores + bias.astype(f32), k)
     chosen = jnp.take_along_axis(scores, experts, axis=1)
     if normalize:
         chosen = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)

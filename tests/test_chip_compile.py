@@ -152,6 +152,63 @@ def test_flash_kernels_compile_with_192_wide_keys_and_128_wide_values(
         assert gone not in text, gone
 
 
+def test_flash_kernels_compile_at_256_wide_heads_and_seq_8192(one_chip):
+    """The gated full-attention layer's shapes at the benchmark's size: 16
+    query heads of 256 (K and V already repeated to them), causal, seq
+    8192: the two streaming kernels over 136 of a head's 16 x 16 tiles.
+    The backward keeps dq for all 8,192 query rows of a head in VMEM (8 MiB
+    float32) and asks the compiler for what ``_bwd_vmem_bytes`` plans, 27
+    MiB at one head a step: under the chip's 128 MiB and over the 16 MiB
+    default, so a compile that passes is the proof of the plan."""
+    import importlib
+    fa = importlib.import_module("mxnet_tpu.kernels.flash_attention")
+    bq = fa._pick_block(8192, 512)
+    hb = fa._pick_block_h(16, bq, bq)
+    assert (bq, hb) == (512, 1)
+    planned = fa._bwd_vmem_bytes(hb, bq, bq, 8192, 256, 256, 2)
+    assert 16 * 2 ** 20 < planned == 27 * 2 ** 20 < 128 * 2 ** 20
+    x = jax.ShapeDtypeStruct((1, 16, 8192, 256), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, None, None, True, 256 ** -0.5)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+        .lower(x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for kernel in ("flash_fwd", "flash_bwd"):
+        assert f"({kernel})" in text, kernel
+    # the backward call carries the plan as its scoped VMEM
+    bwd = next(line for line in text.splitlines()
+               if "(flash_bwd)" in line and "custom-call(" in line)
+    assert f'"size":"{planned}"' in bwd
+
+
+def test_gated_delta_rule_compiles_at_the_cells_shape_for_v5e(one_chip):
+    """The chunked scan at the benchmark's size (one row of 8,192
+    positions, 32 value heads of 128 / 128, bfloat16 in), forward and
+    backward: plain XLA, no Pallas call; its temporaries, with the solves
+    run a group of heads at a time, stay under 2 GB."""
+    from mxnet_tpu.ops.linear_attention import _gated_delta_rule
+    wide = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+                                sharding=one_chip)
+    head = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32,
+                                sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        out = _gated_delta_rule(q, k, v, g, beta, chunk=64)
+        return out.astype(jnp.float32).sum()
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
+            .lower(wide, wide, wide, head, head).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "gdn_scan" in text and " while(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
 def test_routed_experts_compile_to_grouped_kernels_for_v5e(one_chip):
     """``contrib.moe_experts`` at the benchmark's widths, under
     jax_enable_x64 as the package runs: the compiler turns each
@@ -218,6 +275,49 @@ def test_bert_width_trainstep_compiles_with_the_kernel(topo, smoke,
     assert compiled.as_text().count("tpu_custom_call") == 4
     assert mx.telemetry.costmodel.peak_bytes(
         compiled.memory_analysis()) < V5E_HBM_BYTES
+
+
+def test_hybrid_cells_step_compiles_under_the_chips_memory(topo):
+    """The step of ``qwen3_next_80b_a3b.train_s8192`` as the benchmark's
+    builder makes it, at the published widths, the cell's depth and one row
+    of 8,192 positions, for a described v5e: both streaming flash kernels,
+    the grouped products and the scan in one program that fits.  Built on
+    the CPU with 8 of the cell's 32 held experts (the suite's workers share
+    this machine's memory; 323M parameters with Adam's state are 4.5 GB of
+    host memory, the cell's 626M would be 8.8).  What fits here is the
+    state it holds beside the whole row's temporaries; the 24 absent
+    experts a layer are state alone, 4.8 GB more at 14 B a parameter with
+    2 B of gradient, and the compiler then plans the temporaries it has
+    room for (7.7 GB with 16 held, 6.4 with all 32: 15.2 GB in all, read
+    with the verify skill's recipe and as ``program_hbm_gb`` on the
+    chip)."""
+    from perfbench import run as harness
+    from perfbench import weights
+    from perfbench.builders import qwen3_next_zoo
+    from perfbench.reference import gdn_moe_train
+    _bench, cell = harness.load_cell("qwen3_next_80b_a3b.train_s8192")
+    cfg, traffic = cell["config"], cell["traffic"]
+    held = 8
+    cfg = dict(cfg, experts_held=[0, held], num_experts=held)
+    w0 = weights.make_weights(gdn_moe_train.param_shapes(cfg), 1,
+                              cfg["run"]["dtype"], jax.devices()[0])
+    program = qwen3_next_zoo.Program(cfg, traffic, w0, jax.devices())
+    del w0
+    mesh = parallel.make_mesh(shape=(1,), axis_names=("dp",),
+                              devices=list(topo.devices[:1]))
+    held_step = program.step
+    step = parallel.TrainStep(program.model, held_step.loss_fn,
+                              held_step.optimizer, mesh=mesh)
+    batch = jax.ShapeDtypeStruct(
+        (traffic["scan_steps"], traffic["batch"], traffic["seq"]), np.int32)
+    with jax.default_matmul_precision("default"):
+        compiled = step.lowered(batch, batch).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd", "ragged-dot", "gdn_scan"):
+        assert name in text, name
+    planned = mx.telemetry.costmodel.peak_bytes(compiled.memory_analysis())
+    assert 0.25 * V5E_HBM_BYTES < planned < V5E_HBM_BYTES
+    program.close()
 
 
 def test_trainstep_compiles_for_a_four_chip_mesh(topo, smoke, bert_2layer):

@@ -338,3 +338,126 @@ def test_moe_experts_equal_masked_dense_products(bias_on_held):
     for a, b in zip(g_got, g_want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
                                    atol=1e-5)
+
+
+# -- the softmax score and the gated shared expert (qwen3_next's layer) -------
+
+def _route_softmax(x, w, **kw):
+    weights, experts = mx.nd.contrib.moe_router(
+        mx.nd.array(x), mx.nd.array(w), None, score="softmax", **kw)
+    return weights.asnumpy(), experts.asnumpy()
+
+
+@pytest.mark.parametrize("k, normalize", [(1, True), (3, True), (3, False),
+                                          (10, True)])
+def test_router_softmax_against_a_plain_top_k(k, normalize):
+    """softmax over ALL experts in float32, the k largest, their weights
+    over their sum: against jax.numpy written out, no bias anywhere."""
+    import jax
+    import jax.numpy as jnp
+    r = np.random.RandomState(k)
+    x, w = r.randn(40, 16).astype("float32"), r.randn(24, 16).astype(
+        "float32")
+    weights, experts = _route_softmax(x, w, k=k, normalize=normalize)
+    assert experts.dtype == np.int32 and weights.dtype == np.float32
+    p = jax.nn.softmax(jnp.einsum(
+        "nu,eu->ne", x, w, precision=jax.lax.Precision.HIGHEST), axis=-1)
+    picked, chosen = jax.lax.top_k(p, k)
+    assert np.array_equal(experts, np.asarray(chosen))
+    want = picked / picked.sum(-1, keepdims=True) if normalize else picked
+    np.testing.assert_allclose(weights, want, rtol=1e-5)
+    if normalize:
+        np.testing.assert_allclose(weights.sum(1), 1.0, rtol=1e-5)
+    # the probabilities come out in descending order, none negative
+    assert (np.diff(weights, axis=1) <= 1e-7).all() and (weights > 0).all()
+
+
+def test_router_softmax_tie_goes_to_the_lower_expert():
+    x = np.ones((3, 4), "float32")
+    w = np.zeros((6, 4), "float32")         # every probability 1/6
+    weights, experts = _route_softmax(x, w, k=3)
+    assert (experts == np.array([0, 1, 2])).all()
+    np.testing.assert_allclose(weights, 1.0 / 3, rtol=1e-6)
+    w[4] = 0.5                              # one expert ahead, the rest tied
+    _, experts = _route_softmax(x, w, k=3)
+    assert (experts == np.array([4, 0, 1])).all()
+
+
+def test_router_refuses_a_score_it_does_not_know():
+    with pytest.raises(Exception, match="sigmoid\\|softmax"):
+        mx.nd.contrib.moe_router(mx.nd.ones((2, 4)), mx.nd.ones((3, 4)),
+                                 None, score="tanh")
+
+
+def _gated(held, E=8, k=3, units=8, hidden=16, seed=0):
+    moe = DroplessMoE(units, hidden, E, k, experts_held=held,
+                      num_shared_experts=1, score="softmax",
+                      shared_gate=True)
+    mx.random.seed(seed)
+    moe.initialize(mx.init.Normal(0.5))
+    return moe
+
+
+def _gated_oracle(moe, x, held, k=3):
+    """Token by token, expert by expert, in numpy."""
+    p = {n[len(moe.prefix):]: v.data().asnumpy()
+         for n, v in moe.collect_params().items()}
+    silu = lambda a: a / (1.0 + np.exp(-a))             # noqa: E731
+    logits = x @ p["router_weight"].T
+    s = np.exp(logits - logits.max(1, keepdims=True))
+    s /= s.sum(1, keepdims=True)
+    top = np.argsort(-s, axis=1, kind="stable")[:, :k]
+    out = np.zeros_like(x)
+    for n in range(x.shape[0]):
+        w = s[n, top[n]] / s[n, top[n]].sum()
+        for j, e in enumerate(top[n]):
+            if held[0] <= e < held[0] + held[1]:
+                l = e - held[0]
+                h = silu(x[n] @ p["experts_gate"][l]) \
+                    * (x[n] @ p["experts_up"][l])
+                out[n] += w[j] * (h @ p["experts_down"][l])
+    sh = silu(x @ p["shared_gate_weight"].T) * (x @ p["shared_up_weight"].T)
+    gate = 1.0 / (1.0 + np.exp(-(x @ p["shgate_weight"].T)))   # (N, 1)
+    return out + gate * (sh @ p["shared_down_weight"].T)
+
+
+@pytest.mark.parametrize("held", [(0, 8), (2, 3), (7, 1)])
+def test_softmax_moe_with_a_gated_shared_expert_matches_the_oracle(held):
+    moe = _gated(held)
+    assert "router_bias" not in {n[len(moe.prefix):]
+                                 for n in moe.collect_params().keys()}
+    assert moe.shared_gate.weight.shape == (1, 8)
+    x = np.random.RandomState(1).randn(2, 12, 8).astype("float32")
+    y = moe(mx.nd.array(x)).asnumpy()
+    ref = _gated_oracle(moe, x.reshape(-1, 8), held).reshape(x.shape)
+    np.testing.assert_allclose(y, ref, rtol=1e-4, atol=1e-5)
+
+
+def test_softmax_moe_every_leaf_takes_a_gradient():
+    moe = _gated((0, 8))
+    x = mx.nd.array(np.random.RandomState(3).randn(16, 8).astype("float32"))
+    x.attach_grad()
+    with autograd.record():
+        loss = (moe(x) ** 2).sum()
+    loss.backward()
+    for name, p in moe.collect_params().items():
+        g = p.grad().asnumpy()
+        assert np.isfinite(g).all() and np.abs(g).sum() > 0, name
+    # against finite differences of the float64 oracle, on the input
+    eps, xv = 1e-6, x.asnumpy().astype(np.float64)
+    d = np.zeros_like(xv)
+    d[5, 2] = eps
+    up = (_gated_oracle(moe, xv + d, (0, 8)) ** 2).sum()
+    dn = (_gated_oracle(moe, xv - d, (0, 8)) ** 2).sum()
+    assert x.grad.asnumpy()[5, 2] == pytest.approx((up - dn) / (2 * eps),
+                                                   rel=1e-3)
+
+
+@pytest.mark.parametrize("kwargs, match", [
+    (dict(score="tanh"), "score"),
+    (dict(shared_gate=True), "shared_gate without a shared expert"),
+])
+def test_dropless_moe_refuses_arguments_that_do_not_fit(kwargs, match):
+    from mxnet_tpu.base import MXNetError
+    with pytest.raises(MXNetError, match=match):
+        DroplessMoE(8, 16, 8, 3, **kwargs)

@@ -65,6 +65,12 @@ KERNELS = _kernels()
 _OVERRIDE_KEYS = None  # memoized table keys: non-override calls are free
 
 
+def _unit_rows(x):
+    """float32 ``x`` with its last dim scaled to norm 1."""
+    x = x.astype(np.float32)
+    return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+
 def _sweep_override(name):
     global _OVERRIDE_KEYS
     if name is not None and _OVERRIDE_KEYS is not None \
@@ -378,6 +384,19 @@ def _sweep_override(name):
              nd.array(r.randn(3, 4, 5).astype(np.float32)),
              nd.array(r.randn(3, 5, 4).astype(np.float32))],
             {"first": 2}),
+        # linear attention: q, k (B, L, H, Dk) normed, v (B, L, H, Dv), the
+        # log-decay g <= 0 and the write strength beta (B, L, H); a row of
+        # 12 positions in chunks of 8, so the state is carried and padded
+        "contrib.gated_delta_rule": lambda: (
+            [nd.array(_unit_rows(r.randn(2, 12, 3, 4)) / 2.0),
+             nd.array(_unit_rows(r.randn(2, 12, 3, 4))),
+             nd.array(r.randn(2, 12, 3, 5).astype(np.float32)),
+             nd.array(-r.rand(2, 12, 3).astype(np.float32)),
+             nd.array(r.rand(2, 12, 3).astype(np.float32))],
+            {"chunk": 8}),
+        "contrib.causal_conv1d": lambda: (
+            [nd.array(r.randn(2, 9, 6).astype(np.float32)),
+             nd.array(r.randn(6, 4).astype(np.float32))], {}),
         # encdec: q (Lq, B, H*D), kv (Lk, B, 2*H*D) interleaved k/v
         "contrib.masked_encdec_att": lambda: (
             [nd.array(r.randn(4, 2, 8).astype(np.float32)),
@@ -647,6 +666,14 @@ FD_SKIP = {
     "contrib.masked_encdec_att": "float32 softmax core (same class as "
                                  "masked_selfatt); transformer grads in "
                                  "test_model_zoo",
+    "contrib.gated_delta_rule": "float32 state and sums whatever the "
+                                "inputs' type (the float64 FD's precision "
+                                "is lost); out and all five grads against "
+                                "the token-by-token recurrence in "
+                                "test_linear_attention",
+    "contrib.causal_conv1d": "sums in float32 whatever the inputs' type; "
+                             "grads against a windowed einsum in "
+                             "test_linear_attention",
     "contrib.moe_router": "float32 router core with an integer output "
                           "(the chosen experts) and a top-k choice that "
                           "a finite difference can flip; weights and "
