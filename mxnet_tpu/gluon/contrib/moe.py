@@ -259,9 +259,12 @@ class DroplessMoE(HybridBlock):
     shared.  No capacity, no dropped token, no auxiliary loss.  Children:
     ``router``, ``experts``, ``shared``, ``shared_gate``.  Under a ``parallel.TrainStep`` the
     layer reports, per step, ``mxnet_moe_pairs_total{layer}`` (pairs that
-    fell on held experts), ``mxnet_moe_tokens_total`` (tokens routed, summed
-    over layers) and ``mxnet_moe_expert_tokens_max{layer}`` (the fullest
-    held expert of a step) through ``parallel.report_counter``.
+    fell on held experts), ``mxnet_moe_windows_total{layer}`` (windows of
+    the sorted pair buffer the routed part walked to cover them: the trip
+    count of ``contrib.moe_experts``' loop, 1 where the pairs that fell here
+    are no more than the tokens), ``mxnet_moe_tokens_total`` (tokens routed,
+    summed over layers) and ``mxnet_moe_expert_tokens_max{layer}`` (the
+    fullest held expert of a step) through ``parallel.report_counter``.
     """
 
     def __init__(self, units, hidden_size, num_experts,
@@ -299,9 +302,11 @@ class DroplessMoE(HybridBlock):
         from ... import parallel, regions
         xf = F.reshape(x, shape=(-1, self._units))              # (N, U)
         weights, chosen = self.router(xf)
-        y, tokens = self.experts(xf, weights, chosen)
+        y, tokens, windows = self.experts(xf, weights, chosen)
         layer = {"layer": regions.current()}
         parallel.report_counter("mxnet_moe_pairs_total", tokens,
+                                labels=layer)
+        parallel.report_counter("mxnet_moe_windows_total", windows,
                                 labels=layer)
         parallel.report_counter("mxnet_moe_tokens_total", xf.shape[0])
         parallel.report_counter("mxnet_moe_expert_tokens_max", tokens,

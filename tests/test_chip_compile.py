@@ -209,27 +209,44 @@ def test_gated_delta_rule_compiles_at_the_cells_shape_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
-def test_routed_experts_compile_to_grouped_kernels_for_v5e(one_chip):
-    """``contrib.moe_experts`` at the benchmark's widths, under
-    jax_enable_x64 as the package runs: the compiler turns each
-    ``ragged_dot`` into a Mosaic call of its own, forward and backward."""
+@pytest.mark.parametrize("k, held, hidden, temp_limit", [
+    (6, 16, 768, 0.85e9), (10, 32, 512, 1.55e9)],
+    ids=["mla_moe_cell", "hybrid_cell"])
+def test_routed_experts_compile_to_grouped_kernels_for_v5e(
+        one_chip, k, held, hidden, temp_limit):
+    """``contrib.moe_experts`` at both MoE cells' widths (8,192 tokens, so
+    49,152 and 81,920 pairs a layer), under jax_enable_x64 as the package
+    runs: the compiler turns each ``ragged_dot`` into a Mosaic call of its
+    own, the forward and the backward each carry a buffer of all N*k rows
+    through a loop over the windows, and the planned temporaries stay under
+    what the change planned with room (790 and 1,461 MB; the parent, whose
+    every pair buffer had N*k rows, planned 1,216 and 2,155 MB)."""
+    import re
     from mxnet_tpu.ops.moe import _moe_experts
 
     def struct(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(x, w, gate, up, down, experts):
-        y, _tokens = _moe_experts(x, w, experts, gate, up, down, first=0)
+        y = _moe_experts(x, w, experts, gate, up, down, first=0)[0]
         return y.astype(jnp.float32).sum()
 
     with jax.default_matmul_precision("default"):
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-            struct((8192, 2048)), struct((8192, 6), jnp.float32),
-            struct((16, 2048, 768)), struct((16, 2048, 768)),
-            struct((16, 768, 2048)), struct((8192, 6), jnp.int32)) \
-            .compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') >= 9
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4))).lower(
+            struct((8192, 2048)), struct((8192, k), jnp.float32),
+            struct((held, 2048, hidden)), struct((held, 2048, hidden)),
+            struct((held, hidden, 2048)), struct((8192, k), jnp.int32)) \
+            .compile()
+    text = compiled.as_text()
+    # 3 forward + 9 backward products, inline for the first window and
+    # again in the loops' bodies
+    assert text.count('custom_call_target="tpu_custom_call"') >= 24
     assert "ragged-dot" in text
+    loops = re.findall(
+        r"= \(.*bf16\[%d,2048\].*\) while\(" % (8192 * k), text)
+    assert len(loops) == 2, len(loops)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
 
 
 def test_illegal_block_raises_not_falls_back(one_chip):

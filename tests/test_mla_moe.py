@@ -143,6 +143,10 @@ def test_step_returns_counters_and_banks_them_when_losses_are_fetched(
     # 8 of 16 experts held, top 3: about 1.5 pairs a token, never over 3
     assert 0 < pairs <= 2 * 64 * 3
     assert pairs / (2 * 8) <= fullest <= 64
+    # one window of the sorted pair buffer a layer and step: 64 tokens are
+    # less than a window, and the loop's trip count follows the pairs
+    assert telemetry.REGISTRY.get("mxnet_moe_windows_total",
+                                  layer).value == 2
     losses.asnumpy()                     # a second fetch banks nothing more
     assert telemetry.REGISTRY.get("mxnet_moe_pairs_total",
                                   layer).value == pairs
@@ -173,7 +177,8 @@ def test_reports_under_remat_warn_once_and_bank_nothing():
              if "report_counter" in str(w.message)]
     assert sorted(named) == ["mxnet_moe_expert_tokens_max",
                              "mxnet_moe_pairs_total",
-                             "mxnet_moe_tokens_total"]
+                             "mxnet_moe_tokens_total",
+                             "mxnet_moe_windows_total"]
     banked = telemetry.REGISTRY.get("mxnet_moe_tokens_total")
     assert banked is None or banked.value == 0
 

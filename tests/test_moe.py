@@ -254,7 +254,7 @@ def test_dropless_moe_drops_no_token_under_a_planted_imbalance():
     x = np.random.RandomState(2).randn(32, 8).astype("float32")
     xn = mx.nd.array(x)
     weights, experts = moe.router(xn)
-    y, tokens = moe.experts(xn, weights, experts)
+    y, tokens, _windows = moe.experts(xn, weights, experts)
     assert tokens.asnumpy().tolist()[1] == 32
     assert (experts.asnumpy()[:, 0] == 3).all()
     ref = _dropless_oracle(moe, x, (2, 3))
@@ -292,30 +292,66 @@ def test_dropless_moe_shares_its_experts_over_the_ep_axis():
         assert p.sharding == ("ep", None, None)
 
 
-@pytest.mark.parametrize("bias_on_held", [0.0, 100.0],
-                         ids=["even_router", "all_on_one_held_expert"])
-def test_moe_experts_equal_masked_dense_products(bias_on_held):
-    """The op's one path (pair buffers of all N*k rows, absent experts'
-    pairs last) against every held expert run densely over ALL tokens under
-    its weight: the result and every gradient, also when every token lands
-    on one held expert (64 of the 192 pairs on it)."""
+def _plant(n, k, held, absent, fill):
+    """``experts`` (n, k) int32 over ``held + absent`` experts: the first
+    ``fill[e]`` pairs in pair order go to held expert ``e`` (ids ``4 …``),
+    the rest to absent ones (ids ``0 … 3``, ``4 + held …``) in turn."""
+    ids = [4 + e for e, c in enumerate(fill) for _ in range(c)]
+    away = [e for e in range(4 + held + absent) if not 4 <= e < 4 + held]
+    ids += [away[p % len(away)] for p in range(n * k - len(ids))]
+    return np.asarray(ids, np.int32).reshape(n, k)
+
+
+# 64 tokens x 3 choices = 192 pairs, windows of 16 rows, 3 experts held
+_LOADS = {
+    "even_router": None,                    # the router's own choices
+    "all_on_one_held_expert": None,         # ... with a bias on one expert
+    "no_live_pair": [0, 0, 0],
+    "usual_share": [5, 0, 7],               # one window, one expert empty
+    "exactly_one_window": [4, 6, 6],
+    "one_window_and_one_pair": [4, 6, 7],
+    "an_expert_straddles_two_edges": [3, 40, 2],
+    "every_pair_live": [64, 60, 68],        # 12 windows, held >= k
+}
+
+
+@pytest.mark.parametrize("load", list(_LOADS))
+def test_moe_experts_equal_masked_dense_products(load, monkeypatch):
+    """The op (the sorted pair buffer walked in windows, the trip count the
+    live pairs') against every held expert run densely over ALL tokens
+    under its weight: ``y``, ``tokens``, the windows walked and every
+    gradient, in float32, at windows of 16 rows and planted loads from no
+    live pair to all N*k of them.  What a grouped product never writes on
+    the chip (rows past its groups) is planted with NaN."""
     import jax
     import jax.numpy as jnp
+    from mxnet_tpu.ops import moe
     from mxnet_tpu.ops.moe import _moe_experts, _moe_router
+    rows, n, k, held, first = 16, 64, 3, 3, 4
+    monkeypatch.setattr(moe, "_window_rows", lambda n, k: rows)
+
+    def ragged_dot_nan_past_the_groups(lhs, rhs, group_sizes):
+        out = jax.lax.ragged_dot(lhs, rhs, group_sizes)
+        written = jnp.arange(out.shape[0]) < group_sizes.sum()
+        return jnp.where(written[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(moe, "_ragged_dot", ragged_dot_nan_past_the_groups)
     r = np.random.RandomState(4)
-    x = jnp.asarray(r.randn(64, 8), jnp.float32)
+    x = jnp.asarray(r.randn(n, 8), jnp.float32)
     rw = jnp.asarray(r.randn(16, 8), jnp.float32)
-    rb = jnp.zeros(16, jnp.float32).at[5].set(bias_on_held)
-    gate, up = (jnp.asarray(r.randn(2, 8, 12), jnp.float32) for _ in "gu")
-    down = jnp.asarray(r.randn(2, 12, 8), jnp.float32)
-    weights, experts = _moe_router(x, rw, rb, k=3, scale=2.0)
-    cot = jnp.asarray(r.randn(64, 8), jnp.float32)
-    first = 4
+    rb = jnp.zeros(16, jnp.float32).at[5].set(
+        100.0 if load == "all_on_one_held_expert" else 0.0)
+    gate, up = (jnp.asarray(r.randn(held, 8, 12), jnp.float32) for _ in "gu")
+    down = jnp.asarray(r.randn(held, 12, 8), jnp.float32)
+    weights, experts = _moe_router(x, rw, rb, k=k, scale=2.0)
+    if _LOADS[load] is not None:
+        experts = jnp.asarray(_plant(n, k, held, 9, _LOADS[load]))
+    cot = jnp.asarray(r.randn(n, 8), jnp.float32)
 
     def op(x, weights, gate, up, down):
-        y, tokens = _moe_experts(x, weights, experts, gate, up, down,
-                                 first=first)
-        return (y * cot).sum(), tokens
+        y, tokens, windows = _moe_experts(x, weights, experts, gate, up,
+                                          down, first=first)
+        return (y * cot).sum(), (tokens, windows)
 
     def dense(x, weights, gate, up, down):
         y = jnp.zeros_like(x)
@@ -326,15 +362,19 @@ def test_moe_experts_equal_masked_dense_products(bias_on_held):
         return (y * cot).sum(), None
 
     args = (x, weights, gate, up, down)
-    (got, tokens), g_got = jax.value_and_grad(
+    (got, (tokens, windows)), g_got = jax.value_and_grad(
         op, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
     (want, _), g_want = jax.value_and_grad(
         dense, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
-    held = np.asarray(experts) - first
-    assert np.asarray(tokens).tolist() == [int((held == e).sum())
-                                           for e in range(2)]
-    assert (int(tokens[1]) == 64) == bool(bias_on_held)
-    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    local = np.asarray(experts) - first
+    assert np.asarray(tokens).tolist() == [int((local == e).sum())
+                                           for e in range(held)]
+    if _LOADS[load] is not None:
+        assert np.asarray(tokens).tolist() == _LOADS[load]
+    if load == "all_on_one_held_expert":
+        assert int(tokens[1]) == n
+    assert int(windows) == -(-int(tokens.sum()) // rows)
+    assert float(got) == pytest.approx(float(want), rel=1e-5, abs=1e-5)
     for a, b in zip(g_got, g_want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
                                    atol=1e-5)

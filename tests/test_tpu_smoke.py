@@ -302,7 +302,7 @@ def test_tpu_moe_experts_consistency():
                 x, mx.nd.array(rw, ctx=ctx), mx.nd.array(rb, ctx=ctx), k=k,
                 scale=2.0)
             with autograd.record():
-                y, tokens = mx.nd.contrib.moe_experts(
+                y, tokens, _windows = mx.nd.contrib.moe_experts(
                     x, weights, experts, *ws, first=8)
                 loss = (y * y).sum()
             loss.backward()
