@@ -11,8 +11,8 @@ from . import bert  # noqa: F401
 
 def __getattr__(name):
     import importlib
-    if name in ("vision", "llama", "mla_moe", "qwen3_next", "transformer",
-                "yolo"):
+    if name in ("vision", "llama", "mla_moe", "qwen3_next", "ouro",
+                "transformer", "yolo"):
         mod = importlib.import_module(f".{name}", __name__)
         globals()[name] = mod
         return mod

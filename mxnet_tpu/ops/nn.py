@@ -257,17 +257,20 @@ def _softmax_activation(data, mode="instance"):
 
 
 @register("softmax_cross_entropy")
-def _softmax_cross_entropy(data, label):
-    """Sum over the rows of ``logsumexp(data) - data[label]``.
+def _softmax_cross_entropy(data, label, per_row=False):
+    """Sum over the rows of ``logsumexp(data) - data[label]``; with
+    ``per_row`` each row's value instead, ``(rows,)`` in float32 (or wider:
+    the type the reductions run in), for a loss that weighs its rows.
 
     reference src/operator/loss_binary_op.cc.  custom_vjp, so that no array
     of ``data``'s shape outlives the forward except ``data`` itself: the
     residuals are ``data`` as it arrived, the per-row logsumexp and the
     label, and the backward recomputes the softmax from them and writes
-    ``(softmax - onehot) * g`` in one elementwise pass.  The label's place
-    is an iota comparison (no scatter, no one-hot array); reductions run in
-    float32 for narrower ``data``.  ``label`` holds class indices in
-    [0, classes), integer or float, and gets no gradient.
+    ``(softmax - onehot) * g`` in one elementwise pass (``g`` a scalar, or
+    with ``per_row`` a row's own cotangent).  The label's place is an iota
+    comparison (no scatter, no one-hot array); reductions run in float32
+    for narrower ``data``.  ``label`` holds class indices in [0, classes),
+    integer or float, and gets no gradient.
     """
     import jax
     jnp = _jnp()
@@ -287,12 +290,17 @@ def _softmax_cross_entropy(data, label):
         lse = jax.nn.logsumexp(xf, axis=-1, keepdims=True)
         picked = jnp.sum(jnp.where(at_label(l, x.shape), xf, 0), axis=-1,
                          keepdims=True)
+        if per_row:
+            return (lse - picked)[:, 0], (x, lse, l)
         return jnp.sum(lse - picked).astype(x.dtype), (x, lse, l)
 
     def f_bwd(res, g):
         x, lse, l = res
         p = jnp.exp(x.astype(acc) - lse)
-        grad = jnp.where(at_label(l, x.shape), p - 1, p) * g.astype(acc)
+        g = g.astype(acc)
+        if per_row:
+            g = g.reshape(-1, 1)
+        grad = jnp.where(at_label(l, x.shape), p - 1, p) * g
         return grad.astype(x.dtype), None
 
     f.defvjp(f_fwd, f_bwd)

@@ -401,16 +401,19 @@ class _HostPhase:
 _reports = threading.local()
 _UNDER_REMAT = object()     # the sink while a remat'd forward is traced
 _warned_under_remat = set()
+_REDUCE = ("sum", "max", "mean")    # report kinds: the reduction's name
 
 
 def report_counter(name, value, labels=None, kind="sum"):
     """Report ``value`` (an array, reduced over its elements, or a number)
     under the registry name ``name`` from inside a ``TrainStep``'s forward.
     ``kind="sum"``: a counter (``*_total``) that grows by every step's sum;
-    ``kind="max"``: a gauge that keeps the largest value any step showed.
-    Several reports under one name and labels in one step combine the same
-    way.  Outside a TrainStep's trace (an imperative forward) this does
-    nothing.  Under ``TrainStep(remat=True)`` a value cannot leave the
+    ``kind="max"``: a gauge that keeps the largest value any step showed;
+    ``kind="mean"``: a gauge set to the mean over the elements and over the
+    steps of the dispatch fetched last.  Several reports under one name and
+    labels in one step combine the same way (a second mean is refused: it
+    would need the first one's count).  Outside a TrainStep's trace (an
+    imperative forward) this does nothing.  Under ``TrainStep(remat=True)`` a value cannot leave the
     checkpointed forward: it is not collected, and the first report of each
     name says so in a warning."""
     sink = getattr(_reports, "sink", None)
@@ -425,12 +428,15 @@ def report_counter(name, value, labels=None, kind="sum"):
                 "counter stays where it is", RuntimeWarning, stacklevel=2)
         return
     import jax.numpy as jnp
-    if kind not in ("sum", "max"):
-        raise MXNetError(f"report_counter kind {kind!r}: want sum|max")
+    if kind not in _REDUCE:
+        raise MXNetError(f"report_counter kind {kind!r}: want sum|max|mean")
     raw = value._data if isinstance(value, NDArray) else jnp.asarray(value)
-    raw = jnp.sum(raw) if kind == "sum" else jnp.max(raw)
+    raw = getattr(jnp, kind)(raw)
     key = (name, tuple(sorted((labels or {}).items())), kind)
     if key in sink:
+        if kind == "mean":
+            raise MXNetError(f"report_counter({name!r}, kind='mean'): "
+                             "reported twice in one step")
         raw = sink[key] + raw if kind == "sum" \
             else jnp.maximum(sink[key], raw)
     sink[key] = raw
@@ -456,6 +462,8 @@ def _bank_reports(reports):
         labels = dict(labels) or None
         if kind == "sum":
             _tel.counter(name, labels=labels).inc(int(_np.sum(per_step)))
+        elif kind == "mean":
+            _tel.gauge(name, labels=labels).set(float(_np.mean(per_step)))
         else:
             gauge = _tel.gauge(name, labels=labels)
             gauge.set(max(gauge.value, float(_np.max(per_step))))
@@ -875,7 +883,7 @@ class TrainStep:
                     acc, (losses, reports) = jax.lax.scan(
                         micro, zeros, (keys, dm, lm))
                     # a step's report is its microbatches' combined
-                    reports = {k: v.sum(0) if k[2] == "sum" else v.max(0)
+                    reports = {k: getattr(v, k[2])(0)
                                for k, v in reports.items()}
                     inv = jnp.asarray(1.0 / n_micro, losses.dtype)
                     mean_g = tuple(a * jnp.asarray(1.0 / n_micro, a.dtype)

@@ -337,6 +337,45 @@ def test_hybrid_cells_step_compiles_under_the_chips_memory(topo):
     program.close()
 
 
+def test_looped_cells_step_compiles_with_its_flash_calls_and_fits(topo):
+    """The step of ``ouro_2_6b.train_s4096`` as the benchmark's builder
+    makes it, at the published widths, the cell's depth and one row of
+    4,096 positions, for a described v5e: the 128-wide ungrouped flash
+    kernels inside ``jax.checkpoint`` (a ``custom_vjp`` whose forward the
+    backward runs again), 24 forward calls, 18 made again and 24 backward
+    calls in one program that fits with the last loop step's activations
+    kept.  334M parameters with Adam's state are 4.7 GB of host memory."""
+    import re
+    from perfbench import run as harness
+    from perfbench import weights
+    from perfbench.builders import ouro_zoo
+    from perfbench.reference import loop_lm_train
+    _bench, cell = harness.load_cell("ouro_2_6b.train_s4096")
+    cfg, traffic = cell["config"], cell["traffic"]
+    w0 = weights.make_weights(loop_lm_train.param_shapes(cfg), 1,
+                              cfg["run"]["dtype"], jax.devices()[0])
+    program = ouro_zoo.Program(cfg, traffic, w0, jax.devices())
+    del w0
+    mesh = parallel.make_mesh(shape=(1,), axis_names=("dp",),
+                              devices=list(topo.devices[:1]))
+    held_step = program.step
+    step = parallel.TrainStep(program.model, held_step.loss_fn,
+                              held_step.optimizer, mesh=mesh)
+    batch = jax.ShapeDtypeStruct(
+        (traffic["scan_steps"], traffic["batch"], traffic["seq"]), np.int32)
+    with jax.default_matmul_precision("default"):
+        compiled = step.lowered(batch, batch).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(flash_[a-z]+)[.\d]* = ", text)
+    layers, steps = cfg["num_hidden_layers"], cfg["total_ut_steps"]
+    assert calls.count("flash_fwd") == layers * steps + layers * (steps - 1)
+    assert calls.count("flash_bwd") == layers * steps
+    assert text.count("tpu_custom_call") == len(calls) == 66
+    planned = mx.telemetry.costmodel.peak_bytes(compiled.memory_analysis())
+    assert 0.25 * V5E_HBM_BYTES < planned < V5E_HBM_BYTES
+    program.close()
+
+
 def test_trainstep_compiles_for_a_four_chip_mesh(topo, smoke, bert_2layer):
     """GSPMD cannot partition a Mosaic kernel: under a mesh the kernel
     runs inside ops.contrib._flash's shard_map (batch over dp, heads over

@@ -214,3 +214,93 @@ def test_trainstep_loss_and_first_update_equal_the_plain_formulations():
         assert gap <= 1e-3 * max(np.linalg.norm(ref_update), 1e-12), name
         moved += bool(np.linalg.norm(ref_update))
     assert moved >= len(before) - 2     # the pooler is not reached
+
+
+# -- each row's value (``per_row``), for a loss that weighs its rows ----------
+
+def plain_rows(data, label):
+    logp = jax.nn.log_softmax(data, axis=-1)
+    return -jnp.take_along_axis(
+        logp, label.astype(jnp.int32).reshape(-1, 1), axis=-1)[:, 0]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(8, 5), (13, 130)])
+def test_per_row_values_and_weighted_gradient_equal_the_plain(shape, dtype):
+    """Each row's value in float32 whatever the data's type, and a row's
+    own cotangent: the gradient of ``sum(w * rows)``."""
+    data, label = case(shape, dtype, "int32")
+    w = jnp.asarray(np.random.RandomState(3).rand(shape[0]), jnp.float32)
+    rows = OP(data, label, per_row=True)
+    assert rows.shape == shape[:1] and rows.dtype == jnp.float32
+    f32 = data.astype(jnp.float32)
+    tol = _TOL[dtype]
+    np.testing.assert_allclose(rows, plain_rows(f32, label), rtol=tol,
+                               atol=tol)
+    grad = jax.grad(lambda x: jnp.sum(w * OP(x, label, per_row=True)))(data)
+    want = jax.grad(lambda x: jnp.sum(w * plain_rows(x, label)))(f32)
+    assert grad.dtype == data.dtype
+    np.testing.assert_allclose(np.asarray(grad, np.float32), want, atol=tol)
+    # the rows add up to the summed form
+    np.testing.assert_allclose(np.float32(rows.sum()),
+                               np.float32(OP(data, label)), rtol=4 * tol)
+
+
+def test_per_row_keeps_what_the_summed_form_keeps():
+    data, label = case((128, 512), "float32", "int32")
+    _, vjp = jax.vjp(lambda x, l: OP(x, l, per_row=True), data, label)
+    leaves = [x for x in jax.tree_util.tree_leaves(vjp)
+              if hasattr(x, "shape")]
+    wide = [x for x in leaves if x.size >= data.size]
+    assert len(wide) == 1 and wide[0] is data
+    assert sorted(x.size for x in leaves) == [128, 128, 128 * 512]
+
+
+def _summed_as_pr28_left_it(data, label):
+    """The op's body before ``per_row`` (PR 28), kept to hold the summed
+    form to it instruction for instruction."""
+    acc = jnp.promote_types(data.dtype, jnp.float32)
+
+    def at_label(l, shape):
+        classes = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+        return classes == l.astype(jnp.int32).reshape(-1, 1)
+
+    @jax.custom_vjp
+    def f(x, l):
+        return f_fwd(x, l)[0]
+
+    def f_fwd(x, l):
+        xf = x.astype(acc)
+        lse = jax.nn.logsumexp(xf, axis=-1, keepdims=True)
+        picked = jnp.sum(jnp.where(at_label(l, x.shape), xf, 0), axis=-1,
+                         keepdims=True)
+        return jnp.sum(lse - picked).astype(x.dtype), (x, lse, l)
+
+    def f_bwd(res, g):
+        x, lse, l = res
+        p = jnp.exp(x.astype(acc) - lse)
+        grad = jnp.where(at_label(l, x.shape), p - 1, p) * g.astype(acc)
+        return grad.astype(x.dtype), None
+
+    f.defvjp(f_fwd, f_bwd)
+    return f(data, label)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_summed_form_lowers_as_it_did_before_per_row(dtype):
+    """``per_row`` is a branch at trace time: the summed form's program is
+    the one PR 28's readings were taken on, to the instruction."""
+    data, label = case((16, 32), dtype, "int32")
+
+    def lowered(f):
+        def step(x, l):     # one name for both, so that the texts compare
+            return jax.value_and_grad(f)(x, l)
+        return jax.jit(step).lower(data, label).as_text()
+
+    assert lowered(OP) == lowered(_summed_as_pr28_left_it)
+    assert lowered(lambda x, l: OP(x, l, per_row=True).sum()) != lowered(OP)
+    # the op under its two attributes on the tape
+    x = mx.nd.array(np.asarray(data, np.float32))
+    lab = mx.nd.array(np.asarray(label), dtype="int32")
+    assert mx.nd.softmax_cross_entropy(x, lab).shape == ()
+    assert mx.nd.softmax_cross_entropy(x, lab, per_row=True).shape == (16,)
