@@ -16,8 +16,17 @@ and only the state between chunks is carried by a scan, L / C steps of
 the chunk's cumulative sums taken under the mask ``i >= j``, so every
 exponent is at most 0; ``exp(G_i) * exp(-G_j)`` would overflow.
 
-One path, chosen by nothing but shapes.  ``jax.numpy`` + ``lax.scan``; the
-whole op sits in a ``jax.checkpoint``, so what a layer keeps for its
+One algorithm, two lowerings chosen by the platform a program is compiled
+for (``jax.lax.platform_dependent``, as attention's).  Any platform but a
+TPU, and any shape the kernels' tiling does not take, gets the form below,
+``jax.numpy`` + ``lax.scan``, which is also the statement of the
+mathematics; the whole of it sits in a ``jax.checkpoint``.  A TPU gets the
+Pallas kernels of ``kernels/gated_delta.py`` for the forward (``gdn_solve``
++ ``gdn_fwd``: every chunk's system solved by substitution a system a lane,
+then the walk with a head's state in VMEM) under one ``jax.custom_vjp``
+whose backward is the chunked form's own, made again from the op's inputs
+around ``gdn_solve``'s inverses, float32 (in place of the compiler's
+triangular solves, two a layer).  Either way what a layer keeps for its
 backward is the op's inputs, and the backward runs the chunks again (the
 chunk-boundary states, 64 KB a head and chunk in float32, and the chunk's
 solved writes are temporaries of one layer at a time).  The op runs under
@@ -30,38 +39,44 @@ from .. import regions
 from .registry import register
 
 
-def _unit_lower_inverse(a):
+def _unit_lower_inverse(a, given=None):
     """``(I + a)^-1`` of strictly lower-triangular ``a`` (..., C, C): a
     triangular solve against the identity, by substitution, float32 (the
     solve is where the writes of a chunk depend on each other).  A sum of
     the powers of ``a`` ends at ``C - 1`` too and is all matrix products,
     but the powers grow to 1e18 at C = 64 when a chunk's keys are nearly
     parallel, and cancel to nothing.  Its gradient is the closed form
-    ``-inv^T g inv^T``, two products."""
+    ``-inv^T g inv^T``, two products.  ``given``: the inverse in float32,
+    made elsewhere (``gdn_solve`` on a TPU), taken for the solve's
+    result."""
     import jax
     import jax.numpy as jnp
 
-    def solved(a):
+    def solved(a, given):
+        if given is not None:
+            return given
         eye = jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=a.dtype), a.shape)
         return jax.scipy.linalg.solve_triangular(
             eye + a, eye, lower=True, unit_diagonal=True)
 
     @jax.custom_vjp
-    def inverse(a):
-        return solved(a)
+    def inverse(a, given):
+        return solved(a, given)
 
-    def forward(a):
-        inv = solved(a)
-        return inv, inv
+    def forward(a, given):
+        inv = solved(a, given)
+        return inv, (inv, given)
 
-    def backward(inv, g):
+    def backward(kept, g):
+        inv, given = kept
         hi = jax.lax.Precision.HIGHEST
         inv_t = jnp.swapaxes(inv, -1, -2)
         return (-jnp.matmul(jnp.matmul(inv_t, g, precision=hi), inv_t,
-                            precision=hi),)
+                            precision=hi),
+                None if given is None else jnp.zeros_like(given))
 
     inverse.defvjp(forward, backward)
-    return inverse(a)
+    return inverse(a, given)
 
 
 _HEAD_GROUPS = (4, 2, 1)    # the solves run a group of heads at a time
@@ -75,7 +90,7 @@ def _product(spec, a, b, operand):
                       preferred_element_type=jnp.float32)
 
 
-def _solved_chunks(q, k, v, g, beta, operand):
+def _solved_chunks(q, k, v, g, beta, operand, inverse=None):
     """Every chunk at once: what a chunk contributes that does not depend
     on the carried state.  ``q``, ``k``, ``v`` (B, H, N, C, D) in
     ``operand``'s type, ``g`` and ``beta`` (B, H, N, C) float32.  Returns
@@ -97,7 +112,7 @@ def _solved_chunks(q, k, v, g, beta, operand):
     k_beta = k.astype(f32) * beta[..., None]
     a = jnp.where(under, product("...id,...jd->...ij", k_beta, k) * decay,
                   f32(0.0))
-    solve = _unit_lower_inverse(a)                              # (I + a)^-1
+    solve = _unit_lower_inverse(a, inverse)                     # (I + a)^-1
     into = jnp.exp(big_g)[..., None]            # decay from the chunk's start
     xs = (product("...ij,...jd->...id", solve, k_beta * into),  # (…,C,Dk)
           product("...ij,...jd->...id", solve,
@@ -110,7 +125,7 @@ def _solved_chunks(q, k, v, g, beta, operand):
         + (jnp.exp(big_g[..., -1]),)                            # (B,H,N)
 
 
-def _chunked(q, k, v, g, beta, operand):
+def _chunked(q, k, v, g, beta, operand, inverse=None):
     """The rule over (B, H, N, C, D) chunks: q, k, v in ``operand``'s type
     (the type the op was given), g and beta float32; float32 out.  Every
     product takes its operands in ``operand`` (what the MXU rounds a
@@ -130,9 +145,11 @@ def _chunked(q, k, v, g, beta, operand):
         return jnp.moveaxis(
             x.reshape((b, groups, h // groups) + x.shape[2:]), 1, 0)
 
+    given = () if inverse is None else (inverse,)   # (B, H, N, C, C)
     xs = jax.lax.map(
-        jax.checkpoint(lambda args: _solved_chunks(*args, operand)),
-        tuple(by_group(x) for x in (q, k, v, g, beta)))
+        jax.checkpoint(lambda args: _solved_chunks(*args[:5], operand,
+                                                   *args[5:])),
+        tuple(by_group(x) for x in (q, k, v, g, beta) + given))
 
     def by_chunk(x):        # (groups, B, H / groups, N, …) -> (N, B, H, …)
         x = jnp.moveaxis(jnp.moveaxis(x, 3, 0), 1, 2)
@@ -171,6 +188,65 @@ def _gated_delta_rule(q, k, v, g, beta, chunk=64):
     highest precision), every sum and the carried state are float32;
     every other product takes its operands in v's type."""
     import jax
+    from ..kernels import gated_delta
+
+    def portable(*xs):
+        return _chunked_rule(*xs, chunk)
+
+    def kernels(*xs):
+        return _kernel_rule(*xs, chunk)
+
+    with regions.scope("gdn_scan"):
+        # by the shapes a device gets: its share of the heads under a mesh
+        if gated_delta.eligible(chunk, _shards(q.shape)[-1], q.shape[-1],
+                                v.shape[-1], v.dtype):
+            # resolved when the program is lowered: a TPU gets the Pallas
+            # kernels, any other platform the chunked form
+            return jax.lax.platform_dependent(q, k, v, g, beta,
+                                              tpu=kernels, default=portable)
+        return portable(q, k, v, g, beta)
+
+
+def _kernel_rule(q, k, v, g, beta, chunk, interpret=False):
+    """The op as a TPU runs it: the Pallas kernels' forward under one
+    ``jax.custom_vjp`` whose residuals are the op's inputs and whose
+    backward is the chunked form's, around ``gdn_solve``'s float32 inverses.
+    ``interpret=True`` runs the Pallas interpreter (the CPU tests)."""
+    import jax
+    import jax.numpy as jnp
+    from ..kernels import gated_delta
+
+    shards = _shards(q.shape)   # read once: the backward is traced later
+
+    def forward(*inputs):
+        return _per_shard(lambda *xs: gated_delta.forward(
+            *xs, chunk, interpret), shards, *inputs)
+
+    def backward(inputs, d_out):
+        # as jax.checkpoint does: the chunks made again wait for the
+        # cotangent, so nothing of the forward is shared or kept.  The
+        # chunks' systems are solved by the kernel once more, in place of
+        # the compiler's triangular solves (two a layer, 5.5 ms), from k in
+        # the type the chunked form multiplies it in
+        inputs, d_out = jax.lax.optimization_barrier((inputs, d_out))
+        _q, k, v, g, beta = inputs
+        inverse = jnp.moveaxis(jax.lax.stop_gradient(_per_shard(
+            lambda *xs: gated_delta.inverses(*xs, chunk, interpret),
+            shards, k.astype(v.dtype), g, beta)), 2, 1)     # (B, H, N, C, C)
+        return jax.vjp(lambda *xs: _chunked_rule(*xs, chunk, inverse),
+                       *inputs)[1](d_out)
+
+    rule = jax.custom_vjp(forward)
+    rule.defvjp(lambda *inputs: (forward(*inputs), inputs), backward)
+    return rule(q, k, v, g, beta)
+
+
+def _chunked_rule(q, k, v, g, beta, chunk, inverse=None):
+    """The op in ``jax.numpy``: ``_chunked`` over rows padded to whole
+    chunks, the whole of it in a ``jax.checkpoint``.  ``inverse``: every
+    chunk's solved system (B, H, N, C, C) in float32, where a kernel has
+    made it."""
+    import jax
     import jax.numpy as jnp
     f32 = jnp.float32
     b, l, h, _dk = q.shape
@@ -185,15 +261,52 @@ def _gated_delta_rule(q, k, v, g, beta, chunk=64):
         return jnp.moveaxis(x, 3, 1)
 
     @jax.checkpoint
-    def rule(q, k, v, g, beta):
+    def rule(q, k, v, g, beta, *inverse):
         wide = [chunks(x.astype(v.dtype)) for x in (q, k, v)]
         out = _chunked(*wide, chunks(g.astype(f32)),
-                       chunks(beta.astype(f32)), v.dtype)       # (B,H,N,C,Dv)
+                       chunks(beta.astype(f32)), v.dtype,
+                       *inverse)                                # (B,H,N,C,Dv)
         out = jnp.moveaxis(out, 1, 3).reshape(b, n * chunk, h, -1)
         return out[:, :l].astype(v.dtype)
 
-    with regions.scope("gdn_scan"):
-        return rule(q, k, v, g, beta)
+    return rule(q, k, v, g, beta, *(() if inverse is None else (inverse,)))
+
+
+def _shards(shape):
+    """How a ``TrainStep`` traced over several devices splits (B, L, H, …)
+    operands: ``(mesh, batch axes, head axis, heads a device)``, the batch
+    over the step's data axes and the heads over ``tp`` where they divide
+    (``ops/contrib.py::_flash``'s rule; a row's positions stay whole, the
+    scan carries state along them).  No mesh, or one device: ``(None, None,
+    None, H)``."""
+    from .registry import step_layout
+    b, _l, h = shape[:3]
+    layout = step_layout()
+    if layout is None or layout[0].size == 1:
+        return None, None, None, h
+    mesh, batch_axes = layout
+    n_b = 1
+    for a in batch_axes:
+        n_b *= mesh.axis_size(a)
+    tp = mesh.axis_size("tp") if "tp" in mesh.axis_names else 0
+    by_head = tp and h % tp == 0
+    return (mesh, batch_axes if batch_axes and b % n_b == 0 else None,
+            "tp" if by_head else None, h // tp if by_head else h)
+
+
+def _per_shard(fn, shards, *xs):
+    """``fn`` over (B, L, H, …) operands, on each device's own block when a
+    ``TrainStep`` traces over several: GSPMD cannot partition a Mosaic
+    kernel, so the call is a ``shard_map`` over ``shards``, ``_shards``'
+    split."""
+    import jax
+    mesh, b_spec, h_spec, _heads = shards
+    if mesh is None:
+        return fn(*xs)
+    specs = tuple(jax.sharding.PartitionSpec(
+        b_spec, None, h_spec, *(None,) * (x.ndim - 3)) for x in xs)
+    return jax.shard_map(fn, mesh=mesh.mesh, in_specs=specs,
+                         out_specs=specs[0], check_vma=False)(*xs)
 
 
 @register("contrib.causal_conv1d")

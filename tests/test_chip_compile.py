@@ -185,28 +185,101 @@ def test_flash_kernels_compile_at_256_wide_heads_and_seq_8192(one_chip):
     assert f'"size":"{planned}"' in bwd
 
 
-def test_gated_delta_rule_compiles_at_the_cells_shape_for_v5e(one_chip):
-    """The chunked scan at the benchmark's size (one row of 8,192
-    positions, 32 value heads of 128 / 128, bfloat16 in), forward and
-    backward: plain XLA, no Pallas call; its temporaries, with the solves
-    run a group of heads at a time, stay under 2 GB."""
-    from mxnet_tpu.ops.linear_attention import _gated_delta_rule
+GDN_KERNELS = ("gdn_solve", "gdn_fwd")
+
+
+def _gdn_cell_shapes(one_chip):
+    """The benchmark's size: one row of 8,192 positions, 32 value heads of
+    128 / 128, bfloat16 in."""
     wide = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
                                 sharding=one_chip)
     head = jax.ShapeDtypeStruct((1, 8192, 32), jnp.float32,
                                 sharding=one_chip)
+    return wide, wide, wide, head, head
 
+
+def _gdn_compiled(rule, shapes):
     def loss(q, k, v, g, beta):
-        out = _gated_delta_rule(q, k, v, g, beta, chunk=64)
-        return out.astype(jnp.float32).sum()
+        return (rule(q, k, v, g, beta).astype(jnp.float32) ** 2).sum()
 
     with jax.default_matmul_precision("default"):
-        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
-            .lower(wide, wide, wide, head, head).compile()
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))) \
+            .lower(*shapes).compile()
+
+
+def _gdn_calls(text):
+    """How many Mosaic calls of each kernel's name stand in a compiled
+    module's text under the ``gdn_scan`` scope."""
+    import re
+    calls = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*', text)
+    return {name: sum(bool(re.search(
+        r"gdn_scan\)*/[^\"]*/%s/" % name, c)) for c in calls)
+        for name in GDN_KERNELS}
+
+
+def test_gated_delta_rule_compiles_at_the_cells_shape_for_v5e(one_chip):
+    """The op at the benchmark's size, forward and backward, compiled for a
+    TPU: the forward is the Mosaic calls ``gdn_solve`` and ``gdn_fwd`` under
+    the ``gdn_scan`` scope, the backward ``gdn_solve`` once more (in
+    float32) and the chunked form made again around its inverses (the
+    form's ``while`` loops, none of the compiler's triangular solves); the
+    temporaries stay under 2 GB (the chunked backward's)."""
+    from mxnet_tpu.ops.linear_attention import _gated_delta_rule
+    compiled = _gdn_compiled(
+        lambda *a: _gated_delta_rule(*a, chunk=64), _gdn_cell_shapes(one_chip))
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    assert "gdn_scan" in text and " while(" in text
+    assert _gdn_calls(text) == {"gdn_solve": 2, "gdn_fwd": 1}
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    solves = [line for line in text.splitlines()
+              if "custom-call(" in line and "/gdn_solve/" in line]
+    assert sorted(line.split("=", 1)[1].split("[", 1)[0].strip()
+                  for line in solves) == ["bf16", "f32"]
+    assert " while(" in text and "Triangular" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+@pytest.mark.parametrize("heads, kernels", [(8, True), (6, False)])
+def test_gated_delta_rule_compiles_per_shard_on_a_dp2_tp2_mesh(topo, heads,
+                                                               kernels):
+    """Under a step traced over several devices the kernels run inside a
+    ``shard_map`` (GSPMD cannot partition a Mosaic call): rows over ``dp``,
+    heads over ``tp``, each device its own block, no collective in the
+    forward (the backward is the chunked form, GSPMD's as before).  The
+    tiling is asked about a device's heads: 3 of 6 stack in no pairs, and
+    the op is the chunked form there."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu.ops import registry
+    from mxnet_tpu.ops.linear_attention import _gated_delta_rule
+    mesh = parallel.make_mesh(shape=(2, 2), axis_names=("dp", "tp"),
+                              devices=list(topo.devices))
+    wide = jax.ShapeDtypeStruct(
+        (4, 512, heads, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh.mesh, P("dp", None, "tp", None)))
+    head = jax.ShapeDtypeStruct(
+        (4, 512, heads), jnp.float32,
+        sharding=NamedSharding(mesh.mesh, P("dp", None, "tp")))
+
+    def forward(q, k, v, g, beta):
+        with registry.step_layout_scope(mesh, ("dp",)):
+            return _gated_delta_rule(q, k, v, g, beta, chunk=64)
+
+    def gradients(*a):      # the backward's solve runs per shard too
+        return jax.grad(lambda *a: forward(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2, 3, 4))(*a)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(forward).lower(wide, wide, wide, head, head) \
+            .compile().as_text()
+        backward = jax.jit(gradients).lower(wide, wide, wide, head, head) \
+            .compile().as_text()
+    if not kernels:
+        assert "tpu_custom_call" not in text + backward
+        return
+    assert _gdn_calls(text) == {"gdn_solve": 1, "gdn_fwd": 1}
+    # a device's block: 2 rows of 512 positions, 4 heads
+    assert "bf16[2,512,512]" in text
+    assert "all-gather" not in text and "all-reduce(" not in text
+    assert _gdn_calls(backward)["gdn_solve"] >= 1
 
 
 @pytest.mark.parametrize("k, held, hidden, temp_limit", [
@@ -298,7 +371,8 @@ def test_hybrid_cells_step_compiles_under_the_chips_memory(topo):
     """The step of ``qwen3_next_80b_a3b.train_s8192`` as the benchmark's
     builder makes it, at the published widths, the cell's depth and one row
     of 8,192 positions, for a described v5e: both streaming flash kernels,
-    the grouped products and the scan in one program that fits.  Built on
+    the grouped products and the scan's forward kernels, once each a linear
+    layer, in one program that fits.  Built on
     the CPU with 8 of the cell's 32 held experts (the suite's workers share
     this machine's memory; 323M parameters with Adam's state are 4.5 GB of
     host memory, the cell's 626M would be 8.8).  What fits here is the
@@ -332,8 +406,15 @@ def test_hybrid_cells_step_compiles_under_the_chips_memory(topo):
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_bwd", "ragged-dot", "gdn_scan"):
         assert name in text, name
+    linear = cfg["num_hidden_layers"] \
+        - cfg["num_hidden_layers"] // cfg["full_attention_interval"]
+    assert linear == 3 and _gdn_calls(text) == {
+        "gdn_solve": 2 * linear, "gdn_fwd": linear}
     planned = mx.telemetry.costmodel.peak_bytes(compiled.memory_analysis())
-    assert 0.25 * V5E_HBM_BYTES < planned < V5E_HBM_BYTES
+    # 12.3 GB (4.53 of arguments, 7.55 of temporaries, the chunked
+    # backward's as before the forward kernels): what the 24 absent experts
+    # a layer need stays free
+    assert 0.25 * V5E_HBM_BYTES < planned < 13.0e9
     program.close()
 
 
