@@ -28,10 +28,13 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import logging
 import threading
+import time
 import warnings
 
 import numpy as _np
+from jax.profiler import TraceAnnotation
 
 from .base import MXNetError
 from .context import Context
@@ -45,7 +48,8 @@ from .ndarray.ndarray import NDArray
 
 # sharded-step observability (ISSUE 8 satellite): dispatches vs retraces —
 # a steady-state sharded loop must show dispatches growing while retraces
-# stay flat (the runtime twin of graftcheck GC02 for the mesh path)
+# stay flat (the runtime twin of graftcheck GC02 for the mesh path).  Always
+# on, like the dispatch record (telemetry.stepclock): one inc a dispatch.
 _M_STEP_DISPATCHES = _tel.counter(
     "mxnet_sharding_step_dispatches_total",
     "Sharded TrainStep dispatches (one per __call__/run invocation).")
@@ -57,6 +61,17 @@ _M_MICROBATCHES = _tel.counter(
     "mxnet_trainstep_microbatches_total",
     "Microbatches executed by gradient-accumulation TrainSteps "
     "(n_micro per dispatch; n_micro=1 steps do not count).")
+_M_RESOLVE_SECONDS = _tel.gauge(
+    "mxnet_trainstep_resolve_seconds",
+    "Seconds inside TrainStep._resolve (fixing the parameter and state "
+    "order; the imperative forward that finishes deferred init included); "
+    "only grows.")
+_DEVICE_BYTES_HELP = (
+    "On the fullest device of a TrainStep's mesh, at its last dispatch that "
+    "built a program: memory_stats' bytes_in_use just before the call "
+    "(kind=in_use) and the bytes of the program's arguments resident there "
+    "(kind=arguments).  Absent where the runtime gives no memory_stats.")
+_log = logging.getLogger("mxnet_tpu.trainstep")
 
 __all__ = ["DeviceMesh", "make_mesh", "data_parallel_ctxs", "TrainStep",
            "allreduce", "allgather", "current_mesh", "set_mesh",
@@ -349,43 +364,53 @@ class _TracedCount(dict):
         pass
 
 
-# The four host phases of one TrainStep dispatch -> the StepClock phase
-# each feeds (telemetry.stepclock): only the device_put block is h2d, the
-# rest is the host's work to launch the program.  None of it is compute.
-_HOST_PHASES = {"bookkeeping": "enqueue", "h2d": "h2d",
-                "enqueue": "enqueue", "writeback": "enqueue"}
-
-
 class _HostPhase:
-    """``with _HostPhase("h2d", enabled):`` — one host phase of a dispatch,
-    under the name ``trainstep.<phase>``.  Always a
-    ``jax.profiler.TraceAnnotation``: inert (~0.4 us) unless a profiler
-    session is open, and then a span on the trace's host plane, on the
-    device trace's clock.  With telemetry enabled it is a
-    ``telemetry.Span`` (the same annotation plus the ring buffer) and the
-    span's own stamps feed the StepClock."""
+    """``with _HostPhase("h2d") as ph:`` — one host interval of a dispatch
+    under the name ``trainstep.<phase>``: a
+    ``jax.profiler.TraceAnnotation`` (inert, ~0.4 us, unless a profiler
+    session is open; then a span on the trace's host plane, on the device
+    trace's clock) and two ``perf_counter`` stamps, ``t0`` and ``t1``, for
+    the dispatch record (telemetry.stepclock)."""
 
-    __slots__ = ("_span", "_phase")
+    __slots__ = ("_annotation", "t0", "t1")
 
-    def __init__(self, name, enabled):
-        if enabled:
-            self._span = _ttrace.Span(_ttrace.get_tracer(),
-                                      "trainstep." + name, "trainstep", {})
-            self._phase = _HOST_PHASES[name]
-        else:
-            from jax.profiler import TraceAnnotation
-            self._span = TraceAnnotation("trainstep." + name)
-            self._phase = None
+    def __init__(self, name):
+        self._annotation = TraceAnnotation("trainstep." + name)
 
     def __enter__(self):
-        self._span.__enter__()
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._span.__exit__(*exc)
-        if self._phase is not None:
-            _sclock.STEP_CLOCK.note(self._phase, self._span.duration_s)
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         return False
+
+    @property
+    def span(self):
+        return self.t0, self.t1
+
+
+def _bank_device_bytes(mesh, arguments):
+    """``mxnet_trainstep_device_bytes`` for the fullest device of ``mesh``:
+    what ``memory_stats`` has in use there, and the bytes of ``arguments``
+    (device arrays) whose shards live there: metadata, no transfer.
+    Nothing where the runtime gives no ``memory_stats`` (the CPU's)."""
+    in_use = {}
+    for dev in mesh.devices:
+        stats = dev.memory_stats()
+        if stats and "bytes_in_use" in stats:
+            in_use[dev] = int(stats["bytes_in_use"])
+    if not in_use:
+        return
+    fullest = max(in_use, key=in_use.get)
+    resident = sum(shard.data.nbytes for a in arguments
+                   for shard in a.addressable_shards
+                   if shard.device == fullest)
+    for kind, nbytes in (("in_use", in_use[fullest]), ("arguments", resident)):
+        _tel.gauge("mxnet_trainstep_device_bytes", _DEVICE_BYTES_HELP,
+                   labels={"kind": kind}).set(nbytes)
 
 
 # -- counters a block reports from inside the step -------------------------------
@@ -470,13 +495,27 @@ def _bank_reports(reports):
 
 
 class _StepLosses(NDArray):
-    """The losses of a dispatch whose step reported counters: fetching them
-    (``asnumpy`` and what goes through it) banks the counters too, once."""
+    """The losses of a dispatch.  Fetching them (``asnumpy`` and what goes
+    through it) stamps the dispatch's record, once: the blocking part is
+    the interval ``trainstep.fetch``, and a dispatch that came far later
+    than the ring's usual gets one line on the ``mxnet_tpu.trainstep``
+    logger.  Where the step's blocks reported counters, the same fetch
+    banks them."""
 
-    __slots__ = ("_reports",)
+    __slots__ = ("_reports", "_record")
 
     def asnumpy(self):
-        out = super().asnumpy()
+        rec, self._record = self._record, None
+        if rec is None:
+            return super().asnumpy()
+        late = self._data.is_ready()
+        with _HostPhase("fetch") as fetch:
+            out = super().asnumpy()
+        slow = _sclock.close_fetch(rec, *fetch.span, late)
+        if slow:
+            _log.warning(slow)
+        if _ttrace._ENABLED:
+            _sclock.span_to_ring("fetch", *fetch.span)
         reports, self._reports = self._reports, None
         if reports:
             _bank_reports(reports)
@@ -585,6 +624,9 @@ class TrainStep:
         self._fused = None        # (kind, bucket plan) — optimizer_fusion
         self._cache = {}
         self._cache_epoch = None
+        self._fresh = set()       # programs built and not yet dispatched
+        self._owner = next(_sclock.OWNERS)    # in the dispatch records
+        self._last_losses = None  # the dispatch before: was the device fed?
         self._step_count = 0
 
     def _evict_stale_traces(self):
@@ -593,6 +635,7 @@ class TrainStep:
         from .ops import registry as _reg
         if self._cache_epoch != _reg.dispatch_epoch():
             self._cache.clear()
+            self._fresh.clear()
             self._cache_epoch = _reg.dispatch_epoch()
 
     # -- state plumbing -------------------------------------------------------
@@ -631,46 +674,52 @@ class TrainStep:
     def _resolve(self, data_nd):
         """Fix the param/state order; ``data_nd=None`` (lowering from
         shapes alone) skips the forward that finishes deferred init, so
-        every param must already know its shape."""
-        from . import autograd
-        if data_nd is not None:
-            with autograd.pause():
-                self.net(data_nd)  # finish deferred init
-        self._params = list(self.net.collect_params().values())
-        if data_nd is None:
-            deferred = [p.name for p in self._params
-                        if p.shape is None or 0 in p.shape]
-            if deferred:
-                raise MXNetError(
-                    "TrainStep.lowered from shapes needs an initialized "
-                    "net: run one forward first (deferred-init params: "
-                    f"{deferred[:4]})")
-        self._trainable = [p for p in self._params if p.grad_req != "null"]
-        if self._rules is not None:
-            # declarative layout: resolve the rule set against the named
-            # param tree ONCE (first-match-wins, scalars + unmatched
-            # replicate) — _param_sharding then reads these specs
-            from . import sharding as _sh
-            self._param_specs = _sh.match_partition_rules(
-                self._rules, {p.name: p for p in self._params})
-        self._states = {
-            i: self.optimizer.create_state_multi_precision(i, p.data())
-            for i, p in enumerate(self._trainable)}
-        flat, owners = [], []
-        for i in range(len(self._trainable)):
-            n0 = len(flat)
-            self._flat_state(self._states[i], flat)
-            owners.extend([i] * (len(flat) - n0))
-        self._state_nds = flat
-        self._state_owner = owners
-        self._p_sh = self._s_sh = None  # re-resolve shardings next use
-        # fused optimizer (optimizer_fusion): plan the dtype buckets NOW
-        # (host side, before any tracing); raw() then updates through the
-        # fused math inline — the same formulas the imperative Trainer
-        # path dispatches with donation — instead of tracing ~2 registry
-        # dispatch wrappers per parameter
-        from . import optimizer_fusion as _fus
-        self._fused = _fus.plan_trainstep(self.optimizer, self._trainable)
+        every param must already know its shape.  The whole of it is
+        the interval ``trainstep.resolve`` and grows
+        ``mxnet_trainstep_resolve_seconds``."""
+        with _HostPhase("resolve") as resolve:
+            from . import autograd
+            if data_nd is not None:
+                with autograd.pause():
+                    self.net(data_nd)  # finish deferred init
+            self._params = list(self.net.collect_params().values())
+            if data_nd is None:
+                deferred = [p.name for p in self._params
+                            if p.shape is None or 0 in p.shape]
+                if deferred:
+                    raise MXNetError(
+                        "TrainStep.lowered from shapes needs an initialized "
+                        "net: run one forward first (deferred-init params: "
+                        f"{deferred[:4]})")
+            self._trainable = [p for p in self._params if p.grad_req != "null"]
+            if self._rules is not None:
+                # declarative layout: resolve the rule set against the named
+                # param tree ONCE (first-match-wins, scalars + unmatched
+                # replicate) — _param_sharding then reads these specs
+                from . import sharding as _sh
+                self._param_specs = _sh.match_partition_rules(
+                    self._rules, {p.name: p for p in self._params})
+            self._states = {
+                i: self.optimizer.create_state_multi_precision(i, p.data())
+                for i, p in enumerate(self._trainable)}
+            flat, owners = [], []
+            for i in range(len(self._trainable)):
+                n0 = len(flat)
+                self._flat_state(self._states[i], flat)
+                owners.extend([i] * (len(flat) - n0))
+            self._state_nds = flat
+            self._state_owner = owners
+            self._p_sh = self._s_sh = None  # re-resolve shardings next use
+            # fused optimizer (optimizer_fusion): plan the dtype buckets NOW
+            # (host side, before any tracing); raw() then updates through the
+            # fused math inline — the same formulas the imperative Trainer
+            # path dispatches with donation — instead of tracing ~2 registry
+            # dispatch wrappers per parameter
+            from . import optimizer_fusion as _fus
+            self._fused = _fus.plan_trainstep(self.optimizer, self._trainable)
+        _M_RESOLVE_SECONDS.inc(resolve.t1 - resolve.t0)
+        if _ttrace._ENABLED:
+            _sclock.span_to_ring("resolve", *resolve.span)
 
     def _param_sharding(self, p):
         """Resolved NamedSharding for one param.  With partition_rules
@@ -908,8 +957,6 @@ class TrainStep:
         in_sh = (repl, repl, repl, repl, p_sh, s_sh, d_sh, l_sh)
         out_sh = (p_sh, s_sh, repl, repl)
         donate = (4, 5) if self._donate else ()
-        if _ttrace._ENABLED:
-            _M_RETRACES.inc()
         return _costmodel.wrap_jit(
             jax.jit(raw, in_shardings=in_sh, out_shardings=out_sh,
                     donate_argnums=donate), "parallel.TrainStep")
@@ -951,8 +998,6 @@ class TrainStep:
         in_sh = (repl, repl, repl, repl, p_sh, s_sh, d_sh, l_sh)
         out_sh = (p_sh, s_sh, repl, repl)
         donate = (4, 5) if self._donate else ()
-        if _ttrace._ENABLED:
-            _M_RETRACES.inc()
         return _costmodel.wrap_jit(
             jax.jit(train_steps, in_shardings=in_sh, out_shardings=out_sh,
                     donate_argnums=donate), "parallel.TrainStep")
@@ -972,6 +1017,8 @@ class TrainStep:
                 self._build_multi(stacked, len(data.shape),
                                   len(label.shape))
             self._cache[key_sig] = fn
+            self._fresh.add(fn)     # its first dispatch is the one that
+            _M_RETRACES.inc()       # ``built`` (the dispatch record)
         return fn
 
     def lowered(self, data, label, steps=None, scan=True):
@@ -1104,18 +1151,16 @@ class TrainStep:
         them, ``enqueue`` calls the jitted program — asynchronously: it
         returns before the device has finished, so its time says nothing
         about the chip — and ``writeback`` hands the new arrays to the
-        parameter and state handles.  Returns the program's losses as an
-        NDArray; where the step's blocks reported counters
-        (``report_counter``), fetching the losses banks them."""
+        parameter and state handles.  Every dispatch leaves its record in
+        ``telemetry.stepclock.DISPATCHES``, telemetry on or off.  Returns
+        the program's losses as an NDArray whose fetch stamps the record
+        and, where the step's blocks reported counters
+        (``report_counter``), banks them."""
         import jax
-        # one flag read per dispatch (graftcheck GC05); the StepClock
-        # treats each dispatch as one "step"
-        enabled = _ttrace._ENABLED
-        if enabled:
-            _sclock.STEP_CLOCK.begin_step()
-        with _HostPhase("bookkeeping", enabled):
+        rec = _sclock.open_dispatch(self._owner, steps)
+        with _HostPhase("bookkeeping") as ph_bookkeeping:
             scalars = bookkeeping()
-        with _HostPhase("h2d", enabled):
+        with _HostPhase("h2d") as ph_h2d:
             lead = 1 if stacked else 0
             d_sh, l_sh = self._data_shardings(len(data.shape) - lead,
                                               len(label.shape) - lead,
@@ -1127,21 +1172,31 @@ class TrainStep:
                            for p, sh in zip(self._params, p_sh))
             s_vals = tuple(jax.device_put(s._data, sh)
                            for s, sh in zip(self._state_nds, s_sh))
-        if enabled:
-            _M_STEP_DISPATCHES.inc()
-            if self._n_micro > 1:
-                _M_MICROBATCHES.inc(self._n_micro * steps)
-        with _HostPhase("enqueue", enabled):
+        _M_STEP_DISPATCHES.inc()
+        if self._n_micro > 1:
+            _M_MICROBATCHES.inc(self._n_micro * steps)
+        built = fn in self._fresh
+        if built:
+            self._fresh.discard(fn)
+            _bank_device_bytes(self.mesh, p_vals + s_vals + (d, l))
+        with _HostPhase("enqueue") as ph_enqueue:
             new_p, new_s, losses, reports = fn(*scalars, p_vals, s_vals, d, l)
-        with _HostPhase("writeback", enabled):
+        # one non-blocking look: did the device still have the dispatch
+        # before this one to run when this one was queued behind it?
+        last, self._last_losses = self._last_losses, losses
+        fed = last is not None and not last.is_ready()
+        with _HostPhase("writeback") as ph_writeback:
             for p, v in zip(self._params, new_p):
                 p._data._set_data(v)
             for s, v in zip(self._state_nds, new_s):
                 s._set_data(v)
-        if enabled:
-            _sclock.STEP_CLOCK.end_step()
-        if not reports:
-            return NDArray._from_data(losses)
+        # one flag read per dispatch (graftcheck GC05); the StepClock
+        # treats each dispatch as one "step"
+        _sclock.close_dispatch(
+            rec, {"bookkeeping": ph_bookkeeping.span, "h2d": ph_h2d.span,
+                  "enqueue": ph_enqueue.span, "writeback": ph_writeback.span},
+            fed, built, _ttrace._ENABLED)
         out = _StepLosses._from_data(losses)
         out._reports = reports
+        out._record = rec
         return out

@@ -28,8 +28,12 @@ The distributed observability plane (ISSUE 10) sits on top:
   rendered by ``telemetry.report()``.  A ``TrainStep`` phase is host time
   of one dispatch: ``h2d`` (the ``device_put`` block) and ``enqueue``
   (bookkeeping, the asynchronous call of the jitted program, writeback) —
-  never compute, which only a device trace sees.  The same four intervals
-  are ``trainstep.*`` ``TraceAnnotation``s whether telemetry is on or not.
+  never compute, which only a device trace sees.  Those numbers come from
+  the **dispatch record**, which a ``TrainStep`` keeps whether telemetry is
+  on or not: ``stepclock.DISPATCHES``, one record a dispatch (host phases,
+  was the device fed, the wait in the fetch), the same intervals as
+  ``trainstep.*`` ``TraceAnnotation``s, and the ``mxnet_trainstep_*``
+  counters banked from it.
 - **flightrec** — the always-on crash black box: bounded postmortem dumps
   on unhandled exceptions, deadline-exceeded, chaos exits, SIGTERM, and
   SIGUSR2 (``MXNET_FLIGHTREC*`` knobs).

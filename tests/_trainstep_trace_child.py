@@ -1,5 +1,6 @@
 """Child of tests/test_step_names.py: two dispatches of the tiny zoo-BERT
-TrainStep under a jax.profiler trace; prints the trace directory.  Run as a
+TrainStep, each with its fetch, and the resolve of a second TrainStep under
+a jax.profiler trace; prints the trace directory.  Run as a
 FILE (the profiler session and the compile stay out of the test worker, and
 the parent gives the child a time limit)."""
 
@@ -20,6 +21,7 @@ def main(trace_dir):
     try:
         for _ in range(2):
             step.run(tokens, labels).asnumpy()
+        tiny_step()._resolve(tokens[0])
     finally:
         jax.profiler.stop_trace()
     print(trace_dir)

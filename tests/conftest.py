@@ -96,6 +96,15 @@ _HELD_FOR_A_BENCHMARK_PR = {
     "test_the_cell_reports_every_per_layer_metric_that_names_it":
         "asserts its cell is last in BENCHMARK.json's lists; new cells are "
         "appended after it (PERF.md section 7)",
+    # PR 37 appended six per-layer metrics that list every cell (the readers
+    # of the dispatch record); this test asserts that the looped cell's four
+    # metrics are the LAST four of `per_layer` and that no other metric
+    # lists the cell, and a PR that is no `benchmark` PR may not edit it.
+    "test_perfbench_loop_lm_run.py::"
+    "test_the_cell_and_its_metrics_are_declared_after_the_accepted_ones":
+        "asserts its four metrics are the last of per_layer and the only "
+        "new ones that list its cell; PR 37's six are appended after them "
+        "(ROADMAP C10)",
 }
 
 

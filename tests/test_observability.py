@@ -173,8 +173,7 @@ def test_stepclock_phase_accounting():
     clock.note("data_wait", 0.05)          # between-steps note → pending
     clock.begin_step()
     clock.note("comms", 0.02)
-    with clock.phase("h2d"):
-        pass
+    clock.note("h2d", 0.001)
     clock.end_step()
     s = clock.summary()
     assert s["steps"] == 1

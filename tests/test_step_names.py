@@ -190,7 +190,7 @@ def test_build_stages_are_banked_once_with_telemetry_off():
     assert _build_seconds() == built
 
 
-# -- (c) the four host spans on the profiler's clock --------------------------
+# -- (c) the program's host spans on the profiler's clock ---------------------
 
 def test_trainstep_spans_land_on_the_profilers_host_plane(tmp_path):
     sys.path.insert(0, os.path.dirname(HERE))
@@ -211,11 +211,13 @@ def test_trainstep_spans_land_on_the_profilers_host_plane(tmp_path):
                 if name.startswith("trainstep."):
                     spans.setdefault(name, []).append((start, start + dur))
     assert sorted(spans) == ["trainstep.bookkeeping", "trainstep.enqueue",
-                             "trainstep.h2d", "trainstep.writeback"]
+                             "trainstep.fetch", "trainstep.h2d",
+                             "trainstep.resolve", "trainstep.writeback"]
+    assert len(spans.pop("trainstep.resolve")) == 1      # the second step's
     assert {len(v) for v in spans.values()} == {2}       # two dispatches
     first = {k: min(v) for k, v in spans.items()}
     order = ["trainstep.bookkeeping", "trainstep.h2d", "trainstep.enqueue",
-             "trainstep.writeback"]
+             "trainstep.writeback", "trainstep.fetch"]
     for a, b in zip(order, order[1:]):
         assert first[a][1] <= first[b][0], (a, b)
 
