@@ -27,10 +27,12 @@ position, the mean over positions.
 
 Activations: a step's activations are ``loop_steps`` times as deep as its
 weights.  Under ``autograd.is_recording()`` the layer applications of the
-loop steps before the last run under ``gluon.utils.remat_call`` (kept: the
-application's input; everything inside is made again in the backward), the
-last step's are called plainly, since their backward runs first.  A rule of
-the shapes: no argument, no environment variable.
+loop steps before the last run under ``gluon.utils.remat_call`` with the
+policy ``_KEEP`` (kept: the application's input, its seven products and
+the attention kernel's output and ``lse``; the norms, RoPE, the head split
+and merge, the SwiGLU's product and the residual sums are made again in
+the backward), the last step's are called plainly, since their backward
+runs first.  A rule of the model: no argument, no environment variable.
 
 Children are registered under names the benchmark's region file tells
 apart: ``embed``, ``layers/layer<i>/{attn/{in_norm, q_proj, k_proj,
@@ -38,16 +40,17 @@ v_proj, o_proj, out_norm}, mlp/{in_norm, gate, up, down, out_norm}}``,
 ``norm``, ``lm_head``, ``exit_gate``; each loop step's layers and norm run
 under the scope ``loop<t>``, so an instruction reads
 ``ouro/loop2/layers/layer3/attn/…``.  A traced forward grows
-``mxnet_loop_layer_passes_total{model, kind}`` by the forward passes of a
-decoder layer the step will make: one for an application whose activations
-are kept (``kind="kept"``), two for one under ``remat_call``
-(``kind="made_again"``: the pass whose activations are dropped and the
-pass that makes them again).  Under a ``parallel.TrainStep`` the gate
+``mxnet_loop_layer_passes_total{model, kind="kept"}`` by one an
+application: every application's products and attention kernel run once,
+under ``remat_call`` too (what its backward makes again is elementwise).
+Under a ``parallel.TrainStep`` the gate
 reports ``mxnet_loop_exit_mass{step}``, the mean of ``p_t`` over the
 positions of the dispatch fetched last.
 """
 
 from __future__ import annotations
+
+from jax import checkpoint_policies
 
 from ... import autograd, regions
 from ...telemetry import metrics as _metrics
@@ -127,22 +130,29 @@ class OuroDecoderLayer(HybridBlock):
         return a + self.mlp(a)
 
 
+# What a made-again application keeps beside its input: every product with
+# no batch dimensions (q, k, v, o, gate, up, down) and the attention
+# kernel's two outputs.
+_KEEP = checkpoint_policies.save_from_both_policies(
+    checkpoint_policies.dots_with_no_batch_dims_saveable,
+    checkpoint_policies.save_only_these_names("flash_out", "flash_lse"))
+
+
 class _Layers(_PlainLayers):
     """The decoder layers in order, under one scope (``layers``);
-    ``made_again``: each application under ``remat_call``."""
+    ``made_again``: each application under ``remat_call`` keeping its
+    products and the attention kernel's outputs (``_KEEP``)."""
 
     def hybrid_forward(self, F, x, made_again=False):
         if regions.tracing():
             _metrics.counter(
                 "mxnet_loop_layer_passes_total",
-                "Forward passes of a decoder layer a traced step of a "
-                "looped model makes: one an application whose activations "
-                "are kept, two an application under remat_call.",
+                "Forward passes of a decoder layer's products and attention "
+                "a traced step of a looped model makes: one an application.",
                 labels={"model": regions.current().split("/")[0],
-                        "kind": "made_again" if made_again else "kept"}
-            ).inc(len(self._layers) * (2 if made_again else 1))
+                        "kind": "kept"}).inc(len(self._layers))
         for layer in self._layers:
-            x = remat_call(layer, x) if made_again else layer(x)
+            x = remat_call(layer, x, keep=_KEEP) if made_again else layer(x)
         return x
 
 

@@ -3,6 +3,7 @@ clip_global_norm, download (gated — no egress in this environment), check_sha1
 
 from __future__ import annotations
 
+import functools
 import os
 
 from ..base import MXNetError
@@ -82,7 +83,7 @@ def download(url, path=None, overwrite=False, sha1_hash=None,
         f"environment and {fname} is not cached locally")
 
 
-def remat_call(block, *inputs):
+def remat_call(block, *inputs, keep=None):
     """Run ``block(*inputs)`` with activation REMATERIALIZATION: the
     block's internal activations are not stored for backward — they are
     recomputed from the block inputs during the gradient pass
@@ -92,15 +93,26 @@ def remat_call(block, *inputs):
     activation memory drops from O(layers) to O(1) per wrapped segment —
     what makes long-sequence configs fit one chip (SURVEY §5.7).
 
+    ``keep``: a ``jax.checkpoint`` policy naming what else the backward
+    keeps (e.g. the products, ``jax.checkpoint_policies.
+    dots_with_no_batch_dims_saveable``); what it keeps is not made again.
+    ``None`` keeps the inputs and parameters alone.
+
     The whole block becomes ONE node on the autograd tape (its vjp is the
     checkpointed function's vjp), so it composes with ``autograd.record``
     / ``TrainStep`` like any fused op.  Blocks that MUTATE state in
     forward (BatchNorm running stats) are rejected — the mutation would
     silently vanish.
+
+    Under recording a call grows ``mxnet_remat_applications_total{keep}``
+    by one and ``mxnet_remat_kept_bytes_total{keep}`` by the bytes of the
+    residuals its vjp holds beside the parameters (``keep`` = ``inputs``
+    for ``None``, ``products`` for a policy); always on.
     """
     import jax
     from .. import autograd
     from ..ndarray.ndarray import swap_slot_values
+    from ..telemetry import metrics
 
     params = [p for _, p in sorted(block.collect_params().items())]
     in_ctx = next((a.ctx for a in inputs if isinstance(a, NDArray)), None)
@@ -110,7 +122,7 @@ def remat_call(block, *inputs):
     train = autograd.is_training()
     mutated = [False]
 
-    @jax.checkpoint
+    @functools.partial(jax.checkpoint, policy=keep)
     def f(*arrs):
         in_arr, p_arr = arrs[:n_in], arrs[n_in:]
         with swap_slot_values(zip(param_nds, p_arr)) as saved:
@@ -127,6 +139,19 @@ def remat_call(block, *inputs):
 
     if autograd.is_recording():
         out_raw, vjp_fn = jax.vjp(f, *arrays)
+        weights = {id(a) for a in arrays[n_in:]}
+        label = {"keep": "inputs" if keep is None else "products"}
+        metrics.counter(
+            "mxnet_remat_applications_total",
+            "Blocks run under gluon.utils.remat_call while recording.",
+            labels=label).inc()
+        metrics.counter(
+            "mxnet_remat_kept_bytes_total",
+            "Bytes of the residuals a remat_call's vjp holds beside the "
+            "block's parameters.", labels=label).inc(sum(
+                leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree_util.tree_leaves(vjp_fn)
+                if id(leaf) not in weights))
     else:
         out_raw, vjp_fn = f(*arrays), None
     if mutated[0]:

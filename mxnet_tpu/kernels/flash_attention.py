@@ -63,6 +63,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -787,9 +788,14 @@ def _flash_fwd(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q, block_k,
 
 def _flash_fwd_rule(q, k, v, seg_q, seg_kv, causal, sm_scale, block_q,
                     block_k, block_h, interpret):
-    out, res = _flash_fwd(q, k, v, seg_q, seg_kv, causal, sm_scale,
-                          block_q, block_k, block_h, interpret)
-    return out, res
+    out, (q, k, v, sq, skv, _, lse) = _flash_fwd(
+        q, k, v, seg_q, seg_kv, causal, sm_scale, block_q, block_k, block_h,
+        interpret)
+    # named for a jax.checkpoint policy that keeps them (the looped model's
+    # made-again layers, gluon/model_zoo/ouro.py); the identity outside one
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
+    return out, (q, k, v, sq, skv, out, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, block_q, block_k, block_h, interpret,

@@ -105,6 +105,16 @@ _HELD_FOR_A_BENCHMARK_PR = {
         "asserts its four metrics are the last of per_layer and the only "
         "new ones that list its cell; PR 37's six are appended after them "
         "(ROADMAP C10)",
+    # The looped model's made-again applications keep their products and
+    # the attention kernel's outputs, so the layer-pass counter reads one
+    # pass an application (8 in the tiny cell); this test asserts the 8 + 6
+    # of the rule that made whole applications again.  The same run with
+    # the new count: tests/test_ouro.py::
+    # test_the_harness_runs_the_tiny_cell_correct_at_a_pass_an_application.
+    "test_perfbench_loop_lm_run.py::"
+    "test_sound_run_is_correct_and_counts_its_passes":
+        "asserts 8 + 6 layer passes; a made-again application that keeps "
+        "its products counts one pass (PERF.md section 7)",
 }
 
 

@@ -422,10 +422,11 @@ def test_looped_cells_step_compiles_with_its_flash_calls_and_fits(topo):
     """The step of ``ouro_2_6b.train_s4096`` as the benchmark's builder
     makes it, at the published widths, the cell's depth and one row of
     4,096 positions, for a described v5e: the 128-wide ungrouped flash
-    kernels inside ``jax.checkpoint`` (a ``custom_vjp`` whose forward the
-    backward runs again), 24 forward calls, 18 made again and 24 backward
-    calls in one program that fits with the last loop step's activations
-    kept.  334M parameters with Adam's state are 4.7 GB of host memory."""
+    kernels inside ``jax.checkpoint``, whose policy keeps the forward's out
+    and lse: 24 forward and 24 backward calls, none made again, in one
+    program that fits with the made-again applications' products kept
+    (10.6 GB planned).  334M parameters with Adam's state are 4.7 GB of
+    host memory."""
     import re
     from perfbench import run as harness
     from perfbench import weights
@@ -449,11 +450,11 @@ def test_looped_cells_step_compiles_with_its_flash_calls_and_fits(topo):
     text = compiled.as_text()
     calls = re.findall(r"%(flash_[a-z]+)[.\d]* = ", text)
     layers, steps = cfg["num_hidden_layers"], cfg["total_ut_steps"]
-    assert calls.count("flash_fwd") == layers * steps + layers * (steps - 1)
+    assert calls.count("flash_fwd") == layers * steps
     assert calls.count("flash_bwd") == layers * steps
-    assert text.count("tpu_custom_call") == len(calls) == 66
+    assert text.count("tpu_custom_call") == len(calls) == 48
     planned = mx.telemetry.costmodel.peak_bytes(compiled.memory_analysis())
-    assert 0.25 * V5E_HBM_BYTES < planned < V5E_HBM_BYTES
+    assert 0.25 * V5E_HBM_BYTES < planned < 13.0e9
     program.close()
 
 

@@ -559,3 +559,64 @@ def test_masked_att_qkv_takes_a_narrower_value():
                                        mx.nd.array(v), None, causal=True)
     ref = _dense_sdpa(q, k, v, None, True, 1.0 / 24 ** 0.5)
     np.testing.assert_allclose(out.asnumpy(), np.asarray(ref), atol=1e-5)
+
+
+# -- the kernel's residuals under a checkpoint policy --------------------------
+
+@pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
+                         ids=["one_tile", "streaming"])
+def test_named_residuals_leave_outputs_and_gradients_bit_equal(blocks):
+    """``flash_out`` / ``flash_lse`` name the forward rule's residuals; outside
+    a checkpoint the names are the identity: out and dq, dk, dv are the
+    kernels' own, bit for bit."""
+    import importlib
+    fa = importlib.import_module("mxnet_tpu.kernels.flash_attention")
+    q, k, v, _ = _inputs(jnp.bfloat16)
+    scale = 1.0 / q.shape[-1] ** 0.5
+    bq, bk = blocks.get("block_q", 512), blocks.get("block_k", 512)
+    g = jnp.asarray(np.random.RandomState(5).randn(*q.shape), jnp.bfloat16)
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, None, None, True, scale, interpret=True, **blocks), q, k, v)
+    want, lse = fa._fwd(q, k, v, None, None, True, scale, bq, bk, 0, True)
+    want_grads = fa._bwd(q, k, v, None, None, want, lse, g, True, scale, bq,
+                         bk, 0, True)
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(want, np.float32))
+    for name, a, b in zip("qkv", vjp(g), want_grads):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32), err_msg=name)
+
+
+def _kernels_in(jaxpr):
+    """Names of the Pallas calls in a jaxpr, sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(str(eqn.params["name"]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _kernels_in(sub)
+    return names
+
+
+@pytest.mark.parametrize("blocks", [{}, {"block_q": 128, "block_k": 128}],
+                         ids=["one_tile", "streaming"])
+def test_under_the_looped_models_policy_the_backward_runs_no_forward_kernel(
+        blocks):
+    """Under ``jax.checkpoint`` with the policy the looped model's made-again
+    layers use, the backward keeps the kernel's out and lse and so runs the
+    backward kernel alone; with nothing kept it runs the forward again."""
+    from mxnet_tpu.gluon.model_zoo import ouro
+    q, k, v, _ = _inputs(jnp.bfloat16)
+    g = jnp.ones_like(q)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, None, None, True, 0.125,
+                               interpret=True, **blocks)
+
+    def backward(policy):
+        _, vjp = jax.vjp(jax.checkpoint(attend, policy=policy), q, k, v)
+        return _kernels_in(jax.make_jaxpr(vjp)(g).jaxpr)
+
+    kept, again = backward(ouro._KEEP), backward(None)
+    assert kept and all(n.startswith("flash_bwd") for n in kept), kept
+    assert any(n.startswith("flash_fwd") for n in again), again

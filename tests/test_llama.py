@@ -226,3 +226,54 @@ def test_llama_remat_trainstep():
         out = step.run(nd.array(toks), nd.array(labs))
         losses.append(float(np.asarray(out.asnumpy())[-1]))
     assert np.allclose(losses[0], losses[1], rtol=1e-5), losses
+
+
+@pytest.mark.parametrize("keep", ["inputs", "products"])
+def test_remat_call_keeps_its_inputs_alone_unless_a_policy_keeps_more(keep):
+    """``remat_call``'s contract, which ``use_remat`` and
+    ``TrainStep(remat=True)`` rely on: with ``keep=None`` the vjp holds the
+    block's input and parameters and the rotary tables the forward makes
+    from constants, no activation; a policy adds what it keeps.  Each call
+    under recording grows the two counters under its ``keep`` label."""
+    import jax
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.gluon.model_zoo.llama import LlamaModel
+    from mxnet_tpu.gluon.utils import remat_call
+
+    m = LlamaModel(vocab_size=64, num_layers=1, units=32, hidden=96,
+                   heads=4, kv_heads=2, prefix=f"keep_{keep}_")
+    m.initialize(mx.initializer.Normal(0.05))
+    m(mx.nd.array(np.zeros((2, 16), np.int32)))
+    blk = m.blocks[0]
+    x = mx.nd.array(np.random.RandomState(1).randn(2, 16, 32)
+                    .astype("float32"))
+    policy = None if keep == "inputs" else \
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    telemetry.REGISTRY.reset()
+    with autograd.record():
+        y = remat_call(blk, x, keep=policy)
+    weights = {id(p.data()._data) for p in blk.collect_params().values()}
+    leaves = jax.tree_util.tree_leaves(y._node[0].vjp_fn)
+    held = [a for a in leaves if id(a) not in weights]
+    assert len(leaves) - len(held) == len(weights)
+    assert held[0] is x._data
+    tables = [a.shape for a in held[1:] if a.ndim == 1]
+    assert sorted(tables) == [(4,), (4,), (16,), (16,)]
+    activations = [a.shape for a in held[1:] if a.ndim > 1]
+    if keep == "inputs":
+        assert activations == []
+    else:       # q, k, v, the attention's output, gate and up
+        assert sorted(activations) == sorted(
+            [(2, 16, 32), (2, 16, 16), (2, 16, 16), (2, 16, 32),
+             (2, 16, 96), (2, 16, 96)])
+    got = {(mt.name, dict(mt.labels)["keep"]): mt.value
+           for mt in telemetry.REGISTRY.collect()
+           if mt.name.startswith("mxnet_remat_") and mt.value}
+    assert got == {
+        ("mxnet_remat_applications_total", keep): 1,
+        ("mxnet_remat_kept_bytes_total", keep):
+            sum(a.size * a.dtype.itemsize for a in held)}
+    # outside a recording nothing is held, nothing counted
+    remat_call(blk, x, keep=policy)
+    assert telemetry.REGISTRY.get("mxnet_remat_applications_total",
+                                  labels={"keep": keep}).value == 1

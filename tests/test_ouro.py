@@ -254,19 +254,21 @@ def test_a_shared_weights_gradient_is_the_sum_over_four_untied_copies():
 
 def test_made_again_gives_the_losses_and_gradients_of_everything_kept(
         monkeypatch):
-    """The model's rule (loop steps before the last under remat_call)
-    against the same model with every application called plainly: the
-    gradients of one recorded pass, and two steps of a TrainStep."""
+    """The model's rule (loop steps before the last under remat_call,
+    keeping the products and the attention kernel's outputs) against the
+    same model with every application called plainly: the gradients of one
+    recorded pass, and two steps of a TrainStep."""
     tok, lab = _tokens(), _tokens(seed=5)
     net = _net()
     calls = []
     real = ouro.remat_call
     monkeypatch.setattr(ouro, "remat_call",
-                        lambda blk, x: calls.append(blk) or real(blk, x))
+                        lambda blk, x, keep: calls.append(keep)
+                        or real(blk, x, keep=keep))
     _grads_of(net, net, tok[0], lab[0])
-    assert len(calls) == (STEPS - 1) * LAYERS
+    assert calls == [ouro._KEEP] * ((STEPS - 1) * LAYERS)
     again = {n: p.grad().asnumpy() for n, p in net.collect_params().items()}
-    monkeypatch.setattr(ouro, "remat_call", lambda blk, x: blk(x))
+    monkeypatch.setattr(ouro, "remat_call", lambda blk, x, keep: blk(x))
     _grads_of(net, net, tok[0], lab[0])
     for n, p in net.collect_params().items():
         np.testing.assert_allclose(again[n], p.grad().asnumpy(), rtol=1e-5,
@@ -276,13 +278,15 @@ def test_made_again_gives_the_losses_and_gradients_of_everything_kept(
     monkeypatch.setattr(ouro, "remat_call", real)
     del calls[:]
     monkeypatch.setattr(ouro, "remat_call",
-                        lambda blk, x: calls.append(blk) or real(blk, x))
+                        lambda blk, x, keep: calls.append(keep)
+                        or real(blk, x, keep=keep))
     net(mx.nd.array(tok[0], dtype="int32"))
     assert not calls
 
     def trained(kept):
         if kept:
-            monkeypatch.setattr(ouro, "remat_call", lambda blk, x: blk(x))
+            monkeypatch.setattr(ouro, "remat_call",
+                                lambda blk, x, keep: blk(x))
         else:
             monkeypatch.setattr(ouro, "remat_call", real)
         net = _net()
@@ -297,6 +301,54 @@ def test_made_again_gives_the_losses_and_gradients_of_everything_kept(
     for n in w_kept:
         np.testing.assert_allclose(w_again[n], w_kept[n], rtol=1e-4,
                                    atol=1e-6, err_msg=n)
+
+
+def test_a_made_again_application_keeps_its_products_and_the_kernels_outputs(
+        monkeypatch):
+    """What the tape holds for an application the model's rule makes
+    again, in bf16 at a length the flash kernel takes (its branch is traced
+    on any platform): the input, the seven products (q, k, v, o, down at
+    the model's width, gate and up at the SwiGLU's), the attention kernel's
+    output and float32 ``lse``, and the rotary tables; no float32 norm
+    intermediate.  ``mxnet_remat_kept_bytes_total`` is their bytes."""
+    import collections
+    import ml_dtypes
+    net = _net()
+    net.cast(ml_dtypes.bfloat16)
+    L = 256
+    nodes, real = [], ouro.remat_call
+
+    def spy(blk, x, keep):
+        y = real(blk, x, keep=keep)
+        nodes.append((y._node[0].vjp_fn,
+                      {id(p.data()._data) for p in
+                       blk.collect_params().values()}))
+        return y
+
+    monkeypatch.setattr(ouro, "remat_call", spy)
+    telemetry.REGISTRY.reset()
+    with autograd.record():
+        net(mx.nd.array(_tokens(seq=L)[0][:1], dtype="int32"))
+    assert len(nodes) == (STEPS - 1) * LAYERS
+    kept_bytes = 0
+    for vjp, weights in nodes:
+        leaves = [a for a in jax.tree_util.tree_leaves(vjp)
+                  if id(a) not in weights]
+        kept_bytes += sum(a.size * a.dtype.itemsize for a in leaves)
+        bf16 = collections.Counter(a.shape for a in leaves
+                                   if a.dtype == ml_dtypes.bfloat16)
+        assert bf16 == {(1, L, UNITS): 6, (1, L, HIDDEN): 2,
+                        (1, HEADS, L, HEAD_DIM): 1}
+        wide = [a.shape for a in leaves
+                if a.dtype == np.float32 and a.ndim > 1]
+        assert wide == [(1, HEADS, L)]          # lse, and no norm's float32
+        assert len(leaves) == 9 + 1 + 4         # + the rotary tables
+    counters = {(m.name, dict(m.labels)["keep"]): m.value
+                for m in telemetry.REGISTRY.collect()
+                if m.name.startswith("mxnet_remat_")}
+    assert counters == {
+        ("mxnet_remat_applications_total", "products"): len(nodes),
+        ("mxnet_remat_kept_bytes_total", "products"): kept_bytes}
 
 
 # -- names, counter, gauge ---------------------------------------------------
@@ -357,6 +409,21 @@ def test_made_again_instructions_carry_their_forwards_region(compiled_step):
             assert not again
 
 
+def test_no_product_over_a_weight_is_made_again(compiled_step):
+    """The backward makes the norms, RoPE, the SwiGLU's product and the
+    residual sums again, and no product over a weight: every ``dot`` under
+    ``rematted_computation`` has batch dimensions (the CPU's dense
+    attention, which the policy does not keep)."""
+    _, _, _, text = compiled_step
+    again = [line for line in text.splitlines()
+             if "rematted_computation" in line]
+    assert again
+    dots = [line for line in again if re.search(r" dot\(", line)]
+    assert dots and all("lhs_batch_dims" in line for line in dots)
+    assert not [line for line in again if "custom-call(" in line
+                and "matmul" in line]
+
+
 def test_a_traced_step_counts_its_layer_passes_and_reports_the_exit_mass(
         compiled_step):
     net, step, tok, _ = compiled_step
@@ -368,10 +435,18 @@ def test_a_traced_step_counts_its_layer_passes_and_reports_the_exit_mass(
               for m in telemetry.REGISTRY.collect()
               if m.name == "mxnet_loop_layer_passes_total"
               and dict(m.labels)["model"] == "ouro"}
-    # a trace of the step: the last loop step's layers once each, the
-    # three before it twice each (first, and again in the backward)
-    assert passes["kept"] % LAYERS == 0 and passes["kept"] > 0
-    assert passes["made_again"] == 2 * (STEPS - 1) * passes["kept"]
+    # a trace of the step: every application's products and attention run
+    # once, those of loop steps 1-3 under remat_call as well
+    traces = passes["kept"] // (STEPS * LAYERS)
+    assert set(passes) == {"kept"} and traces > 0
+    assert passes["kept"] == traces * STEPS * LAYERS
+    assert telemetry.REGISTRY.get(
+        "mxnet_remat_applications_total", labels={"keep": "products"}
+    ).value == traces * (STEPS - 1) * LAYERS
+    # what the benchmark's reader makes of it: a pass an application
+    from perfbench import counters_loop
+    assert counters_loop.layer_passes_per_step(STEPS * LAYERS) \
+        == STEPS * LAYERS
     mass = {dict(m.labels)["step"]: m.value
             for m in telemetry.REGISTRY.collect()
             if m.name == "mxnet_loop_exit_mass"}
@@ -391,6 +466,42 @@ def test_a_traced_step_counts_its_layer_passes_and_reports_the_exit_mass(
              for m in telemetry.REGISTRY.collect()
              if m.name == "mxnet_loop_layer_passes_total"}
     assert after == before
+
+
+def _tiny(name):
+    """A module of tests/perfbench/ (the benchmark's tiny cells)."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "perfbench", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_harness_runs_the_tiny_cell_correct_at_a_pass_an_application():
+    """The benchmark's harness over the looped cell's builder at a tiny
+    size (2 layers x 4 loop steps, 6 applications made again): the run is
+    ``correct`` against the float32 reference, and the reader of the
+    layer-pass counter reads one pass an application."""
+    import time
+    from perfbench import counters_loop, counts_loop_lm
+    from perfbench import run as harness
+    bench, tiny = _tiny("perfbench_tiny"), _tiny("perfbench_tiny_loop_lm")
+    telemetry.REGISTRY.reset()
+    cell = tiny.cell()
+    result = harness.run_cell(bench.bench(), cell, (1 << 31) + 9, 0.5, None,
+                              jax.devices(), bench.PEAK,
+                              start=time.perf_counter())
+    assert result["correct"] is True, result["compared"]
+    assert result["failed"] == 0
+    applications = counts_loop_lm.layer_applications(cell["config"])
+    assert applications == 8
+    assert counters_loop.layer_passes_per_step(applications) == 8
+    made_again = telemetry.REGISTRY.get(
+        "mxnet_remat_applications_total", labels={"keep": "products"}).value
+    assert made_again > 0 and made_again % 6 == 0
 
 
 def test_a_mean_report_is_a_gauge_of_the_last_dispatch():

@@ -373,6 +373,30 @@ _STEADY_CASES = {
 }
 
 
+def test_trainstep_remat_holds_the_batch_alone():
+    """``TrainStep(remat=True)`` runs the net forward under ``remat_call``
+    with ``keep=None``: one application a trace, whose vjp holds the batch's
+    tokens (2 x 256 int32) and the rotary tables the forward makes (2
+    layers x 2 x (8 + 256) float32), no activation; a dispatch that builds
+    nothing adds nothing."""
+    from mxnet_tpu import telemetry
+    dispatch = _llama_causal_step(remat=True)
+    telemetry.REGISTRY.reset()
+
+    def counters():
+        return {(m.name, dict(m.labels)["keep"]): m.value
+                for m in telemetry.REGISTRY.collect()
+                if m.name.startswith("mxnet_remat_") and m.value}
+
+    want = {("mxnet_remat_applications_total", "inputs"): 1,
+            ("mxnet_remat_kept_bytes_total", "inputs"):
+                2 * 256 * 4 + 2 * 2 * (8 + 256) * 4}
+    assert np.isfinite(dispatch().asnumpy()).all()
+    assert counters() == want
+    dispatch()
+    assert counters() == want
+
+
 @pytest.mark.parametrize("case", list(_STEADY_CASES))
 def test_trainstep_run_steady_state(case, monkeypatch):
     """One warm dispatch, then two that build nothing: every dispatch is
