@@ -82,6 +82,45 @@ def _unit_lower_inverse(a, given=None):
 _HEAD_GROUPS = (4, 2, 1)    # the solves run a group of heads at a time
 
 
+def _chunks(x, chunk):
+    """(B, L, H, …) -> (B, H, N, C, …): a row padded at its end with zeros
+    to a whole number N of chunks of C = ``chunk`` positions."""
+    import jax.numpy as jnp
+    b, l = x.shape[:2]
+    pad = -l % chunk
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    x = x.reshape((b, (l + pad) // chunk, chunk) + x.shape[2:])
+    return jnp.moveaxis(x, 3, 1)
+
+
+def _rows(out, length):
+    """(B, H, N, C, …) -> (B, ``length``, H, …): ``_chunks`` undone, the
+    padding cut off."""
+    import jax.numpy as jnp
+    b, h, n, c = out.shape[:4]
+    out = jnp.moveaxis(out, 1, 3).reshape((b, n * c, h) + out.shape[4:])
+    return out[:, :length]
+
+
+def _at_or_under(chunk):
+    """The (C, C) mask ``i >= j`` of a chunk's positions."""
+    import jax.numpy as jnp
+    rows = jnp.arange(chunk, dtype=jnp.int32)
+    return rows[:, None] >= rows[None, :]
+
+
+def _decays(big_g, at_or_under):
+    """``exp(G_i - G_j)`` at ``i >= j`` and 0 above the diagonal, of a
+    chunk's cumulative log decays ``big_g`` (…, C) -> (…, C, C);
+    ``at_or_under`` is ``_at_or_under(C)``.  The ratio is taken under the
+    mask, so every exponent is at most 0; ``exp(G_i) * exp(-G_j)`` would
+    overflow."""
+    import jax.numpy as jnp
+    ratio = big_g[..., :, None] - big_g[..., None, :]           # G_i - G_j
+    return jnp.exp(jnp.where(at_or_under, ratio, -jnp.inf))     # 0 over diag
+
+
 def _product(spec, a, b, operand):
     """``einsum`` with both operands in ``operand``'s type, summed in
     float32."""
@@ -107,8 +146,7 @@ def _solved_chunks(q, k, v, g, beta, operand, inverse=None):
         return _product(spec, a, b, operand)
 
     big_g = jnp.cumsum(g, axis=-1)                              # (B,H,N,C)
-    ratio = big_g[..., :, None] - big_g[..., None, :]           # G_i - G_j
-    decay = jnp.exp(jnp.where(at_or_under, ratio, -jnp.inf))    # 0 over diag
+    decay = _decays(big_g, at_or_under)
     k_beta = k.astype(f32) * beta[..., None]
     a = jnp.where(under, product("...id,...jd->...ij", k_beta, k) * decay,
                   f32(0.0))
@@ -249,25 +287,14 @@ def _chunked_rule(q, k, v, g, beta, chunk, inverse=None):
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
-    b, l, h, _dk = q.shape
-    pad = -l % chunk
-    n = (l + pad) // chunk
-
-    def chunks(x):
-        """(B, L, H, …) -> (B, H, N, C, …)."""
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape((b, n, chunk) + x.shape[2:])
-        return jnp.moveaxis(x, 3, 1)
 
     @jax.checkpoint
     def rule(q, k, v, g, beta, *inverse):
-        wide = [chunks(x.astype(v.dtype)) for x in (q, k, v)]
-        out = _chunked(*wide, chunks(g.astype(f32)),
-                       chunks(beta.astype(f32)), v.dtype,
+        wide = [_chunks(x.astype(v.dtype), chunk) for x in (q, k, v)]
+        out = _chunked(*wide, _chunks(g.astype(f32), chunk),
+                       _chunks(beta.astype(f32), chunk), v.dtype,
                        *inverse)                                # (B,H,N,C,Dv)
-        out = jnp.moveaxis(out, 1, 3).reshape(b, n * chunk, h, -1)
-        return out[:, :l].astype(v.dtype)
+        return _rows(out, q.shape[1]).astype(v.dtype)
 
     return rule(q, k, v, g, beta, *(() if inverse is None else (inverse,)))
 

@@ -115,6 +115,20 @@ _HELD_FOR_A_BENCHMARK_PR = {
     "test_sound_run_is_correct_and_counts_its_passes":
         "asserts 8 + 6 layer passes; a made-again application that keeps "
         "its products counts one pass (PERF.md section 7)",
+    # The hybrid Mamba-2 + attention configuration and its cell were
+    # appended after the looped ones, where the benchmark takes new entries: this
+    # test asserts that the looped configuration is the LAST of `configs`.
+    "test_perfbench_loop_lm_run.py::"
+    "test_the_cell_files_give_the_builder_what_it_needs":
+        "asserts ouro_2_6b is the last configuration; granite_4_0_h_micro "
+        "is appended after it (ROADMAP C10)",
+    # ... and this one that `workloads` is exactly the seven cells before
+    # it and that the six dispatch metrics are the last six of `per_layer`,
+    # where the hybrid cell's four metrics now follow them.
+    "test_perfbench_dispatch_metrics.py::"
+    "test_the_six_metrics_are_declared_last_for_every_cell":
+        "asserts seven cells and the dispatch metrics last; a cell and four "
+        "metrics are appended after them (ROADMAP C10)",
 }
 
 

@@ -1,0 +1,177 @@
+"""The plain hybrid Mamba-2 + attention reference against the zoo's
+GraniteHybridModel + TrainStep at a tiny size on the CPU, through the
+benchmark's own comparison (loss, first gradients, Adam's update); the same
+step in a lower precision and the faults the cell's limits are set against
+fail it; the reference's quadratic scan against the program's chunked one.
+(The shares of the vocabulary: test_perfbench_ssm_hybrid_shares.py.)"""
+
+import gc
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench import compare, weights
+from perfbench.feed import TokenFeed
+from perfbench.reference import ssm_hybrid_train as ref
+from perfbench.runners import train_step
+from perfbench.tools import faults_ssm_hybrid
+
+import perfbench_tiny_ssm_hybrid as tiny
+
+SEED = (1 << 31) + 77       # the benchmark's seeds pass 32 signed bits
+
+
+def _sides(cell, seed=SEED):
+    """(program observation, reference(config=None, **fault))."""
+    cfg, traffic = cell["config"], cell["traffic"]
+    _ref, builder, shapes = train_step.sides(cfg)
+    dev = jax.devices()[0]
+    dtype = cfg["run"]["dtype"]
+    first = TokenFeed(traffic, cfg["vocab_size"], seed).next()
+    program = builder.Program(
+        cfg, traffic, weights.make_weights(shapes, seed, dtype, dev),
+        jax.devices())
+    losses = program.losses(program.run(*first))
+    obs = train_step.observe_program(program, shapes, seed, dtype, dev,
+                                     losses)
+    program.close()
+    del program
+    gc.collect()
+
+    def reference(config=None, **kw):
+        return train_step.observe_reference(
+            ref, config or cfg, cell, shapes, seed, dtype, dev, *first, **kw)
+
+    return obs, reference
+
+
+@pytest.fixture(scope="module")
+def float32_sides():
+    cell = tiny.cell("float32")
+    obs, reference = _sides(cell)
+    return cell, obs, reference(), reference
+
+
+def test_reference_agrees_with_zoo_trainstep(float32_sides):
+    cell, obs, ref_obs, _ = float32_sides
+    ok, rows = compare.judge(compare.numbers(obs, ref_obs), cell["limits"])
+    assert ok, rows
+    np.testing.assert_allclose(obs["losses"], ref_obs["losses"], rtol=1e-5)
+    for leaf, want in ref_obs["m"].items():
+        assert obs["m"][leaf] == pytest.approx(want, rel=5e-3, abs=1e-12)
+    for leaf, want in ref_obs["delta"].items():
+        assert obs["delta"][leaf] == pytest.approx(want, rel=5e-3, abs=1e-7)
+    assert set(obs["m"]) == set(ref_obs["m"]) == set(
+        ref.param_shapes(cell["config"]))
+
+
+def test_same_step_in_bfloat16_fails_the_same_comparison():
+    cell = tiny.cell("float32")
+    obs16, reference16 = _sides(tiny.cell("bfloat16"))
+    ok, rows = compare.judge(compare.numbers(obs16, reference16()),
+                             cell["limits"])
+    assert not ok, rows
+
+
+@pytest.mark.parametrize("fault", faults_ssm_hybrid.FAULTS)
+def test_planted_faults_and_the_control_fail(float32_sides, fault):
+    """The faults perfbench/tools/faults_ssm_hybrid.py plants at the cell's
+    size, here at the tiny one (chunks of 8 positions, four a row)."""
+    cell, _, ref_obs, reference = float32_sides
+    cfg = cell["config"]
+    with faults_ssm_hybrid.planted(ref, fault, cfg) as change:
+        obs = reference(dict(cfg, **change),
+                        **faults_ssm_hybrid.arguments(ref, fault,
+                                                      cell["traffic"]))
+    for name in ("_gated_norm", "_reach", "_skip", "_step", "_write"):
+        assert getattr(ref, name).__module__ == ref.__name__    # patch gone
+    ok, rows = compare.judge(compare.numbers(obs, ref_obs), cell["limits"])
+    assert not ok, rows
+
+
+def test_blocks_of_rows_and_of_query_rows_add_up(float32_sides, monkeypatch):
+    cell, _, ref_obs, _ = float32_sides
+    # the whole batch at once, and the scan and attention 8 query rows at a
+    # time (the fixture's: a row at a time, a row's 32 positions in one)
+    whole = dict(cell, reference_block_rows=cell["traffic"]["batch"])
+    monkeypatch.setattr(ref, "_QUERY_ROWS", 8)
+    cfg = dict(cell["config"], traced_again=1)
+    first = TokenFeed(cell["traffic"], cfg["vocab_size"], SEED).next()
+    one = train_step.observe_reference(
+        ref, cfg, whole, ref.param_shapes(cfg), SEED, "float32",
+        jax.devices()[0], *first)
+    np.testing.assert_allclose(one["losses"], ref_obs["losses"], rtol=1e-6)
+    for leaf, want in ref_obs["m"].items():
+        assert one["m"][leaf] == pytest.approx(want, rel=1e-4, abs=1e-12)
+    with pytest.raises(ValueError, match="do not divide into blocks"):
+        train_step.observe_reference(
+            ref, cfg, dict(cell, reference_block_rows=3),
+            ref.param_shapes(cfg), SEED, "float32", jax.devices()[0], *first)
+
+
+@pytest.mark.parametrize("length, groups", [(48, 1), (40, 2)],
+                         ids=["one_group", "two_groups"])
+def test_the_quadratic_scan_is_the_chunked_one(length, groups):
+    """The reference's scan (every pair of positions at once) against the
+    program's chunked op: two algorithms, one definition."""
+    from mxnet_tpu.ops import state_space
+    rs = np.random.RandomState(4)
+    b, h, p, n = 2, 4, 8, 16
+    x = jnp.asarray(rs.randn(b, length, h, p), jnp.float32)
+    dt = jnp.asarray(0.1 * np.exp(0.5 * rs.randn(b, length, h)), jnp.float32)
+    A = jnp.asarray(-0.5 * np.exp(0.3 * rs.randn(h)), jnp.float32)
+    B, C = (jnp.asarray(rs.randn(b, length, groups, n) / 4, jnp.float32)
+            for _ in range(2))
+    D = jnp.asarray(rs.randn(h), jnp.float32)
+    want = state_space._ssd_scan(x, dt, A, B, C, D, chunk=16)
+    got = ref.scan(x, dt, A, B, C, D)
+    np.testing.assert_allclose(got, want, rtol=1e-4,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+
+
+def test_first_gradient_comes_back_as_norms_and_weights_stay():
+    cell = tiny.cell("float32")
+    cfg = cell["config"]
+    w0 = weights.make_weights(ref.param_shapes(cfg), SEED, "float32")
+    keep = {k: np.asarray(v) for k, v in w0.items()}
+    tok, lab = TokenFeed(cell["traffic"], cfg["vocab_size"], SEED).next()
+    _l, first, m, _v, w = ref.train_steps(
+        w0, jnp.asarray(tok), jnp.asarray(lab), cfg, cfg["run"]["optimizer"],
+        block_rows=1)
+    assert all(g.shape == (1,) for g in first.values())
+    assert compare.leaf_norms(first)["embed"] == pytest.approx(
+        float(first["embed"][0]))
+    for k, v in w0.items():             # the caller's copy was not donated
+        assert np.array_equal(np.asarray(v), keep[k])
+    assert set(w) == set(m) == set(w0)
+
+
+def test_init_scale_sets_the_decay_and_step_the_file_states():
+    """``A_log`` and ``dt_bias`` start at -3 and the convolution's taps at 16
+    times the harness's width; the harness's values are what the change is
+    taken from."""
+    cfg = tiny.config()
+    assert cfg["init_scale"] == {"A_log": -3, "dt_bias": -3, "conv_w": 16}
+    w = weights.make_weights(ref.param_shapes(cfg), SEED, "float32")
+    start = ref.at_init(w, cfg)
+    np.testing.assert_array_equal(start["layer0.A_log"], -3.0)
+    np.testing.assert_array_equal(start["layer0.dt_bias"], -3.0)
+    np.testing.assert_allclose(start["layer0.conv_w"], 16 * w["layer0.conv_w"])
+    # a position keeps exp(-softplus(dt - 3) exp(-3)) of the state: 0.9963
+    # at dt's mean 0.075, so a chunk of 256 keeps 0.38
+    keep = np.exp(-0.075 * np.exp(-3.0))
+    assert keep == pytest.approx(0.9963, abs=1e-4)
+    assert keep ** 256 == pytest.approx(0.38, abs=0.01)
+
+
+def test_the_reference_imports_nothing_of_the_program():
+    import ast
+    import inspect
+    tree = ast.parse(inspect.getsource(ref))
+    names = [n.module or "" for n in ast.walk(tree)
+             if isinstance(n, ast.ImportFrom)]
+    names += [a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+              for a in n.names]
+    assert not [n for n in names if n.startswith("mxnet_tpu")]

@@ -458,6 +458,53 @@ def test_looped_cells_step_compiles_with_its_flash_calls_and_fits(topo):
     program.close()
 
 
+def test_ssm_hybrid_cells_step_compiles_and_its_layers_fit(topo):
+    """The step of ``granite_4_0_h_micro.train_s4096`` as the benchmark's
+    builder makes it, at the published widths, the vocabulary slice and one
+    row of 4,096 positions, for a described v5e, with four of its ten
+    layers (three Mamba-2 and the attention layer: 315M parameters, 5.0 GB
+    of host memory with Adam's state; the ten are 12.4): every layer under
+    ``jax.checkpoint`` keeping its input, the scan in ``jax.numpy`` (no
+    Pallas call of its own) and the 64-wide flash kernels over 32 / 8
+    heads, one forward and one backward call.  A layer's temporaries do
+    not grow with the depth, so what the four plan beside their state is
+    what the ten need beside theirs: it must fit in what the cell's state
+    (10.81 GB) and gradient buffers (1.54 GB) leave of the chip."""
+    import re
+    from perfbench import run as harness
+    from perfbench import weights
+    from perfbench.builders import granite_zoo
+    from perfbench.reference import ssm_hybrid_train
+    _bench, cell = harness.load_cell("granite_4_0_h_micro.train_s4096")
+    cfg, traffic = cell["config"], cell["traffic"]
+    cfg = dict(cfg, num_hidden_layers=4,
+               layer_types=["mamba"] * 3 + ["attention"])
+    w0 = weights.make_weights(ssm_hybrid_train.param_shapes(cfg), 1,
+                              cfg["run"]["dtype"], jax.devices()[0])
+    program = granite_zoo.Program(cfg, traffic, w0, jax.devices())
+    del w0
+    mesh = parallel.make_mesh(shape=(1,), axis_names=("dp",),
+                              devices=list(topo.devices[:1]))
+    held_step = program.step
+    step = parallel.TrainStep(program.model, held_step.loss_fn,
+                              held_step.optimizer, mesh=mesh)
+    batch = jax.ShapeDtypeStruct(
+        (traffic["scan_steps"], traffic["batch"], traffic["seq"]), np.int32)
+    with jax.default_matmul_precision("default"):
+        compiled = step.lowered(batch, batch).compile()
+    text = compiled.as_text()
+    assert "ssd_scan" in text
+    calls = re.findall(r"%(flash_[a-z]+)[.\d]* = ", text)
+    assert sorted(set(calls)) == ["flash_bwd", "flash_fwd"]
+    assert text.count("tpu_custom_call") == len(calls)
+    mem = compiled.memory_analysis()
+    temporaries = mem.temp_size_in_bytes
+    # the cell: 10.81 GB of state + 1.54 GB of gradient buffers + these
+    assert 10.81e9 + 1.54e9 + temporaries < 16.9e9, temporaries
+    assert mx.telemetry.costmodel.peak_bytes(mem) < 0.6 * V5E_HBM_BYTES
+    program.close()
+
+
 def test_trainstep_compiles_for_a_four_chip_mesh(topo, smoke, bert_2layer):
     """GSPMD cannot partition a Mosaic kernel: under a mesh the kernel
     runs inside ops.contrib._flash's shard_map (batch over dp, heads over

@@ -397,6 +397,17 @@ def _sweep_override(name):
         "contrib.causal_conv1d": lambda: (
             [nd.array(r.randn(2, 9, 6).astype(np.float32)),
              nd.array(r.randn(6, 4).astype(np.float32))], {}),
+        # the Mamba-2 scan: x (B, L, H, P), steps dt >= 0 (B, L, H), A < 0
+        # (H,), B and C in 2 groups of 4 heads (B, L, G, N), D (H,); a row
+        # of 12 positions in chunks of 8, so the state is carried and padded
+        "contrib.ssd_scan": lambda: (
+            [nd.array(r.randn(2, 12, 4, 3).astype(np.float32)),
+             nd.array(0.5 * r.rand(2, 12, 4).astype(np.float32)),
+             nd.array(-r.rand(4).astype(np.float32)),
+             nd.array(r.randn(2, 12, 2, 5).astype(np.float32)),
+             nd.array(r.randn(2, 12, 2, 5).astype(np.float32)),
+             nd.array(r.randn(4).astype(np.float32))],
+            {"chunk": 8}),
         # encdec: q (Lq, B, H*D), kv (Lk, B, 2*H*D) interleaved k/v
         "contrib.masked_encdec_att": lambda: (
             [nd.array(r.randn(4, 2, 8).astype(np.float32)),
@@ -674,6 +685,10 @@ FD_SKIP = {
     "contrib.causal_conv1d": "sums in float32 whatever the inputs' type; "
                              "grads against a windowed einsum in "
                              "test_linear_attention",
+    "contrib.ssd_scan": "float32 decays, state and sums whatever the "
+                        "inputs' type (the float64 FD's precision is "
+                        "lost); out and all six grads against the "
+                        "token-by-token recurrence in test_state_space",
     "contrib.moe_router": "float32 router core with an integer output "
                           "(the chosen experts) and a top-k choice that "
                           "a finite difference can flip; weights and "
