@@ -2,7 +2,8 @@
 
 The reference forks multiprocessing workers that return batches through
 POSIX-shared-memory NDArrays (Context kCPUShared).  TPU-native rebuild: the
-worker pool is a standard multiprocessing pool returning numpy batches
+worker pool is a standard multiprocessing pool, started by forkserver with
+the dataset pickled, returning numpy batches
 (pickled via shared mmap when large); the final host→device transfer is one
 ``jax.device_put`` per batch, which PJRT pipelines asynchronously — the role
 pinned memory + copy streams play in the reference.  ``num_workers=0`` is the
@@ -13,6 +14,7 @@ sandbox).
 from __future__ import annotations
 
 import multiprocessing as mp
+import pickle
 
 import numpy as _np
 
@@ -62,9 +64,9 @@ def _as_numpy_sample(sample):
 _worker_dataset = None
 
 
-def _worker_init(dataset):
+def _worker_init(pickled):
     global _worker_dataset
-    _worker_dataset = dataset
+    _worker_dataset = pickle.loads(pickled)
 
 
 def _worker_fn(samples):
@@ -116,9 +118,20 @@ class DataLoader:
             and hasattr(dataset, "_decode_plan")
             and config.get_int("MXNET_IO_POOL", 1) != 0)
         if self._num_workers > 0 and not self._use_decode_pool:
-            self._pool = mp.get_context("fork").Pool(
+            # NOT fork (io/pipeline.py's rule): the parent holds JAX's
+            # runtime threads by now, and a fork can copy their held locks
+            # into a worker.  The dataset reaches the workers pickled.
+            try:
+                pickled = pickle.dumps(dataset)
+            except (pickle.PicklingError, TypeError, AttributeError) as exc:
+                raise TypeError(
+                    f"DataLoader(num_workers={self._num_workers}) sends its "
+                    f"dataset to worker processes by pickle, and {dataset!r} "
+                    f"does not pickle ({exc}); give a dataset that pickles "
+                    "or num_workers=0") from exc
+            self._pool = mp.get_context("forkserver").Pool(
                 self._num_workers, initializer=_worker_init,
-                initargs=(dataset,))
+                initargs=(pickled,))
 
     def _materialize(self, batch_idx, hit_chaos=True):
         """In-process fetch + batchify of one batch (the synchronous path

@@ -22,6 +22,13 @@ if "xla_force_host_platform_device_count" not in flags:
         (flags + " --xla_force_host_platform_device_count=8").strip()
 import jax
 if os.environ.get("MXNET_TEST_DEVICE", "cpu") != "tpu":
+    # the suite's programs are built at tiny sizes and run once or twice:
+    # most of its time is XLA compiling them, so the CPU lane compiles at
+    # LLVM's lowest level.  How a program is built, not what it computes.
+    flags = os.environ["XLA_FLAGS"]
+    if "xla_backend_optimization_level" not in flags:
+        os.environ["XLA_FLAGS"] = \
+            flags + " --xla_backend_optimization_level=0"
     os.environ["JAX_PLATFORMS"] = "cpu"
     jax.config.update("jax_platforms", "cpu")
     # mxnet_tpu keeps a persistent compile cache in the checkout
@@ -39,8 +46,35 @@ if "MXNET_FLIGHTREC_DIR" not in os.environ:
     os.environ["MXNET_FLIGHTREC_DIR"] = tempfile.mkdtemp(
         prefix="mxnet-flightrec-")
 
+import faulthandler
+
 import numpy as np
 import pytest
+
+# Seconds a test's call may take.  Past it faulthandler writes every
+# thread's stack to the run's stderr and ends the process: under xdist the
+# test is reported failed ("node down") and a new worker runs the rest, so
+# a hang costs LIMIT and names itself instead of holding the whole run.
+# faulthandler keeps one timer: pytest.ini's faulthandler_timeout (a dump
+# that stops nothing) covers a test's setup; this one takes its place for
+# the call.
+LIMIT = 300
+_stderr = None
+
+
+def pytest_configure(config):
+    # pytest captures fd 2 while a test runs; the dump goes to the run's own
+    global _stderr
+    _stderr = os.dup(2)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    faulthandler.dump_traceback_later(LIMIT, exit=True, file=_stderr)
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 @pytest.fixture(autouse=True)

@@ -6,7 +6,11 @@
    4-device mesh, and the serving executables at the chip_smoke.py width.
    They raise what the chip's compiler would raise — interpret-mode tests
    cannot see an illegal block, a kernel GSPMD cannot partition or a program
-   that does not fit.  A compile that passes is not a chip run.
+   that does not fit.  A compile that passes is not a chip run.  The three
+   steps of whole benchmark cells at their published widths (4.5-5.0 GB of
+   host memory each, 70-160 s) are ``slow``: out of the tier-1 lane, run
+   with ``-m slow``; the cells' own runs on the chip read what they check
+   (``program_hbm_gb`` and the kernels' per-layer counts).
 2. chip_smoke.py's phase functions at a tiny size on the CPU, and the
    refusal: chip_smoke.py never falls back to the CPU.
 
@@ -367,6 +371,7 @@ def test_bert_width_trainstep_compiles_with_the_kernel(topo, smoke,
         compiled.memory_analysis()) < V5E_HBM_BYTES
 
 
+@pytest.mark.slow
 def test_hybrid_cells_step_compiles_under_the_chips_memory(topo):
     """The step of ``qwen3_next_80b_a3b.train_s8192`` as the benchmark's
     builder makes it, at the published widths, the cell's depth and one row
@@ -418,6 +423,7 @@ def test_hybrid_cells_step_compiles_under_the_chips_memory(topo):
     program.close()
 
 
+@pytest.mark.slow
 def test_looped_cells_step_compiles_with_its_flash_calls_and_fits(topo):
     """The step of ``ouro_2_6b.train_s4096`` as the benchmark's builder
     makes it, at the published widths, the cell's depth and one row of
@@ -458,6 +464,7 @@ def test_looped_cells_step_compiles_with_its_flash_calls_and_fits(topo):
     program.close()
 
 
+@pytest.mark.slow
 def test_ssm_hybrid_cells_step_compiles_and_its_layers_fit(topo):
     """The step of ``granite_4_0_h_micro.train_s4096`` as the benchmark's
     builder makes it, at the published widths, the vocabulary slice and one

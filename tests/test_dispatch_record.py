@@ -396,13 +396,15 @@ def test_the_host_cost_of_a_dispatch_around_a_stubbed_program(monkeypatch):
         return outputs
 
     def fifty():
-        t0 = time.perf_counter()
+        t0 = time.thread_time()
         for _ in range(50):
             step._dispatch(program, lambda: scalars, x, y, False,
                            1).asnumpy()
-        return (time.perf_counter() - t0) / 50
+        return (time.thread_time() - t0) / 50
 
-    # the best of many short batches: the suite's other workers share the
-    # cores, and one batch that ran alone says what the code costs
+    # the stubbed dispatch does all its work on this thread, so the
+    # thread's CPU time is its cost, less the time the suite's other
+    # workers hold the core; the best of many short batches is one that
+    # shared the core's caches least
     best = min(fifty() for _ in range(20))
     assert best < 100e-6, f"{best * 1e6:.1f} us a dispatch"

@@ -322,6 +322,37 @@ def test_dataloader_degrades_to_single_process(monkeypatch):
     assert loader._pool is None
 
 
+def test_dataloader_workers_give_the_in_process_batches():
+    rng = np.random.RandomState(0)
+    ds = gluon.data.ArrayDataset(rng.randn(10, 3).astype(np.float32),
+                                 np.arange(10, dtype=np.float32))
+    loaders = [gluon.data.DataLoader(ds, batch_size=3, num_workers=n,
+                                     timeout=30) for n in (0, 2)]
+    assert loaders[1]._pool is not None
+    before = _metric("mxnet_resilience_fallbacks_total")
+    try:
+        alone, pooled = ([(d.asnumpy(), l.asnumpy()) for d, l in loader]
+                         for loader in loaders)
+    finally:
+        loaders[1]._shutdown_pool()
+    # every batch came from a worker: none was refetched in-process
+    assert _metric("mxnet_resilience_fallbacks_total") == before
+    assert len(alone) == len(pooled) == 4
+    for (d0, l0), (d2, l2) in zip(alone, pooled):
+        np.testing.assert_array_equal(d0, d2)
+        np.testing.assert_array_equal(l0, l2)
+
+
+def test_dataloader_workers_refuse_a_dataset_that_does_not_pickle():
+    ds = gluon.data.SimpleDataset(list(range(8))).transform(lambda x: 2 * x)
+    with pytest.raises(TypeError, match="_LazyTransformDataset.* does not "
+                                        "pickle"):
+        gluon.data.DataLoader(ds, batch_size=2, num_workers=2)
+    # in-process loading needs no pickle
+    loader = gluon.data.DataLoader(ds, batch_size=2, num_workers=0)
+    assert [b.asnumpy().tolist() for b in loader][0] == [0, 2]
+
+
 class _WorkerKillerDataset(gluon.data.Dataset):
     """__getitem__(0) kills the WORKER process (never the parent)."""
 
