@@ -22,8 +22,9 @@ dispatch so XLA tiles everything onto the MXU, no dynamic gather/scatter:
 
 ``DroplessMoE`` is the second design, the one today's large open MoE
 decoders train with: a top-k router over ALL experts of the layer (sigmoid
-scores with a choice-only bias, DeepSeek-V3's, or softmax scores with none,
-``qwen3_next``'s), no capacity and no dropped token, bias-free SwiGLU experts
+scores with a choice-only bias, DeepSeek-V3's and LFM2's, or softmax scores
+with none, ``qwen3_next``'s; the chosen weights over their sum plus an
+epsilon, 1e-20 unless the model gives its own), no capacity and no dropped token, bias-free SwiGLU experts
 run as grouped matrix products over the (token, expert) pairs sorted by
 expert (``ops/moe.py``), shared experts every token passes (as they are, or
 each token's multiplied by ``sigmoid(x w_g)``: ``shared_gate``), and a notion
@@ -189,15 +190,19 @@ class _Router(HybridBlock):
     ``contrib.moe_router``.  ``score="sigmoid"`` comes with a choice-only
     bias, which takes no gradient (``grad_req="null"``): what moves it in
     the published recipe is a balancing rule outside the loss.
-    ``score="softmax"`` has no bias."""
+    ``score="softmax"`` has no bias.  ``eps``: what the chosen weights'
+    sum is given before it divides them; None keeps the op's 1e-20."""
 
     def __init__(self, units, num_experts, k, scale, normalize,
-                 score="sigmoid", **kwargs):
+                 score="sigmoid", eps=None, **kwargs):
         super().__init__(**kwargs)
         self._attrs = dict(k=int(k), scale=float(scale),
                            normalize=bool(normalize))
-        if score != "sigmoid":      # the default stays out of the op's
-            self._attrs["score"] = score    # attributes: its cache key
+        # the defaults stay out of the op's attributes: its cache key
+        if score != "sigmoid":
+            self._attrs["score"] = score
+        if eps is not None:
+            self._attrs["eps"] = float(eps)
         with self.name_scope():
             self.weight = self.params.get(
                 "weight", shape=(num_experts, units), init=None)
@@ -234,8 +239,9 @@ class _Experts(HybridBlock):
 
 
 class DroplessMoE(HybridBlock):
-    """Dropless routed feed-forward with shared experts (DeepSeek-V3's,
-    and with ``score="softmax"``, ``shared_gate=True`` ``qwen3_next``'s).
+    """Dropless routed feed-forward with shared experts (DeepSeek-V3's;
+    with ``score="softmax"``, ``shared_gate=True`` ``qwen3_next``'s; with no
+    shared expert and ``router_eps=1e-6`` LFM2's).
 
     Parameters
     ----------
@@ -250,6 +256,8 @@ class DroplessMoE(HybridBlock):
         ``num_shared_experts * hidden_size`` on every token (0 = none).
     routed_scaling_factor, norm_topk_prob : the router's scale, and whether
         the chosen weights are divided by their sum first.
+    router_eps : float or None — added to that sum before it divides
+        (LFM2's router: 1e-6); None keeps ``contrib.moe_router``'s 1e-20.
     score : ``"sigmoid"`` (scores with a choice-only bias that takes no
         gradient) or ``"softmax"`` over the layer's experts (no bias).
     shared_gate : bool — each token's shared-expert output is multiplied
@@ -271,7 +279,7 @@ class DroplessMoE(HybridBlock):
                  num_experts_per_token, experts_held=None,
                  num_shared_experts=0, routed_scaling_factor=1.0,
                  norm_topk_prob=True, score="sigmoid", shared_gate=False,
-                 expert_axis="ep", **kwargs):
+                 router_eps=None, expert_axis="ep", **kwargs):
         super().__init__(**kwargs)
         first, count = experts_held or (0, num_experts)
         if num_experts_per_token > num_experts:
@@ -288,7 +296,8 @@ class DroplessMoE(HybridBlock):
         with self.name_scope():
             self.router = _Router(units, num_experts, num_experts_per_token,
                                   routed_scaling_factor, norm_topk_prob,
-                                  score=score, prefix="router_")
+                                  score=score, eps=router_eps,
+                                  prefix="router_")
             self.experts = _Experts(units, hidden_size, first, count,
                                     expert_axis, prefix="experts_")
             self.shared = SwiGLU(units, num_shared_experts * hidden_size,

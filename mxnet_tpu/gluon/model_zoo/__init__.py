@@ -12,7 +12,7 @@ from . import bert  # noqa: F401
 def __getattr__(name):
     import importlib
     if name in ("vision", "llama", "mla_moe", "qwen3_next", "ouro",
-                "granite_hybrid", "transformer", "yolo"):
+                "granite_hybrid", "lfm2_moe", "transformer", "yolo"):
         mod = importlib.import_module(f".{name}", __name__)
         globals()[name] = mod
         return mod

@@ -108,9 +108,14 @@ def remat_call(block, *inputs, keep=None):
     by one and ``mxnet_remat_kept_bytes_total{keep}`` by the bytes of the
     residuals its vjp holds beside the parameters (``keep`` = ``inputs``
     for ``None``, ``products`` for a policy); always on.
+
+    What the block reports through ``parallel.report_counter`` while a
+    ``TrainStep`` traces it leaves the checkpointed function as outputs of
+    its own and is reported again outside it: collected once a traced
+    forward, not again for the pass the backward makes.
     """
     import jax
-    from .. import autograd
+    from .. import autograd, parallel
     from ..ndarray.ndarray import swap_slot_values
     from ..telemetry import metrics
 
@@ -121,11 +126,13 @@ def remat_call(block, *inputs, keep=None):
     n_in = len(inputs)
     train = autograd.is_training()
     mutated = [False]
+    reported = []       # the keys of what the block reported, in order
 
     @functools.partial(jax.checkpoint, policy=keep)
     def f(*arrs):
         in_arr, p_arr = arrs[:n_in], arrs[n_in:]
-        with swap_slot_values(zip(param_nds, p_arr)) as saved:
+        with swap_slot_values(zip(param_nds, p_arr)) as saved, \
+                parallel._collect_reports(inner=True) as reports:
             in_nds = [NDArray._from_data(a) for a in in_arr]
             with autograd._scope(recording=False, training=train):
                 out = block(*in_nds)
@@ -135,10 +142,11 @@ def remat_call(block, *inputs, keep=None):
             if isinstance(out, (list, tuple)):
                 raise MXNetError(
                     "remat_call supports single-output blocks")
-            return out._data
+        reported[:] = reports
+        return out._data, [reports[key] for key in reported]
 
     if autograd.is_recording():
-        out_raw, vjp_fn = jax.vjp(f, *arrays)
+        out_raw, vjp_fn, values = jax.vjp(f, *arrays, has_aux=True)
         weights = {id(a) for a in arrays[n_in:]}
         label = {"keep": "inputs" if keep is None else "products"}
         metrics.counter(
@@ -153,7 +161,9 @@ def remat_call(block, *inputs, keep=None):
                 for leaf in jax.tree_util.tree_leaves(vjp_fn)
                 if id(leaf) not in weights))
     else:
-        out_raw, vjp_fn = f(*arrays), None
+        (out_raw, values), vjp_fn = f(*arrays), None
+    for (name, labels, kind), value in zip(reported, values):
+        parallel.report_counter(name, value, dict(labels), kind)
     if mutated[0]:
         raise MXNetError(
             "remat_call: block mutates state in forward (BatchNorm "

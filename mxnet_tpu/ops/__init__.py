@@ -21,6 +21,7 @@ from . import contrib  # noqa: F401
 from . import moe  # noqa: F401
 from . import linear_attention  # noqa: F401
 from . import state_space  # noqa: F401
+from . import short_conv  # noqa: F401
 from . import vision  # noqa: F401
 from . import quantization  # noqa: F401
 from . import sparse_ops  # noqa: F401

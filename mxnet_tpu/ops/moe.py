@@ -1,7 +1,8 @@
 """Dropless mixture-of-experts ops: a top-k router over all experts of a
-layer (sigmoid scores with a choice-only bias, DeepSeek-V3's, or softmax
-scores with none, ``qwen3_next``'s), and the routed feed-forward of the
-experts THIS device holds.
+layer (sigmoid scores with a choice-only bias, DeepSeek-V3's and LFM2's, or
+softmax scores with none, ``qwen3_next``'s; the chosen weights normalised
+over their sum plus an epsilon the caller may set), and the routed
+feed-forward of the experts THIS device holds.
 
 The layer expert parallelism needs, run without its exchange: the router
 scores every token against all E experts of the layer; the device holds the
@@ -54,14 +55,15 @@ from .registry import register
 
 @register("contrib.moe_router", num_outputs=2)
 def _moe_router(x, weight, bias=None, k=1, scale=1.0, normalize=True,
-                score="sigmoid"):
+                score="sigmoid", eps=1e-20):
     """Top-k routing over all E experts of a layer.  ``x`` (N, U);
     ``weight`` (E, U), the layout of a Dense weight.  ``score``:
     ``"sigmoid"`` (DeepSeek-V3's ``noaux_tc`` with one group) or
     ``"softmax"`` over the E logits (``qwen3_next``'s).  ``bias`` (E,) or
     None: added to the scores for the CHOICE alone.  Returns ``(weights
     (N, k) float32, experts (N, k) int32)``: the scores (without the bias)
-    at the chosen experts, divided by their sum + 1e-20 if ``normalize``,
+    at the chosen experts, divided by their sum + ``eps`` if
+    ``normalize`` (1e-20 by default; LFM2's published router adds 1e-6),
     times ``scale``.  The product runs in float32 at the highest precision
     whatever the activations' type; of equal scores the lower expert
     wins."""
@@ -80,7 +82,7 @@ def _moe_router(x, weight, bias=None, k=1, scale=1.0, normalize=True,
         scores if bias is None else scores + bias.astype(f32), k)
     chosen = jnp.take_along_axis(scores, experts, axis=1)
     if normalize:
-        chosen = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+        chosen = chosen / (chosen.sum(axis=-1, keepdims=True) + eps)
     return chosen * f32(scale), experts.astype(jnp.int32)
 
 

@@ -31,7 +31,6 @@ import functools
 import logging
 import threading
 import time
-import warnings
 
 import numpy as _np
 from jax.profiler import TraceAnnotation
@@ -424,8 +423,6 @@ def _bank_device_bytes(mesh, arguments):
 # fetched dispatch; a net that reports nothing pays nothing.
 
 _reports = threading.local()
-_UNDER_REMAT = object()     # the sink while a remat'd forward is traced
-_warned_under_remat = set()
 _REDUCE = ("sum", "max", "mean")    # report kinds: the reduction's name
 
 
@@ -438,19 +435,11 @@ def report_counter(name, value, labels=None, kind="sum"):
     steps of the dispatch fetched last.  Several reports under one name and
     labels in one step combine the same way (a second mean is refused: it
     would need the first one's count).  Outside a TrainStep's trace (an
-    imperative forward) this does nothing.  Under ``TrainStep(remat=True)`` a value cannot leave the
-    checkpointed forward: it is not collected, and the first report of each
-    name says so in a warning."""
+    imperative forward) this does nothing.  A block under
+    ``gluon.utils.remat_call`` reports as any other: the call hands its
+    values out of the checkpointed forward."""
     sink = getattr(_reports, "sink", None)
     if sink is None:
-        return
-    if sink is _UNDER_REMAT:
-        if name not in _warned_under_remat:
-            _warned_under_remat.add(name)
-            warnings.warn(
-                f"report_counter({name!r}): the TrainStep runs the net under "
-                "remat, so the values it reports are not collected and the "
-                "counter stays where it is", RuntimeWarning, stacklevel=2)
         return
     import jax.numpy as jnp
     if kind not in _REDUCE:
@@ -468,13 +457,15 @@ def report_counter(name, value, labels=None, kind="sum"):
 
 
 @contextlib.contextmanager
-def _collect_reports(sink=None):
+def _collect_reports(inner=False):
     """The dict ``report_counter`` fills on this thread while the body
-    runs (or ``_UNDER_REMAT``, which collects nothing and warns)."""
+    runs.  ``inner``: a body nested in a collecting one (``remat_call``'s),
+    which collects only where the outer body does, and hands its values
+    on."""
     outer = getattr(_reports, "sink", None)
-    _reports.sink = sink = {} if sink is None else sink
+    _reports.sink = sink = None if inner and outer is None else {}
     try:
-        yield sink
+        yield {} if sink is None else sink
     finally:
         _reports.sink = outer
 
@@ -830,14 +821,11 @@ class TrainStep:
             scope = _rnd.trace_key_scope(key)
             reports = {}
             with scope, autograd._scope(recording=True, training=True):
-                if remat:
-                    # a value reported from inside the checkpointed
-                    # forward could not leave it: nothing is collected
-                    from .gluon.utils import remat_call
-                    with _collect_reports(_UNDER_REMAT):
+                with _collect_reports() as reports:
+                    if remat:
+                        from .gluon.utils import remat_call
                         out = remat_call(net, d_nd)
-                else:
-                    with _collect_reports() as reports:
+                    else:
                         out = net(d_nd)
                 with _regions.scope("loss"):
                     loss = loss_fn(out, l_nd)

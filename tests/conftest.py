@@ -163,6 +163,13 @@ _HELD_FOR_A_BENCHMARK_PR = {
     "test_the_six_metrics_are_declared_last_for_every_cell":
         "asserts seven cells and the dispatch metrics last; a cell and four "
         "metrics are appended after them (ROADMAP C10)",
+    # The short-convolution configuration and its cell were appended after
+    # the state-space ones: this test asserts that the state-space
+    # configuration is the LAST of `configs`.
+    "test_perfbench_ssm_hybrid_run.py::"
+    "test_the_cell_files_give_the_builder_what_it_needs":
+        "asserts granite_4_0_h_micro is the last configuration; "
+        "lfm2_24b_a2b is appended after it (ROADMAP C10)",
 }
 
 

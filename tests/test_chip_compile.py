@@ -6,7 +6,7 @@
    4-device mesh, and the serving executables at the chip_smoke.py width.
    They raise what the chip's compiler would raise — interpret-mode tests
    cannot see an illegal block, a kernel GSPMD cannot partition or a program
-   that does not fit.  A compile that passes is not a chip run.  The three
+   that does not fit.  A compile that passes is not a chip run.  The four
    steps of whole benchmark cells at their published widths (4.5-5.0 GB of
    host memory each, 70-160 s) are ``slow``: out of the tier-1 lane, run
    with ``-m slow``; the cells' own runs on the chip read what they check
@@ -509,6 +509,52 @@ def test_ssm_hybrid_cells_step_compiles_and_its_layers_fit(topo):
     # the cell: 10.81 GB of state + 1.54 GB of gradient buffers + these
     assert 10.81e9 + 1.54e9 + temporaries < 16.9e9, temporaries
     assert mx.telemetry.costmodel.peak_bytes(mem) < 0.6 * V5E_HBM_BYTES
+    program.close()
+
+
+@pytest.mark.slow
+def test_conv_moe_cells_step_compiles_and_its_layers_fit(topo):
+    """The step of ``lfm2_24b_a2b.train_s8192`` as the benchmark's builder
+    makes it, at the published widths, the vocabulary slice and four rows
+    of 8,192 positions, for a described v5e, with three of its five layers
+    (the dense short convolution, the attention layer and a short
+    convolution over the MoE: 284M parameters, ~4.5 GB of host memory with
+    Adam's state): every layer under ``jax.checkpoint`` keeping its input,
+    the gated convolution in ``jax.numpy`` under its own scope, the 64-wide
+    flash kernels over 32 / 8 heads (forward twice, backward once) and the
+    compiler's grouped products.  A layer's temporaries barely grow with
+    the depth (its kept input, 0.13 GB), so what the three plan beside
+    their state is what the five need beside theirs: it must fit in what
+    the cell's state (6.57 GB) and gradient buffers (0.94 GB) leave of the
+    chip, at batch 4 (the whole step planned 11.33 GB here)."""
+    import re
+    from perfbench import run as harness
+    from perfbench import weights
+    from perfbench.builders import lfm2_zoo
+    from perfbench.reference import conv_moe_train
+    _bench, cell = harness.load_cell("lfm2_24b_a2b.train_s8192")
+    cfg, traffic = cell["config"], cell["traffic"]
+    cfg = dict(cfg, num_hidden_layers=3, layers_held=[0, 2, 3])
+    w0 = weights.make_weights(conv_moe_train.param_shapes(cfg), 1,
+                              cfg["run"]["dtype"], jax.devices()[0])
+    program = lfm2_zoo.Program(cfg, traffic, w0, jax.devices())
+    del w0
+    mesh = parallel.make_mesh(shape=(1,), axis_names=("dp",),
+                              devices=list(topo.devices[:1]))
+    held_step = program.step
+    step = parallel.TrainStep(program.model, held_step.loss_fn,
+                              held_step.optimizer, mesh=mesh)
+    batch = jax.ShapeDtypeStruct(
+        (traffic["scan_steps"], traffic["batch"], traffic["seq"]), np.int32)
+    with jax.default_matmul_precision("default"):
+        compiled = step.lowered(batch, batch).compile()
+    text = compiled.as_text()
+    assert "gated_short_conv" in text and "ragged" in text
+    calls = re.findall(r"%(flash_[a-z]+)[.\d]* = ", text)
+    assert sorted(calls) == ["flash_bwd", "flash_fwd", "flash_fwd"]
+    mem = compiled.memory_analysis()
+    temporaries = mem.temp_size_in_bytes
+    assert 6.57e9 + 0.94e9 + temporaries < 15.5e9, temporaries
     program.close()
 
 

@@ -252,3 +252,34 @@ def test_causal_conv_gradients():
     want = jax.grad(by_hand, argnums=(0, 1))(x, w)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def _causal_conv1d_as_it_was(x, weight):
+    """``contrib.causal_conv1d`` as it stands, word for word: the gated
+    short convolution calls its arithmetic, and must leave the op the
+    accepted cells run alone."""
+    f32 = jnp.float32
+    taps = weight.shape[1]
+    length = x.shape[1]
+    padded = jnp.pad(x.astype(f32), ((0, 0), (taps - 1, 0), (0, 0)))
+    w = weight.astype(f32)
+    out = padded[:, :length] * w[:, 0]
+    for j in range(1, taps):
+        out = out + padded[:, j:j + length] * w[:, j]
+    return out.astype(x.dtype)
+
+
+def test_causal_conv_lowers_to_the_module_it_lowered_to():
+    """Forward and backward of the op at the Gated DeltaNet and Mamba-2
+    cells' 4 taps lower to the module of the op as it stands."""
+    rs = np.random.RandomState(0)
+    x = jnp.asarray(rs.randn(1, 64, 48), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(48, 4), jnp.bfloat16)
+
+    def lowered(fn):
+        def op(x, w):
+            return jax.grad(lambda x, w: (fn(x, w).astype(jnp.float32)
+                                          ** 2).sum(), (0, 1))(x, w)
+        return jax.jit(op).lower(x, w).as_text()
+
+    assert lowered(_causal_conv1d) == lowered(_causal_conv1d_as_it_was)

@@ -501,3 +501,109 @@ def test_dropless_moe_refuses_arguments_that_do_not_fit(kwargs, match):
     from mxnet_tpu.base import MXNetError
     with pytest.raises(MXNetError, match=match):
         DroplessMoE(8, 16, 8, 3, **kwargs)
+
+
+# -- the router's epsilon and the accepted cells' modules -----------------------
+
+def _router_as_it_was(x, weight, bias=None, k=1, scale=1.0, normalize=True,
+                      score="sigmoid"):
+    """``contrib.moe_router`` as it stood before it took an epsilon: the
+    sum of the chosen weights given 1e-20."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    logits = jnp.einsum("nu,eu->ne", x.astype(f32), weight.astype(f32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    _, experts = jax.lax.top_k(
+        scores if bias is None else scores + bias.astype(f32), k)
+    chosen = jnp.take_along_axis(scores, experts, axis=1)
+    if normalize:
+        chosen = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+    return chosen * f32(scale), experts.astype(jnp.int32)
+
+
+def _lowered(fn, *args, **attrs):
+    import jax
+
+    def op(*a):
+        return fn(*a, **attrs)
+    return jax.jit(op).lower(*args).as_text()
+
+
+@pytest.mark.parametrize("attrs, biased", [
+    (dict(k=6, scale=2.448, normalize=True), True),             # kanana
+    (dict(k=10, scale=1.0, normalize=True, score="softmax"), False),  # qwen3
+], ids=["kanana", "qwen3_next"])
+def test_the_routers_module_at_the_accepted_cells_attributes_is_unchanged(
+        attrs, biased):
+    """The epsilon is an attribute with the old constant as its default,
+    and DroplessMoE passes it only where a model sets it: at the MLA +
+    MoE and the Gated DeltaNet cells' attributes the op lowers to the
+    module it lowered to before, and their routers' attributes (the op's
+    cache key) hold no epsilon."""
+    import jax.numpy as jnp
+    from mxnet_tpu.gluon.contrib import moe as gmoe
+    from mxnet_tpu.ops.moe import _moe_router
+    rs = np.random.RandomState(0)
+    x = jnp.asarray(rs.randn(64, 32), jnp.bfloat16)
+    w = jnp.asarray(rs.randn(128, 32), jnp.bfloat16)
+    b = jnp.asarray(rs.randn(128), jnp.float32) if biased else None
+    args = (x, w, b) if biased else (x, w)
+    assert _lowered(_moe_router, *args, **attrs) == \
+        _lowered(_router_as_it_was, *args, **attrs)
+    score = attrs.get("score", "sigmoid")
+    router = gmoe._Router(32, 128, attrs["k"], attrs["scale"], True,
+                          score=score)
+    assert router._attrs == attrs
+    layer = gmoe.DroplessMoE(32, 16, 128, attrs["k"], experts_held=(0, 8),
+                             score=score)
+    assert "eps" not in layer.router._attrs
+
+
+def _pairs_counted(remat):
+    """``mxnet_moe_pairs_total`` and ``mxnet_moe_tokens_total`` after one
+    dispatch of a TrainStep over a dropless layer, with the step's own
+    ``remat`` on or off."""
+    import jax
+    from mxnet_tpu import parallel, telemetry
+    from mxnet_tpu.gluon import nn
+
+    class Net(nn.HybridBlock):
+        def __init__(self, **kwargs):
+            super().__init__(**kwargs)
+            with self.name_scope():
+                self.moe = DroplessMoE(8, 16, 8, 2, experts_held=(0, 4))
+
+        def hybrid_forward(self, F, x):
+            return self.moe(x)
+
+    mx.random.seed(0)
+    net = Net(prefix="counted_")
+    net.initialize(mx.init.Normal(0.5))
+    net(mx.nd.ones((1, 3, 8)))
+    mesh = parallel.make_mesh(shape=(1,), axis_names=("dp",),
+                              devices=jax.devices()[:1])
+    step = parallel.TrainStep(
+        net, lambda out, lab: ((out - lab) ** 2).mean(),
+        mx.optimizer.SGD(learning_rate=0.1), mesh=mesh, remat=remat)
+    rs = np.random.RandomState(1)
+    x = mx.nd.array(rs.randn(2, 2, 5, 8).astype(np.float32))
+    telemetry.REGISTRY.reset()
+    step.run(x, x).asnumpy()
+    return {m.name: m.value for m in telemetry.REGISTRY.collect()
+            if m.name in ("mxnet_moe_pairs_total", "mxnet_moe_tokens_total")
+            and m.value}
+
+
+def test_a_step_made_again_whole_still_collects_the_routed_counters():
+    """``TrainStep(remat=True)`` runs the net under ``remat_call``, which
+    hands what the layer reports out of the checkpointed forward: the
+    counters read as they do without the remake (2 steps x 10 tokens)."""
+    plain, again = _pairs_counted(False), _pairs_counted(True)
+    assert plain == again
+    assert again["mxnet_moe_tokens_total"] == 20
+    assert 0 < again["mxnet_moe_pairs_total"] <= 40

@@ -397,6 +397,10 @@ def _sweep_override(name):
         "contrib.causal_conv1d": lambda: (
             [nd.array(r.randn(2, 9, 6).astype(np.float32)),
              nd.array(r.randn(6, 4).astype(np.float32))], {}),
+        # the gated short convolution: [B | C | x] (B, L, 3 U), taps (U, K)
+        "contrib.gated_short_conv": lambda: (
+            [nd.array(r.randn(2, 9, 18).astype(np.float32)),
+             nd.array(r.randn(6, 3).astype(np.float32))], {}),
         # the Mamba-2 scan: x (B, L, H, P), steps dt >= 0 (B, L, H), A < 0
         # (H,), B and C in 2 groups of 4 heads (B, L, G, N), D (H,); a row
         # of 12 positions in chunks of 8, so the state is carried and padded
@@ -685,6 +689,10 @@ FD_SKIP = {
     "contrib.causal_conv1d": "sums in float32 whatever the inputs' type; "
                              "grads against a windowed einsum in "
                              "test_linear_attention",
+    "contrib.gated_short_conv": "products and sums in float32 whatever the "
+                                "inputs' type; out and grads against "
+                                "lax.conv_general_dilated in "
+                                "test_perfbench_conv_moe_reference",
     "contrib.ssd_scan": "float32 decays, state and sums whatever the "
                         "inputs' type (the float64 FD's precision is "
                         "lost); out and all six grads against the "
